@@ -4,8 +4,7 @@ The timing methodology matches bench.py: R independent solves are chained
 inside one jitted lax.scan (a data dependency folds each result into a
 carry so no solve can be elided), and throughput is the marginal time
 between a short and a long chain — this cancels the fixed dispatch/sync
-latency of the device link, which is irrelevant to steady-state event
-processing where results stay device-resident.
+latency of one host round trip.
 """
 
 from __future__ import annotations
@@ -41,20 +40,17 @@ def time_marginal(run, reps_small: int, reps_big: int, rounds: int = 3) -> float
 
     `run(reps)` must block until the device is done. The median of the
     positive per-round marginals is reported — taking the minimum would
-    systematically favor rounds where link-sync jitter happened to inflate
-    the short chain and deflate the long one.
+    systematically favor rounds where sync jitter happened to inflate the
+    short chain and deflate the long one.
 
-    When EVERY round's marginal is non-positive (sync jitter swamped the
-    chain-length delta), falls back to the best (minimum) whole-chain time
-    observed across all rounds divided by reps_big — the least
-    jitter-inflated sample available — and notes the degraded methodology
-    on stderr (the fixed dispatch latency is then NOT cancelled, so the
-    number overstates per-event cost).
+    When EVERY round's marginal is non-positive (jitter swamped the
+    chain-length delta) there is no measurement: raises, so the caller
+    lengthens the chains instead of reporting a number under another
+    method.
     """
     run(reps_small)  # compile/warm
     run(reps_big)
     marginals = []
-    best_t_big = None
     for _ in range(rounds):
         t0 = time.time()
         run(reps_small)
@@ -62,18 +58,14 @@ def time_marginal(run, reps_small: int, reps_big: int, rounds: int = 3) -> float
         t0 = time.time()
         run(reps_big)
         t_big = time.time() - t0
-        if best_t_big is None or t_big < best_t_big:
-            best_t_big = t_big
         marginal = (t_big - t_small) / (reps_big - reps_small)
         if marginal > 0:  # noise guard: jitter can invert tiny pairs
             marginals.append(marginal)
     if not marginals:
-        note(
-            f"time_marginal: all {rounds} round marginals non-positive; "
-            f"degraded fallback = best whole-chain {best_t_big:.4f}s / "
-            f"{reps_big} reps (dispatch latency not cancelled)"
+        raise RuntimeError(
+            f"time_marginal: all {rounds} round marginals non-positive "
+            f"({reps_small} vs {reps_big} reps); lengthen the chains"
         )
-        return best_t_big / reps_big
     return float(np.median(marginals))
 
 

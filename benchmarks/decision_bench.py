@@ -111,6 +111,9 @@ def _flap_event_bench(
 
 
 def main(argv: List[str] = ()) -> None:
+    from openr_tpu.utils.compile_cache import ensure_compile_cache
+
+    ensure_compile_cache()
     grid_sides = [
         int(x)
         for x in os.environ.get("DECISION_GRID_SIDES", "10,32").split(",")

@@ -59,6 +59,9 @@ def _set_link_overload(dbs, ls, node: str, other: str, down: bool) -> bool:
 
 
 def main(argv=None) -> None:
+    from openr_tpu.utils.compile_cache import ensure_compile_cache
+
+    ensure_compile_cache()
     smoke = os.environ.get("BENCH_SMOKE") == "1"
     pods = _env_int("INC_PODS", 2 if smoke else 170)
     planes = _env_int("INC_PLANES", 2 if smoke else 4)

@@ -38,6 +38,7 @@ Discipline mirrors `_AreaSolve` (solver/tpu.py):
 
 from __future__ import annotations
 
+import logging
 import time
 from typing import Callable, Dict, Optional, Tuple
 
@@ -55,6 +56,8 @@ from openr_tpu.apsp.kernels import (
 )
 from openr_tpu.ops.graph import CompiledGraph, _next_bucket
 from openr_tpu.testing.faults import fault_point
+
+log = logging.getLogger(__name__)
 
 # re-close safety margin: the restricted fixpoint stitches at least one
 # old-path segment per round, so rounds beyond the block count mean a bug
@@ -272,6 +275,10 @@ class ApspState:
         try:
             return primary(), False
         except Exception:
+            # unsupervised embedding: the numpy close serves, but never
+            # silently — fallback() counts it (fallback_closes) and the
+            # device error is logged with its traceback
+            log.exception("%s failed on device; numpy fallback serves", op)
             return fallback(), True
 
     def _close_cold(self, graph: CompiledGraph, audit: bool = True) -> None:

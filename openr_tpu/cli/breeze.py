@@ -415,7 +415,7 @@ def cmd_decision(client: BlockingCtrlClient, args) -> None:
         if args.json:
             _print_json(report)
             return
-        state = "DEGRADED cpu-fallback" if report.get("degraded") else "ok"
+        state = "DEGRADED cpu" if report.get("degraded") else "ok"
         print(
             f"te-optimize [{state}]: max link util "
             f"{report['initial_max_util']:.3f} -> "

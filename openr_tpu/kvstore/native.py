@@ -6,38 +6,26 @@ Python keeps the protocol machinery (flooding, sync FSM, TTL timers) and
 sees the table through `NativeKvTable`, a MutableMapping adapter speaking
 the compact record format documented in native/kvstore/onl_kvstore.h.
 
-Auto-builds openr_tpu/_native/libopenr_kv.so via `make` on first use, like
-the netlink binding. `native_kv_available()` gates callers; everything
-falls back to the pure-Python dict store when the toolchain is missing.
+Builds openr_tpu/_native/libopenr_kv.so through `make` on first use (a
+no-op when the binary is fresh). `native_kv_available()` gates callers;
+everything falls back to the pure-Python dict store when the toolchain is
+missing.
 """
 
 from __future__ import annotations
 
 import ctypes
-import os
+import logging
 import struct
-import subprocess
 from typing import Dict, Iterator, MutableMapping, Optional, Tuple
 
 from openr_tpu.types import KeyVals, Value, generate_hash
+from openr_tpu.utils.native_build import build_native
 
-_NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(__file__)), "_native")
-_SO_PATH = os.path.join(_NATIVE_DIR, "libopenr_kv.so")
-_MAKE_DIR = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.dirname(__file__))), "native"
-)
+log = logging.getLogger(__name__)
 
 _lib: Optional[ctypes.CDLL] = None
 _load_attempted = False
-
-
-def _build() -> None:
-    subprocess.run(
-        ["make", "-C", _MAKE_DIR],
-        check=True,
-        capture_output=True,
-        timeout=120,
-    )
 
 
 def _load() -> Optional[ctypes.CDLL]:
@@ -46,10 +34,11 @@ def _load() -> Optional[ctypes.CDLL]:
         return _lib
     _load_attempted = True
     try:
-        if not os.path.exists(_SO_PATH):
-            _build()
-        lib = ctypes.CDLL(_SO_PATH)
-    except Exception:
+        lib = ctypes.CDLL(build_native("libopenr_kv.so"))
+    except Exception as exc:
+        log.warning(
+            "native KvStore engine unavailable (%r); dict store serves", exc
+        )
         return None
     lib.okv_create.restype = ctypes.c_void_p
     lib.okv_destroy.argtypes = [ctypes.c_void_p]

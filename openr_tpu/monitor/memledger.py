@@ -158,6 +158,12 @@ class MemLedger:
         self.last_refusal: Optional[Dict[str, Any]] = None
         self._capacity_override = capacity_bytes
         self._headroom_frac = 0.10
+        # devices the solver places state on (its mesh's), set by
+        # TpuSpfSolver; None = JAX's default device. capacity() and
+        # reconcile() sum the allocator's view over THESE only: on a
+        # four-chip host a single-device solve is admitted against one
+        # chip's HBM, not four
+        self._devices: Optional[Tuple[Any, ...]] = None
         self._externals: Dict[str, Callable[[], Dict[str, Any]]] = {}
         # per-structure live/peak, folded onto the fixed gauge vocabulary
         # (bench lines report the structure peak next to predict_fit)
@@ -398,15 +404,28 @@ class MemLedger:
             snap["external"] = external
         return snap
 
+    def set_devices(self, devices: Optional[Iterable[Any]]) -> None:
+        """Name the devices resident state lives on: the solver mesh's
+        devices, or None for JAX's default device."""
+        self._devices = tuple(devices) if devices is not None else None
+
+    def _solver_devices(self) -> Tuple[Any, ...]:
+        if self._devices is not None:
+            return self._devices
+        import jax
+
+        return tuple(jax.devices()[:1])
+
     # -- watermark reconciliation --------------------------------------
 
     def reconcile(self) -> Dict[str, Any]:
-        """Compare ledger live bytes against the backend's own view.
-        Preference order: allocator `memory_stats()` (real HBM
-        accounting, present on accelerator backends) > `jax.live_arrays()`
-        (logical live-buffer sum — the CPU-backend tier-1 path) >
-        unavailable (bump `drift_events`: the check could not be made,
-        which is itself a signal worth counting)."""
+        """Compare ledger live bytes against the backend's own view on
+        the solver's devices. Preference order: allocator
+        `memory_stats()` (real HBM accounting, present on accelerator
+        backends) > `jax.live_arrays()` (logical live-buffer sum — the
+        CPU-backend tier-1 path) > unavailable (bump `drift_events`: the
+        check could not be made, which is itself a signal worth
+        counting)."""
         backend_bytes: Optional[int] = None
         peak: Optional[int] = None
         source = "unavailable"
@@ -416,7 +435,7 @@ class MemLedger:
             stats_total = 0
             stats_seen = False
             peak_total = 0
-            for dev in jax.devices():
+            for dev in self._solver_devices():
                 stats = None
                 try:
                     stats = dev.memory_stats()
@@ -464,21 +483,20 @@ class MemLedger:
         self._headroom_frac = max(0.0, min(float(frac), 1.0))
 
     def capacity(self) -> Dict[str, Any]:
-        """Total device capacity and where the number came from:
-        `override` (config / tests) > `memory_stats` bytes_limit >
-        `fallback` (no capacity source — admission gates must fall back
-        to their static caps, e.g. `solver_apsp_max_nodes`)."""
+        """Capacity of the solver's devices and where the number came
+        from: `override` (config / tests) > `memory_stats` bytes_limit
+        summed over the solver's devices > `fallback` (no capacity
+        source — admission gates must fall back to their static caps,
+        e.g. `solver_apsp_max_nodes`)."""
         if self._capacity_override is not None:
             return {
                 "capacity_bytes": int(self._capacity_override),
                 "source": "override",
             }
         try:
-            import jax
-
             total = 0
             seen = False
-            for dev in jax.devices():
+            for dev in self._solver_devices():
                 try:
                     stats = dev.memory_stats()
                 except Exception:

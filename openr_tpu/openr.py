@@ -59,7 +59,11 @@ class OpenrDaemon:
         loop: Optional[asyncio.AbstractEventLoop] = None,
     ) -> None:
         from openr_tpu.kvstore import KvStoreTcpServer, TcpTransport
+        from openr_tpu.utils.compile_cache import ensure_compile_cache
 
+        # before the first compile: a restarted daemon loads its solver
+        # executables from the persistent cache instead of recompiling
+        ensure_compile_cache()
         self.config = config
         self._loop = loop
         # real-socket deployment: when KvStore peers over TCP, this daemon

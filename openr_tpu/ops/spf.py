@@ -748,7 +748,6 @@ def _tile_solver(key: Tuple, mesh):
     (n_pad,). (sources, src_l, hseg, w2, hcols, overloaded) -> (D, rounds)
     with D sharded P('batch', 'graph') — each device keeps only its
     [S/batch, n_pad/graph] tile."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     g, n_tile, e_tile, h, n_pad = key
@@ -763,7 +762,7 @@ def _tile_solver(key: Tuple, mesh):
         )
         return d, rounds
 
-    fn = shard_map(
+    fn = jax.shard_map(
         solve,
         mesh=mesh,
         in_specs=(
@@ -775,7 +774,7 @@ def _tile_solver(key: Tuple, mesh):
             P(),
         ),
         out_specs=(P("batch", "graph"), P()),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(fn)
 
@@ -805,7 +804,6 @@ def _tile_solver_warm(key: Tuple, mesh):
     own columns, reduced over 'batch'); num_changed is the replicated
     scalar popcount the host reads to size the compacted _delta_extract
     dispatch — the DeltaPath handshake is unchanged by the resharding."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     g, n_tile, e_tile, h, n_pad = key
@@ -881,7 +879,7 @@ def _tile_solver_warm(key: Tuple, mesh):
         )
         return d, rounds, inv_rounds, col_changed, num_changed
 
-    fn = shard_map(
+    fn = jax.shard_map(
         solve,
         mesh=mesh,
         in_specs=(
@@ -896,7 +894,7 @@ def _tile_solver_warm(key: Tuple, mesh):
             P("batch", "graph"),
         ),
         out_specs=(P("batch", "graph"), P(), P(), P("graph"), P()),
-        check_rep=False,
+        check_vma=False,
     )
     # d_prev is donated: the caller always replaces its resident handle
     # and the output tile matches its shape and sharding exactly

@@ -9,27 +9,24 @@ Operates directly on the CompiledGraph edge arrays (openr_tpu/ops/graph.py),
 so link flaps/metric changes are `set_weight` patches, mirroring the device
 path's weight-patch incrementality.
 
-Auto-builds openr_tpu/_native/libopenr_spf.so via `make` on first use;
-`native_spf_available()` gates callers, who fall back to the Python
-LinkState oracle when the toolchain is missing.
+Builds openr_tpu/_native/libopenr_spf.so through `make` on first use (a
+no-op when the binary is fresh); `native_spf_available()` gates callers,
+who fall back to the Python LinkState oracle when the toolchain is
+missing.
 """
 
 from __future__ import annotations
 
 import ctypes
-import os
-import subprocess
+import logging
 from typing import List, Optional, Set
 
 import numpy as np
 
 from openr_tpu.ops.graph import INF, CompiledGraph
+from openr_tpu.utils.native_build import build_native
 
-_NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(__file__)), "_native")
-_SO_PATH = os.path.join(_NATIVE_DIR, "libopenr_spf.so")
-_MAKE_DIR = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.dirname(__file__))), "native"
-)
+log = logging.getLogger(__name__)
 
 _lib: Optional[ctypes.CDLL] = None
 _load_attempted = False
@@ -41,18 +38,11 @@ def _load() -> Optional[ctypes.CDLL]:
         return _lib
     _load_attempted = True
     try:
-        if not os.path.exists(_SO_PATH):
-            # build only the SPF library: a failure in an unrelated native
-            # component (e.g. netlink, needing linux headers) must not
-            # disable the SPF baseline
-            subprocess.run(
-                ["make", "-C", _MAKE_DIR, "../openr_tpu/_native/libopenr_spf.so"],
-                check=True,
-                capture_output=True,
-                timeout=120,
-            )
-        lib = ctypes.CDLL(_SO_PATH)
-    except Exception:
+        lib = ctypes.CDLL(build_native("libopenr_spf.so"))
+    except Exception as exc:
+        log.warning(
+            "native SPF oracle unavailable (%r); Python oracle serves", exc
+        )
         return None
     i32p = ctypes.POINTER(ctypes.c_int32)
     u64p = ctypes.POINTER(ctypes.c_uint64)

@@ -75,7 +75,7 @@ def classify_solver_error(exc: BaseException) -> str:
 
     Classification is by exception type name + message substrings rather
     than concrete jax types: the supervisor must not import device
-    runtimes it is there to survive, and jax's exception taxonomy moves
+    runtimes it is there to survive, and jax's exception classes move
     between releases. Unknown errors classify as runtime (the safe bucket:
     retry-then-fallback)."""
     if isinstance(exc, SolveDeadlineExceeded):
@@ -100,6 +100,11 @@ def classify_solver_error(exc: BaseException) -> str:
         )
     ) or "DeviceCapacityError" in names:
         return FAULT_DEVICE_OOM
+    # BEFORE the compile bucket: a backend that is gone says so with
+    # wording that also mentions compilation ("UNAVAILABLE: TPU backend
+    # setup/compile error"), and jax 0.9's refusal to bring a backend up
+    # ("Unable to initialize backend 'tpu': ... TPU initialization
+    # failed") carries no device-loss word at all
     if any(
         hint in text
         for hint in (
@@ -110,6 +115,9 @@ def classify_solver_error(exc: BaseException) -> str:
             "halted",
             "data transfer",
             "device unavailable",
+            "unavailable:",
+            "unable to initialize backend",
+            "tpu initialization failed",
         )
     ):
         return FAULT_DEVICE_LOSS

@@ -1552,6 +1552,9 @@ class TpuSpfSolver(SpfSolver):
 
             mesh = resolve_mesh(mesh)
         self.mesh = mesh
+        self._ledger.set_devices(
+            mesh.devices.flat if mesh is not None else None
+        )
 
     def attach_supervisor(self, supervisor) -> None:
         """Wire the solver fault domain into non-solve device workloads
@@ -1565,17 +1568,6 @@ class TpuSpfSolver(SpfSolver):
         the first solve; cached solves created earlier (none in the
         supervised construction order) keep recording disabled."""
         self._recorder = recorder
-
-    def _apsp_dispatch(self, op: str, primary_fn, fallback_fn):
-        """ApspState dispatch hook: supervised when a supervisor is
-        attached (classified faults feed the shared breaker), bare
-        try/except with the numpy fallback otherwise."""
-        if self._supervisor is not None:
-            return self._supervisor.supervised_call(op, primary_fn, fallback_fn)
-        try:
-            return primary_fn(), False
-        except Exception:
-            return fallback_fn(), True
 
     def _area_solve(
         self, link_state: LinkState, node: str
@@ -1607,7 +1599,15 @@ class TpuSpfSolver(SpfSolver):
             warm_start=self.warm_start,
             apsp_max_nodes=self.apsp_max_nodes,
             apsp_audit_interval=self.apsp_audit_interval,
-            apsp_dispatch=self._apsp_dispatch,
+            # supervised: APSP closes run in the solver fault domain
+            # (classified faults feed the shared breaker). Bare: ApspState
+            # serves its numpy fallback itself, logged and counted
+            # (decision.spf.apsp_fallback_closes)
+            apsp_dispatch=(
+                self._supervisor.supervised_call
+                if self._supervisor is not None
+                else None
+            ),
             recorder=self._recorder,
             on_capacity_refusal=self._note_capacity_refusal,
         )
@@ -1864,6 +1864,7 @@ class TpuSpfSolver(SpfSolver):
         if new_mesh is None:
             return False
         self.mesh = new_mesh
+        self._ledger.set_devices(new_mesh.devices.flat)
         self._close_solves()
         counters = self._ensure_counters()
         self._bump("decision.spf.mesh_degradations")

@@ -303,7 +303,7 @@ class TeService(CountersMixin, HistogramsMixin):
             "scenarios": scenarios,
             "steps": result.steps,
             "best_step": result.best_step,
-            "backend": "cpu-fallback" if degraded else "primary",
+            "backend": "cpu" if degraded else "primary",
             "degraded": bool(degraded),
             "improved": bool(improved),
             "initial_max_util": round(float(result.initial_max_util), 6),

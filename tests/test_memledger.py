@@ -388,3 +388,68 @@ class TestPredictFitAccuracy:
             )
         finally:
             apsp.close()
+
+
+class TestSolverDeviceScope:
+    """capacity() and reconcile() sum the allocator's view over the
+    devices the solver uses — its mesh's, or the default device — never
+    over every device of the host: on a four-chip host a single-device
+    solve must be admitted against one chip's HBM."""
+
+    LIMIT = 16 << 30
+
+    @pytest.fixture
+    def stub_stats(self, monkeypatch):
+        import jax
+
+        dev_type = type(jax.devices()[0])
+        monkeypatch.setattr(
+            dev_type,
+            "memory_stats",
+            lambda self: {
+                "bytes_limit": TestSolverDeviceScope.LIMIT,
+                "bytes_in_use": 1000 + self.id,
+                "peak_bytes_in_use": 2000 + self.id,
+            },
+            raising=False,
+        )
+        ledger = get_ledger()
+        yield ledger
+        ledger.set_devices(None)
+
+    def test_default_device_only(self, stub_stats):
+        import jax
+
+        assert len(jax.devices()) == 8
+        TpuSpfSolver("g0_0")  # no mesh: the default device
+        cap = stub_stats.capacity()
+        assert cap == {"capacity_bytes": self.LIMIT, "source": "memory_stats"}
+        rec = stub_stats.reconcile()
+        assert rec["source"] == "memory_stats"
+        assert rec["backend_bytes"] == 1000 + jax.devices()[0].id
+        assert rec["backend_peak_bytes"] == 2000 + jax.devices()[0].id
+
+    def test_mesh_devices_and_degrade(self, stub_stats):
+        solver = TpuSpfSolver("g0_0", mesh=(2, 2))
+        ids = sorted(d.id for d in solver.mesh.devices.flat)
+        assert len(ids) == 4
+        assert stub_stats.capacity()["capacity_bytes"] == 4 * self.LIMIT
+        assert stub_stats.reconcile()["backend_bytes"] == sum(
+            1000 + i for i in ids
+        )
+        # a degraded mesh shrinks the admission budget with it
+        assert solver.degrade_mesh()
+        n = solver.mesh.devices.size
+        assert n < 4
+        assert stub_stats.capacity()["capacity_bytes"] == n * self.LIMIT
+
+    def test_predict_fit_gates_on_one_chip(self, stub_stats):
+        TpuSpfSolver("g0_0")
+        graph = compile_edges(wan_edges(64, degree=4, seed=1))
+        # [n_pad, n_pad] FW triple at n = 65536 is ~38 GB: fits the
+        # host's eight stubbed chips summed, not the one the solve uses
+        verdict = stub_stats.predict_fit(65536, "apsp")
+        assert verdict["source"] == "memory_stats"
+        assert verdict["capacity_bytes"] == self.LIMIT
+        assert verdict["fits"] is False
+        assert stub_stats.predict_fit(64, "sell", graph=graph)["fits"] is True

@@ -14,6 +14,7 @@ from openr_tpu.solver.supervisor import (
     FAULT_COMPILE,
     FAULT_DEADLINE,
     FAULT_DEVICE_LOSS,
+    FAULT_DEVICE_OOM,
     FAULT_RUNTIME,
     HALF_OPEN,
     OPEN,
@@ -99,6 +100,42 @@ class TestClassification:
     def test_device_loss_by_message(self):
         assert classify_solver_error(
             RuntimeError("DEVICE_LOST: chip 3 went away")
+        ) == FAULT_DEVICE_LOSS
+
+    def test_messages_jax_0_9_and_libtpu_raise(self):
+        """The exact wording jax 0.9.0 / libtpu 0.0.34 produce (chip probe
+        and dead-backend runs, PR 21) lands in the right bucket."""
+        # runtime allocation past HBM: a plain ValueError on the chip
+        assert classify_solver_error(
+            ValueError(
+                "RESOURCE_EXHAUSTED: Error allocating device buffer: "
+                "Attempting to allocate 4.00G. That was not possible. "
+                "There are 3.75G free.; (0x0x0_HBM0)"
+            )
+        ) == FAULT_DEVICE_OOM
+        # a primitive with no TPU lowering
+        assert classify_solver_error(
+            NotImplementedError(
+                "MLIR translation rule for primitive 'eig' not found for "
+                "platform tpu"
+            )
+        ) == FAULT_COMPILE
+        # a handle used after its buffer was donated
+        assert classify_solver_error(
+            RuntimeError("Array has been deleted with shape=float32[1024].")
+        ) == FAULT_RUNTIME
+        # a backend that is not there: both wordings mention neither
+        # "device lost" nor a loss code, one of them mentions "compile"
+        assert classify_solver_error(
+            RuntimeError(
+                "UNAVAILABLE: TPU backend setup/compile error (Unavailable)."
+            )
+        ) == FAULT_DEVICE_LOSS
+        assert classify_solver_error(
+            RuntimeError(
+                "Unable to initialize backend 'tpu': UNKNOWN: TPU "
+                "initialization failed: No jellyfish device found."
+            )
         ) == FAULT_DEVICE_LOSS
 
     def test_compile_by_message_and_type(self):

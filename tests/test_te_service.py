@@ -248,7 +248,7 @@ class TestFaultDomain:
             report = svc.optimize({"demands": spec, "steps": 40, "seed": 0})
             assert inj.fired("te.optimize") >= 1
         assert report["degraded"] is True
-        assert report["backend"] == "cpu-fallback"
+        assert report["backend"] == "cpu"
         # the degraded path runs the identical optimization: still a
         # strict improvement on the fixture
         assert report["improved"] is True
