@@ -1,0 +1,90 @@
+"""The plain reference: what the vantage's routing table has to hold.
+
+Shortest paths with equal-cost multipath (upstream's SP_ECMP): for every
+other node's prefix, the set of the vantage's adjacencies that lie on some
+shortest path to that node, each with the path's metric. Distances come
+from scipy's Dijkstra, run from the vantage and from each of its
+neighbours: neighbour `u` is a first hop toward `d` exactly where
+metric(vantage, u) + dist_u(d) = dist_vantage(d). It imports nothing of
+the program and reads only `chipbench.lsdb.Lsdb`.
+
+A table is `{prefix: frozenset((address, interface, metric), ...)}`: the
+form in which `compare.py` also reads the platform agent's table.
+"""
+
+from __future__ import annotations
+
+from typing import Dict, FrozenSet, Iterable, Tuple
+
+import numpy as np
+from scipy.sparse import csr_matrix
+from scipy.sparse.csgraph import dijkstra
+
+from chipbench.lsdb import Lsdb, if_name, nexthop_v4
+
+NextHops = FrozenSet[Tuple[str, str, int]]
+Table = Dict[str, NextHops]
+
+
+class Reference:
+    """The vantage's table on `lsdb` as it stands; `refresh` after the
+    LSDB moved."""
+
+    def __init__(self, lsdb: Lsdb, vantage: str) -> None:
+        self.lsdb, self.vantage = lsdb, vantage
+        self.number = {node: i for i, node in enumerate(lsdb.nodes)}
+        # CSR by hand, a row per node in the order of `lsdb.nodes`: `slot`
+        # is where each directed link's weight sits in `graph.data`
+        self.slot: Dict[Tuple[str, str], int] = {}
+        indices, indptr = [], [0]
+        for node in lsdb.nodes:
+            for peer in lsdb.metric[node]:
+                self.slot[node, peer] = len(indices)
+                indices.append(self.number[peer])
+            indptr.append(len(indices))
+        n = len(lsdb.nodes)
+        self.graph = csr_matrix(
+            (np.ones(len(indices)), np.array(indices), np.array(indptr)),
+            shape=(n, n),
+        )
+        self.refresh(lsdb.nodes)
+        self.neighbours = list(lsdb.metric[vantage])
+        self.hops = [
+            (nexthop_v4(vantage, peer), if_name(vantage, peer))
+            for peer in self.neighbours
+        ]
+        self.prefixes = [lsdb.prefix_of[node] for node in lsdb.nodes]
+
+    def refresh(self, nodes: Iterable[str]) -> None:
+        """Re-reads the metrics of the links out of `nodes`."""
+        for node in nodes:
+            for peer, metric in self.lsdb.metric[node].items():
+                self.graph.data[self.slot[node, peer]] = metric
+
+    def table(self) -> Table:
+        """Every reachable node's prefix -> its ECMP next-hop set."""
+        me = self.number[self.vantage]
+        sources = [me] + [self.number[peer] for peer in self.neighbours]
+        dist = dijkstra(self.graph, directed=True, indices=sources)
+        mask = np.zeros(dist.shape[1], dtype=np.int64)
+        for i, peer in enumerate(self.neighbours):
+            through = self.lsdb.metric[self.vantage][peer] + dist[1 + i]
+            mask |= (through == dist[0]).astype(np.int64) << i
+        sets: Dict[Tuple[int, int], NextHops] = {}
+        table: Table = {}
+        for node in np.flatnonzero(np.isfinite(dist[0])).tolist():
+            if node == me:
+                continue
+            key = (int(mask[node]), int(dist[0][node]))
+            if key not in sets:
+                sets[key] = frozenset(
+                    (address, iface, key[1])
+                    for i, (address, iface) in enumerate(self.hops)
+                    if key[0] >> i & 1
+                )
+            table[self.prefixes[node]] = sets[key]
+        return table
+
+
+def route_table(lsdb: Lsdb, vantage: str) -> Table:
+    return Reference(lsdb, vantage).table()
