@@ -491,11 +491,14 @@ def check_served_counters(
             "incremental_solves >= 20",
         )
         check(c("decision.spf.delta_columns", 0) > 0, "delta_columns > 0")
-        for phase in ("prepare", "h2d", "relax", "delta_extract", "d2h"):
+        for phase in (
+            "refresh", "prepare", "h2d", "relax", "delta_extract",
+            "mirror_patch", "d2h",
+        ):
             h = hists.get(f"decision.spf.phase.{phase}_ms") or {}
             check(
                 h.get("count", 0) > 0,
-                f"decision.spf.phase.{phase}_ms sampled "
+                f"decision.spf.phase.{phase}_ms recorded "
                 f"({h.get('count', 0)}x, avg {h.get('avg', 0):.2f}ms)",
             )
     else:

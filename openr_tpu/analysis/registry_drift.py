@@ -174,10 +174,26 @@ def collect_emitted_names(
     return found
 
 
+def _stage_histogram(node) -> Optional[str]:
+    """`stage("<name>", <histograms>, ...)` (monitor/spans.py) records into
+    the histogram `<name>_ms`; a stage with no histograms records none."""
+    if not (
+        isinstance(node, ast.Call)
+        and isinstance(node.func, ast.Name)
+        and node.func.id == "stage"
+        and len(node.args) >= 2
+        and isinstance(node.args[0], ast.Constant)
+        and isinstance(node.args[0].value, str)
+    ):
+        return None
+    return f"{node.args[0].value}_ms"
+
+
 def collect_histogram_names(
     ctx: AnalysisContext,
 ) -> List[Tuple[str, SourceFile, int]]:
-    """Literal first args of _observe/_timer anywhere in scope."""
+    """Literal first args of _observe/_timer anywhere in scope, and the
+    histograms of the stages."""
     found = []
     for sf in ctx.files:
         for node in walk_nodes(sf.tree):
@@ -190,6 +206,8 @@ def collect_histogram_names(
                 and isinstance(node.args[0].value, str)
             ):
                 found.append((node.args[0].value, sf, node.lineno))
+            elif (name := _stage_histogram(node)) is not None:
+                found.append((name, sf, node.lineno))
     return found
 
 
@@ -208,6 +226,8 @@ def _string_universe(ctx: AnalysisContext) -> Tuple[Set[str], Set[str]]:
             ):
                 if NAME_RE.match(node.value):
                     exact.add(node.value)
+            elif (name := _stage_histogram(node)) is not None:
+                exact.add(name)
             elif isinstance(node, ast.JoinedStr) and node.values:
                 first = node.values[0]
                 if isinstance(first, ast.Constant) and isinstance(

@@ -58,6 +58,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from openr_tpu.monitor.spans import stage
 from openr_tpu.ops.graph import INF, CompiledGraph
 from openr_tpu.utils.shape_contract import shape_contract
 
@@ -68,15 +69,6 @@ _FW_BLOCK = 128
 # fixed warm-patch width: events increasing more (u, v) pair minima than
 # this fall back to a cold close (the ApspState staleness guard)
 _APSP_PATCH_SLOTS = 64
-
-
-def _profile_span(name: str):
-    """Named `jax.profiler.TraceAnnotation` around an APSP dispatch seam
-    (same convention as ops/spf.py:profile_span): on-demand profiling
-    windows label the blocked-FW dispatches; no-op-cheap otherwise."""
-    from jax.profiler import TraceAnnotation
-
-    return TraceAnnotation(name)
 
 
 def fw_block_shape(n_pad: int) -> Tuple[int, int]:
@@ -168,7 +160,7 @@ def _fw_solver(key: Tuple):
     def dispatch(w, allow):
         # named profiling seam: on-demand jax.profiler windows
         # (monitor/profiling.py) show the cold close under this label
-        with _profile_span(f"apsp.fw_close.{nb}x{bsz}"):
+        with stage(f"apsp.fw_close.{nb}x{bsz}"):
             return fit(w, allow)
 
     return dispatch
@@ -221,7 +213,7 @@ def _fw_seed_solver(key: Tuple):
     fit = jax.jit(seed)
 
     def dispatch(d_prev, w_new, inc_u, inc_v, inc_w):
-        with _profile_span(f"apsp.fw_seed.{nb}x{bsz}"):
+        with stage(f"apsp.fw_seed.{nb}x{bsz}"):
             return fit(d_prev, w_new, inc_u, inc_v, inc_w)
 
     return dispatch
@@ -301,7 +293,7 @@ def _fw_reclose_solver(key: Tuple):
     fit = jax.jit(reclose)
 
     def dispatch(d, allow, dirty):
-        with _profile_span(f"apsp.fw_reclose.{nb}x{bsz}"):
+        with stage(f"apsp.fw_reclose.{nb}x{bsz}"):
             return fit(d, allow, dirty)
 
     return dispatch

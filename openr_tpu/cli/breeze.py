@@ -342,8 +342,6 @@ def cmd_decision(client: BlockingCtrlClient, args) -> None:
             f"flight recorder: {stats.get('recorded', 0)} recorded = "
             f"{stats.get('retained', 0)} retained + "
             f"{stats.get('evicted', 0)} evicted; "
-            f"{stats.get('sampled_solves', 0)} sampled "
-            f"(every {stats.get('sample_every', 0)}th), "
             f"ring {stats.get('ring_size', 0)}/area"
         )
         rows = []

@@ -194,11 +194,9 @@ class DecisionConfigSection:
     solver_apsp: bool = True
     solver_apsp_max_nodes: int = 4096
     # solver flight recorder (docs/Monitoring.md "Flight recorder &
-    # profiling"): per-area SolveTrace ring bound, sampled phase-timing
-    # cadence (every Nth solve takes phase-seam barriers; 0 disables),
-    # and an optional forensics-dump artifact directory
+    # profiling"): per-area SolveTrace ring bound and an optional
+    # forensics-dump artifact directory
     solver_trace_ring: int = 64
-    solver_trace_sample_every: int = 16
     solver_forensics_dir: Optional[str] = None
     # device-memory observatory (docs/Monitoring.md "Device-memory
     # observatory"): capacity admission keeps this fraction of device

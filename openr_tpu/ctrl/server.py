@@ -39,6 +39,7 @@ from typing import Any, Callable, Dict, List, Optional
 
 from openr_tpu.kvstore import wire
 from openr_tpu.messaging import QueueClosedError
+from openr_tpu.monitor.spans import stage
 from openr_tpu.testing.faults import fault_point
 from openr_tpu.types import (
     ADJ_DB_MARKER,
@@ -144,6 +145,9 @@ class CtrlServer:
         self.stream_manager = stream_manager
         self.admission = admission
         self.journal = journal
+        # this module's own stages (ctrl.decode_ms); the daemon registers
+        # the server with its monitor like any other module
+        self.histograms: Dict = {}
         self._route_updates = route_updates
         self._own_stream_manager = False
         # on-demand jax profiling window (monitor/profiling.py), built
@@ -375,7 +379,7 @@ class CtrlServer:
         merged = merge_module_histograms(
             (
                 m
-                for m in (self.decision, self.fib, self.link_monitor)
+                for m in (self.decision, self.fib, self.link_monitor, self)
                 if m is not None
             ),
             reset=reset,
@@ -403,7 +407,7 @@ class CtrlServer:
     def m_getSolveTraces(self, params) -> Dict[str, Any]:
         """Flight-recorder read surface (docs/Monitoring.md "Flight
         recorder & profiling"): per-area SolveTrace rings (event class,
-        layout, warm/cold, per-phase ms on sampled solves), ring/eviction
+        layout, warm/cold, per-phase ms), ring/eviction
         accounting, and the forensics-dump index. params: area (filter),
         last_n (most recent N)."""
         assert self.decision is not None, "decision module not attached"
@@ -732,10 +736,11 @@ class CtrlServer:
     def m_setKvStoreKeyVals(self, params) -> None:
         assert self.kvstore is not None
         area = params.get("area", "0")
-        key_vals: KeyVals = {
-            k: _value_from_json(v)
-            for k, v in params.get("key_vals", {}).items()
-        }
+        with stage("ctrl.decode", self.histograms):
+            key_vals: KeyVals = {
+                k: _value_from_json(v)
+                for k, v in params.get("key_vals", {}).items()
+            }
         self.kvstore.db(area).set_key_vals(key_vals)
 
     def m_getKvStorePeers(self, params) -> Dict[str, Any]:
@@ -941,7 +946,7 @@ class CtrlServer:
 
     def m_explainRoute(self, params) -> Dict[str, Any]:
         """Provenance chain: route → contributing prefix/adjacency keys →
-        originating publication → (when sampled) the SolveTrace that
+        originating publication → the SolveTrace that
         computed it. params: prefix (required), at."""
         journal = self._journal_or_error()
         if journal is None:
@@ -953,7 +958,7 @@ class CtrlServer:
             prefix, float(at) if at is not None else None
         )
         out["enabled"] = True
-        # link the nearest sampled SolveTrace at-or-before the replayed
+        # link the nearest SolveTrace at-or-before the replayed
         # instant (the flight recorder lives in Decision, not the journal)
         out["solve_trace"] = None
         if self.decision is not None and out.get("found"):

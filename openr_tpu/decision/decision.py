@@ -27,7 +27,7 @@ from typing import Dict, List, Optional, Set
 
 from openr_tpu.lsdb import LinkState, PrefixState
 from openr_tpu.messaging import QueueClosedError, RQueue, ReplicateQueue
-from openr_tpu.monitor.spans import Span
+from openr_tpu.monitor.spans import Span, stage
 from openr_tpu.solver import (
     DecisionRouteDb,
     DecisionRouteUpdate,
@@ -153,12 +153,9 @@ class DecisionConfig:
     solver_apsp: bool = True
     solver_apsp_max_nodes: int = 4096
     # flight recorder (solver/flight_recorder.py, docs/Monitoring.md):
-    # per-area SolveTrace ring bound, the sampled phase-timing cadence
-    # (every Nth solve takes block_until_ready barriers at phase seams;
-    # 0 disables sampling), and an optional directory forensics dumps
-    # are written to as JSON artifacts
+    # per-area SolveTrace ring bound, and an optional directory forensics
+    # dumps are written to as JSON artifacts
     solver_trace_ring: int = 64
-    solver_trace_sample_every: int = 16
     solver_forensics_dir: Optional[str] = None
     # device-memory observatory (monitor/memledger.py,
     # docs/Monitoring.md "Device-memory observatory"): capacity admission
@@ -360,9 +357,6 @@ class Decision(CountersMixin, HistogramsMixin):
                         audit_interval=config.solver_audit_interval,
                         mesh_degrade=config.solver_mesh_degrade,
                         trace_ring_size=config.solver_trace_ring,
-                        trace_sample_every=(
-                            config.solver_trace_sample_every
-                        ),
                         forensics_dir=config.solver_forensics_dir,
                     ),
                     watchdog=watchdog,
@@ -382,11 +376,16 @@ class Decision(CountersMixin, HistogramsMixin):
         self._full_db_entries: Dict[tuple, Dict] = {}
         self.route_db = DecisionRouteDb()
         self.rib_policy: Optional[RibPolicy] = None
+        self.counters: Dict[str, int] = {}
+        self.histograms: Dict = {}
         # DeltaPath: builds DecisionRouteUpdates directly from the device
         # delta's changed destinations when the event qualifies, falling
         # back to the classic full build + get_route_delta diff
-        self._delta_builder = DeltaRouteBuilder(self.solver)
+        self._delta_builder = DeltaRouteBuilder(self.solver, self.histograms)
         self._pending = _PendingUpdates()
+        # the debounce wait as a profiler stage: begun where the timer is
+        # armed, ended where it fires, both on the loop's thread
+        self._debounce_stage: Optional[stage] = None
         self._rebuild_debounce = AsyncDebounce(
             config.debounce_min,
             config.debounce_max,
@@ -398,8 +397,6 @@ class Decision(CountersMixin, HistogramsMixin):
         self._retry_timer: Optional[asyncio.TimerHandle] = None
         self._rib_policy_timer: Optional[asyncio.TimerHandle] = None
         self._task: Optional[asyncio.Task] = None
-        self.counters: Dict[str, int] = {}
-        self.histograms: Dict = {}
         if isinstance(self.solver, SolverSupervisor):
             # breaker trips, probes and audits happen in the BACKGROUND,
             # between rebuilds — the supervisor records straight into this
@@ -450,6 +447,7 @@ class Decision(CountersMixin, HistogramsMixin):
             self._task.cancel()
             self._task = None
         self._rebuild_debounce.cancel()
+        self._end_debounce_stage()
         if self._cold_start_timer is not None:
             self._cold_start_timer.cancel()
             self._cold_start_timer = None
@@ -505,7 +503,26 @@ class Decision(CountersMixin, HistogramsMixin):
     # small batches gain nothing over the incremental diff path
     _BULK_ADJ_THRESHOLD = 8
 
+    def _next_build(self) -> int:
+        """The number the route build for what is being ingested now will
+        carry: the identifier its profiler stages share."""
+        return self.counters.get("decision.route_build_runs", 0) + 1
+
     def process_publication(self, publication: Publication) -> None:
+        if publication.ts_monotonic is not None:
+            # KvStore's publish stamp -> taken up here: the queue hop
+            self._observe(
+                "decision.queue_wait_ms",
+                (time.monotonic() - publication.ts_monotonic) * 1e3,
+            )
+        with stage("decision.ingest", self.histograms, self._next_build()):
+            changed = self._ingest(publication)
+        if changed:
+            self._schedule_rebuild()
+
+    def _ingest(self, publication: Publication) -> bool:
+        """The publication's pass through LinkState / PrefixState; whether
+        routes have to be rebuilt for it."""
         area = publication.area
         link_state = self.area_link_states.get(area)
         if link_state is None:
@@ -560,8 +577,7 @@ class Decision(CountersMixin, HistogramsMixin):
                     self._pending.dirty_prefixes |= dirty
                     self._pending.apply(None, publication)
 
-        if changed:
-            self._schedule_rebuild()
+        return changed
 
     def _bulk_adj_keys(
         self, publication: Publication, link_state: LinkState
@@ -741,6 +757,15 @@ class Decision(CountersMixin, HistogramsMixin):
         if self._cold_start_until is not None:
             return  # waiting for LSDB fill after restart
         self._rebuild_debounce()
+        if self._debounce_stage is None:
+            self._debounce_stage = stage(
+                "decision.debounce", build=self._next_build()
+            ).start()
+
+    def _end_debounce_stage(self) -> None:
+        if self._debounce_stage is not None:
+            self._debounce_stage.stop()
+            self._debounce_stage = None
 
     # ------------------------------------------------------------------
     # route computation + emission
@@ -754,6 +779,7 @@ class Decision(CountersMixin, HistogramsMixin):
         from the changed destinations (DeltaRouteBuilder) — no full table
         rebuild, no full-db diff — and streamed into Fib's incremental
         programming path like any other update."""
+        self._end_debounce_stage()
         if self._cold_start_until is not None:
             return
         if not self._pending.needs_route_update:
@@ -765,7 +791,12 @@ class Decision(CountersMixin, HistogramsMixin):
         self._bump("decision.batched_updates", self._pending.count)
         self._pending.reset()
         self._bump("decision.route_build_runs")
+        build = self.counters["decision.route_build_runs"]
+        recorder = getattr(self.solver, "recorder", None)
+        if recorder is not None:
+            recorder.build = build  # tags the solve's phase spans
         if span is not None:
+            span.build = build
             # oldest-event recv -> debounce fire, on the monotonic clock
             self._observe("decision.debounce_ms", span.mark("decision.debounce"))
 
@@ -779,6 +810,7 @@ class Decision(CountersMixin, HistogramsMixin):
                 dirty_prefixes=dirty_prefixes,
                 force_full=force_full,
                 policy_fn=self._rib_policy_entry_fn(),
+                build=build,
             )
         except Exception:
             # rebuild_routes runs from a loop timer callback: an uncaught
@@ -802,6 +834,17 @@ class Decision(CountersMixin, HistogramsMixin):
             )
             return
         build_ms = (time.perf_counter() - t0) * 1e3
+        with stage("decision.emit", self.histograms, build):
+            self._emit(
+                new_db, delta, used_delta, build_ms, span, perf_events
+            )
+
+    def _emit(
+        self, new_db, delta, used_delta, build_ms, span, perf_events
+    ) -> None:
+        """After the build: its histograms, the solver's counters merged
+        into this module's, the delta build's shadow audit, and the push
+        to Fib."""
         self._observe("decision.route_build_ms", build_ms)
         if used_delta:
             self._bump("decision.route_build_delta_runs")

@@ -42,6 +42,7 @@ from typing import Dict, List, Optional, Set, Tuple
 
 from openr_tpu.messaging import ReplicateQueue
 from openr_tpu.monitor.monitor import LogSample
+from openr_tpu.monitor.spans import stage
 from openr_tpu.testing.faults import fault_point
 from openr_tpu.utils.ownership import owned_by
 from openr_tpu.types import (
@@ -538,19 +539,23 @@ class KvStoreDb(CountersMixin, HistogramsMixin):
         the producing module (LinkMonitor's spark→advertise chain) — ride
         the local publication so Decision's span starts at the Spark event,
         not at this store's publish stamp."""
-        updates = merge_key_values(self.store, key_vals, self.params.filters)
-        self._update_ttl_countdown(updates)
-        if updates:
-            self._bump("kvstore.updated_key_vals", len(updates))
-            flood = self._damp_updates(updates)
-            if flood:
-                self.flood_publication(
-                    Publication(
-                        key_vals=flood,
-                        area=self.area,
-                        span_stages=span_stages,
+        # merge + publish, to the push onto the subscribers' queues
+        with stage("kvstore.set_key_vals", self.histograms):
+            updates = merge_key_values(
+                self.store, key_vals, self.params.filters
+            )
+            self._update_ttl_countdown(updates)
+            if updates:
+                self._bump("kvstore.updated_key_vals", len(updates))
+                flood = self._damp_updates(updates)
+                if flood:
+                    self.flood_publication(
+                        Publication(
+                            key_vals=flood,
+                            area=self.area,
+                            span_stages=span_stages,
+                        )
                     )
-                )
         return updates
 
     def handle_set_key_vals(

@@ -35,6 +35,7 @@ from openr_tpu.monitor import (
     Watchdog,
     WatchdogConfig,
 )
+from openr_tpu.monitor.spans import GC_WATCH
 from openr_tpu.platform import MockFibHandler
 from openr_tpu.prefixmanager import PrefixManager, PrefixManagerConfig
 from openr_tpu.spark.spark import Spark, SparkConfig as SparkModuleConfig
@@ -333,7 +334,6 @@ class OpenrDaemon:
                 solver_apsp=dc.solver_apsp,
                 solver_apsp_max_nodes=dc.solver_apsp_max_nodes,
                 solver_trace_ring=dc.solver_trace_ring,
-                solver_trace_sample_every=dc.solver_trace_sample_every,
                 solver_forensics_dir=dc.solver_forensics_dir,
                 solver_mem_headroom_frac=dc.solver_mem_headroom_frac,
                 solver_mem_capacity_bytes=dc.solver_mem_capacity_bytes,
@@ -485,6 +485,10 @@ class OpenrDaemon:
             ("ctrl_stream", self.stream_manager),
             ("ctrl_admission", self.admission),
             ("journal", self.journal),
+            # ctrl.decode_ms, and the process's full collections
+            # (process.gc_ms): the one watch every daemon of a process shares
+            ("ctrl", self.ctrl_server),
+            ("process", GC_WATCH),
         ):
             self.monitor.register_module(name, module)
 
@@ -497,6 +501,7 @@ class OpenrDaemon:
             # bound (possibly ephemeral) port goes into Spark's handshake
             await self.kvstore_server.start()
             self.spark.config.kvstore_cmd_port = self.kvstore_server.port
+        GC_WATCH.acquire(self)
         self.monitor.start()
         self.exporter.start()  # push loop only when a sink is configured
         if self.watchdog is not None:
@@ -576,6 +581,7 @@ class OpenrDaemon:
             self.watchdog.stop()
         self.exporter.stop()
         self.monitor.stop()
+        GC_WATCH.release(self)
         self.config_store.stop()
         for q in (
             self.route_updates_queue,

@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from openr_tpu.monitor.spans import stage
 from openr_tpu.ops.graph import INF, CompiledGraph, _next_bucket
 from openr_tpu.testing.faults import fault_point
 from openr_tpu.utils.shape_contract import shape_contract
@@ -1072,7 +1073,7 @@ def sell_fixpoint_masked(
         ov = jnp.asarray(overloaded)
     if d_prev is not None:
         fn = _sell_solver_vw_warm(sell.shape_key(), mesh)
-        with profile_span("spf.ksp_masked_warm"):
+        with stage("spf.ksp_masked_warm"):
             return fn(
                 jnp.asarray(sources, dtype=jnp.int32),
                 nbrs,
@@ -1082,22 +1083,11 @@ def sell_fixpoint_masked(
                 d_prev,
             )
     fn = _sell_solver_vw(sell.shape_key(), mesh)
-    with profile_span("spf.ksp_masked"):
+    with stage("spf.ksp_masked"):
         return fn(
             jnp.asarray(sources, dtype=jnp.int32), nbrs, wgs, tuple(masks), ov
         )
 
-
-
-def profile_span(name: str):
-    """Named `jax.profiler.TraceAnnotation` around a kernel dispatch seam:
-    inside an on-demand profiling window (monitor/profiling.py) the
-    captured TensorBoard trace shows the dispatch under this label; with
-    no profiler active the annotation is a C++-side no-op, cheap enough
-    for the serving path."""
-    from jax.profiler import TraceAnnotation
-
-    return TraceAnnotation(name)
 
 
 def sell_fixpoint(
@@ -1108,7 +1098,7 @@ def sell_fixpoint(
 ) -> jnp.ndarray:
     """Distance matrix D [S, N] via the sliced-ELL pull relaxation."""
     fn = _sell_solver(sell.shape_key(), None)
-    with profile_span("spf.sell_fixpoint"):
+    with stage("spf.sell_fixpoint"):
         return fn(
             jnp.asarray(sources, dtype=jnp.int32),
             tuple(jnp.asarray(a) for a in sell.nbr),
@@ -1130,7 +1120,7 @@ def batched_spf(graph: CompiledGraph, source_rows: np.ndarray) -> jnp.ndarray:
         return sell_fixpoint(
             graph.sell, source_rows, graph.sell.wg, graph.overloaded
         )
-    with profile_span("spf.batched_cold"):
+    with stage("spf.batched_cold"):
         return _bf_fixpoint(
             jnp.asarray(source_rows, dtype=jnp.int32),
             jnp.asarray(graph.src),
@@ -1149,7 +1139,7 @@ def batched_spf_vw(
     With a mesh, sources and weight rows shard over 'batch' (S must be a
     multiple of the batch-axis size)."""
     fault_point("ops.spf.batched_spf_vw", graph)
-    with profile_span("spf.batched_vw"):
+    with stage("spf.batched_vw"):
         return _bf_vw_solver(mesh)(
             jnp.asarray(source_rows, dtype=jnp.int32),
             jnp.asarray(graph.src),

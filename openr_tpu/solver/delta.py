@@ -42,6 +42,7 @@ from __future__ import annotations
 import logging
 from typing import Callable, Dict, Optional, Set, Tuple
 
+from openr_tpu.monitor.spans import stage
 from openr_tpu.solver.routes import (
     DecisionRouteDb,
     DecisionRouteUpdate,
@@ -59,8 +60,10 @@ class DeltaRouteBuilder:
     class qualifies, else the classic full build + diff. Owned by Decision;
     drivable synchronously by tests without an event loop."""
 
-    def __init__(self, solver) -> None:
+    def __init__(self, solver, histograms: Optional[Dict] = None) -> None:
         self.solver = solver
+        # the owner's histogram dict (decision.delta_build_ms)
+        self.histograms = histograms
         # label -> set of nodes advertising it (collision detection for the
         # partial node-label rebuild); rebuilt lazily after any full build,
         # so it can never span a structural change
@@ -81,11 +84,13 @@ class DeltaRouteBuilder:
         dirty_prefixes: Set = frozenset(),
         force_full: bool = False,
         policy_fn: Optional[Callable] = None,
+        build: Optional[int] = None,
     ) -> Tuple[Optional[DecisionRouteDb], Optional[DecisionRouteUpdate], bool]:
         """Returns (new_db, update, used_delta). new_db is None when this
         node is in no area's graph (build_route_db contract). policy_fn, if
         given, is applied to every (re)computed unicast entry before
-        diffing — the RibPolicy hook."""
+        diffing — the RibPolicy hook. `build` is Decision's number for
+        this route build, the tag of its profiler stages."""
         self.last_error = None
         changed_nodes: Optional[Set[str]] = None
         try:
@@ -105,15 +110,18 @@ class DeltaRouteBuilder:
             and (not lfa_on or (lfa_ready is not None and lfa_ready()))
         ):
             try:
-                out = self._build_delta(
-                    my_node_name,
-                    area_link_states,
-                    prefix_state,
-                    prev_db,
-                    changed_nodes,
-                    set(dirty_prefixes),
-                    policy_fn,
-                )
+                # the solver has returned: DeltaPath's route objects and
+                # their comparison with the previous db
+                with stage("decision.delta_build", self.histograms, build):
+                    out = self._build_delta(
+                        my_node_name,
+                        area_link_states,
+                        prefix_state,
+                        prev_db,
+                        changed_nodes,
+                        set(dirty_prefixes),
+                        policy_fn,
+                    )
                 if out is not None:
                     self.delta_builds += 1
                     return out[0], out[1], True

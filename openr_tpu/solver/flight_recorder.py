@@ -1,4 +1,4 @@
-"""Solver flight recorder: per-solve traces, sampled phase timing, and
+"""Solver flight recorder: per-solve traces, phase timing, and
 fault-forensics dumps.
 
 Every solver path this repo has shipped — cold, warm-invalidation,
@@ -12,16 +12,16 @@ needed to diagnose it. This module is the missing observability layer:
   - **SolveTrace** — one structured record per supervised solve: event
     class, layout kind (sell / bf / tile2d / cpu), warm/cold disposition,
     wall time, fixpoint rounds, transfer bytes, compile-cache deltas,
-    breaker state, and (on sampled solves) a per-phase millisecond
-    breakdown.
-  - **PhaseClock** — the sampled phase timer. Every `sample_every`-th
-    solve gets a live clock whose `seam(...)` calls take
-    `block_until_ready` barriers at the phase boundaries, so the
-    recorded per-phase times are real device time; the other solves get
-    the shared `NULL_CLOCK`, whose `seam` is a single attribute check —
-    the unsampled hot path never touches a device buffer it would not
-    have touched anyway (the probe-effect contract,
-    tests/test_flight_recorder.py).
+    breaker state, and a per-phase millisecond breakdown of the host's
+    time.
+  - **PhaseClock** — the phase timer of every solve. `enter(phase)` is a
+    seam: it ends the phase in progress and begins the next, two clock
+    reads and a profiler annotation, and never waits for the device. The
+    seams of the solve path lie where the path blocks on a device value
+    anyway (a round count, the changed-column count, the copied
+    columns), so `relax` is the host's wait for the device solve and
+    `h2d` means "uploads issued and program enqueued". Device time per
+    event is the profiler trace's to give (docs/Monitoring.md).
   - **FlightRecorder** — a bounded per-area ring of traces with exact
     eviction accounting (`recorded == retained + evicted`), plus the
     forensics side: `dump(reason)` snapshots the rings, the solver
@@ -44,58 +44,81 @@ import time
 from dataclasses import asdict, dataclass, field
 from typing import Any, Deque, Dict, List, Optional, Tuple
 
+from openr_tpu.monitor.spans import stage
+
 # phase vocabulary, in dispatch order. The fused warm kernels run the
 # invalidation-mark fixpoint and (on the tiled layout) the halo exchange
 # inside the same dispatch as the relax rounds, so those phases are
 # attributed inside `relax` with the per-trace round/exchange gauges
 # splitting them (docs/Monitoring.md "Flight recorder & profiling").
-PHASES = ("prepare", "h2d", "relax", "delta_extract", "d2h")
+# `refresh` comes before the solve's own clock (`solve_ms`) starts, and
+# `d2h` after it stopped: the mirror is fetched when a reader wants it.
+PHASES = (
+    "refresh",
+    "prepare",
+    "h2d",
+    "relax",
+    "delta_extract",
+    "mirror_patch",
+    "d2h",
+)
 
 # phase -> registry histogram (docs/Monitoring.md histogram table); the
 # full names live here as literals so the doc rows stay pinned to code
-# by the registry-drift analyzer's string universe
+# by the registry-drift analyzer's string universe. A phase's profiler
+# span is its histogram's name without `_ms` (monitor/spans.py).
 PHASE_HISTOGRAMS: Dict[str, str] = {
+    "refresh": "decision.spf.phase.refresh_ms",
     "prepare": "decision.spf.phase.prepare_ms",
     "h2d": "decision.spf.phase.h2d_ms",
     "relax": "decision.spf.phase.relax_ms",
     "delta_extract": "decision.spf.phase.delta_extract_ms",
+    "mirror_patch": "decision.spf.phase.mirror_patch_ms",
     "d2h": "decision.spf.phase.d2h_ms",
 }
 
 
+def phase_stage(phase: str, build: Optional[int] = None) -> stage:
+    """The started stage of one solve phase; its milliseconds go to the
+    phase's histogram through the recorder (`observe_phase`), which owns
+    no registry."""
+    return stage(PHASE_HISTOGRAMS[phase][: -len("_ms")], build=build).start()
+
+
 class PhaseClock:
-    """Per-solve phase timer; a live one exists only on sampled solves.
+    """Per-solve phase timer: the phases tile the solve.
 
-    `seam(phase, *values)` closes the current phase: it blocks on every
-    value that exposes `block_until_ready` (so device execution up to the
-    seam is inside the measured window, not smeared into the next phase
-    by async dispatch) and credits the elapsed milliseconds to `phase`.
-    The shared NULL_CLOCK instance short-circuits on `self.sampled`."""
+    `enter(phase)` is a seam: one clock reading ends the phase in
+    progress, credited to `phases`, and begins `phase`, so the phases add
+    up to the stretch from the first `enter` to `stop()`. No seam waits
+    for the device. `build` tags the phases' profiler spans with
+    Decision's route build number."""
 
-    __slots__ = ("sampled", "phases", "barriers", "_last")
+    __slots__ = ("phases", "build", "_phase", "_since", "_open")
 
-    def __init__(self, sampled: bool) -> None:
-        self.sampled = sampled
+    def __init__(self, build: Optional[int] = None) -> None:
         self.phases: Dict[str, float] = {}
-        self.barriers = 0  # block_until_ready calls taken (probe-effect)
-        self._last = time.perf_counter() if sampled else 0.0
+        self.build = build
+        self._phase: Optional[str] = None
+        self._since = 0.0
+        self._open: Optional[stage] = None  # the phase's profiler span
 
-    def seam(self, phase: str, *values: Any) -> None:
-        if not self.sampled:
-            return
-        for value in values:
-            block = getattr(value, "block_until_ready", None)
-            if block is not None:
-                block()
-                self.barriers += 1
+    def enter(self, phase: str) -> None:
         now = time.perf_counter()
-        self.phases[phase] = (
-            self.phases.get(phase, 0.0) + (now - self._last) * 1e3
-        )
-        self._last = now
+        self._end(now)
+        self._phase, self._since = phase, now
+        self._open = phase_stage(phase, self.build)
 
+    def stop(self) -> None:
+        self._end(time.perf_counter())
 
-NULL_CLOCK = PhaseClock(False)
+    def _end(self, now: float) -> None:
+        if self._open is not None:
+            self._open.stop()
+            self._open = None
+            self.phases[self._phase] = (
+                self.phases.get(self._phase, 0.0) + (now - self._since) * 1e3
+            )
 
 
 @dataclass
@@ -119,7 +142,6 @@ class SolveTrace:
     delta_columns: Optional[int]
     compile_cache_misses: int  # executables compiled BY this solve
     breaker_state: str
-    sampled: bool
     phases: Dict[str, float] = field(default_factory=dict)
     fault_kind: Optional[str] = None
     detail: Optional[str] = None
@@ -134,14 +156,12 @@ class FlightRecorder:
     def __init__(
         self,
         ring_size: int = 64,
-        sample_every: int = 16,
         forensics_dir: Optional[str] = None,
         forensics_last_n: int = 16,
         max_dumps: int = 8,
         node: str = "",
     ) -> None:
         self.ring_size = max(int(ring_size), 1)
-        self.sample_every = max(int(sample_every), 0)  # 0 = never sample
         self.forensics_dir = forensics_dir
         self.forensics_last_n = max(int(forensics_last_n), 1)
         self.max_dumps = max(int(max_dumps), 1)
@@ -149,13 +169,13 @@ class FlightRecorder:
         # stamped by the supervisor on breaker transitions so traces and
         # dumps carry the serving state they were recorded under
         self.breaker_state = "closed"
+        # Decision's route build number, set before each build: the
+        # identifier the solve's profiler spans share with the event's
+        self.build: Optional[int] = None
         self._rings: Dict[str, Deque[SolveTrace]] = {}
         self._seq = 0
-        self.solves_seen = 0
         self.recorded = 0
         self.evicted = 0
-        self.sampled_solves = 0
-        self.barrier_calls = 0  # total sampled-seam barriers ever taken
         self._pending_obs: List[Tuple[str, float]] = []
         self.dumps: List[Dict[str, Any]] = []
         self.dumps_written = 0
@@ -164,26 +184,13 @@ class FlightRecorder:
 
     # -- recording -------------------------------------------------------
 
-    def begin(self) -> PhaseClock:
-        """Per-solve sampling decision: every `sample_every`-th solve gets
-        a live PhaseClock (barriers at phase seams), the rest share the
-        no-op NULL_CLOCK."""
-        self.solves_seen += 1
-        if self.sample_every > 0 and (
-            self.solves_seen % self.sample_every == 1
-            or self.sample_every == 1
-        ):
-            self.sampled_solves += 1
-            return PhaseClock(True)
-        return NULL_CLOCK
-
     def next_seq(self) -> int:
         self._seq += 1
         return self._seq
 
     def record(self, trace: SolveTrace, clock: Optional[PhaseClock] = None):
         """Append one trace to its area ring (evicting with accounting)
-        and queue the sampled phase observations for the histogram
+        and queue the solve's phase observations for the histogram
         drain."""
         ring = self._rings.get(trace.area)
         if ring is None:
@@ -193,8 +200,7 @@ class FlightRecorder:
             self.evicted += 1
         ring.append(trace)
         self.recorded += 1
-        if clock is not None and clock.sampled:
-            self.barrier_calls += clock.barriers
+        if clock is not None:
             for phase, ms in clock.phases.items():
                 self.observe_phase(phase, ms)
 
@@ -232,13 +238,10 @@ class FlightRecorder:
     def stats(self) -> Dict[str, Any]:
         return {
             "ring_size": self.ring_size,
-            "sample_every": self.sample_every,
             "areas": sorted(self._rings),
             "recorded": self.recorded,
             "retained": self.retained(),
             "evicted": self.evicted,
-            "sampled_solves": self.sampled_solves,
-            "barrier_calls": self.barrier_calls,
         }
 
     # -- forensics -------------------------------------------------------

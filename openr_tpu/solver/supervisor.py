@@ -171,12 +171,8 @@ class SupervisorConfig:
     # watchdog heartbeat name stamped around solves
     watchdog_module: str = "decision"
     # flight recorder (solver/flight_recorder.py, docs/Monitoring.md
-    # "Flight recorder & profiling"): per-area SolveTrace ring bound and
-    # the phase-timing sampling cadence — every trace_sample_every-th
-    # solve takes block_until_ready barriers at phase seams; 0 disables
-    # sampling entirely (traces still record, without phase splits)
+    # "Flight recorder & profiling"): per-area SolveTrace ring bound
     trace_ring_size: int = 64
-    trace_sample_every: int = 16
     # forensics dumps: traces per area snapshotted into each dump, and an
     # optional directory the JSON artifacts are also written to (None =
     # in-memory only, read via ctrl getSolveTraces)
@@ -237,7 +233,6 @@ class SolverSupervisor(CountersMixin, HistogramsMixin):
 
         self.recorder = FlightRecorder(
             ring_size=self.config.trace_ring_size,
-            sample_every=self.config.trace_sample_every,
             forensics_dir=self.config.forensics_dir,
             forensics_last_n=self.config.forensics_last_n,
             node=self.my_node_name,
@@ -587,7 +582,6 @@ class SolverSupervisor(CountersMixin, HistogramsMixin):
                 delta_columns=None,
                 compile_cache_misses=0,
                 breaker_state=self.state,
-                sampled=False,
                 fault_kind=fault_kind,
                 detail=detail,
             )

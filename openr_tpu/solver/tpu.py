@@ -50,7 +50,11 @@ from openr_tpu.ops.spf import (
 )
 from openr_tpu.monitor.memledger import get_ledger
 from openr_tpu.solver.cpu import Metric, SpfSolver
-from openr_tpu.solver.flight_recorder import NULL_CLOCK, SolveTrace
+from openr_tpu.solver.flight_recorder import (
+    PhaseClock,
+    SolveTrace,
+    phase_stage,
+)
 from openr_tpu.testing.faults import fault_point
 
 
@@ -266,11 +270,10 @@ class _AreaSolve:
         self._mem: Dict[str, int] = {}
         self._on_capacity_refusal = on_capacity_refusal
         # flight recorder (solver/flight_recorder.py): every solve emits a
-        # SolveTrace into the bounded per-area ring; every Nth solve gets
-        # a live PhaseClock whose seams barrier at phase boundaries. The
-        # unsampled path sees only NULL_CLOCK attribute checks.
+        # SolveTrace into the bounded per-area ring, with the host's time
+        # split by a PhaseClock whose seams never wait for the device
         self._recorder = recorder
-        self._pclock = NULL_CLOCK
+        self._pclock = PhaseClock()
         self._last_trace: Optional[SolveTrace] = None
         # jax.sharding.Mesh or None: when set, the source batch is sharded
         # over the mesh 'batch' axis and the persistent layout buffers are
@@ -312,6 +315,8 @@ class _AreaSolve:
         self.last_solve_warm = False
         self.h2d_bytes = 0
         self.d2h_bytes = 0
+        # host reads that block on a device value (decision.spf.device_syncs)
+        self.device_syncs = 0
         # halo-exchange accounting (the 2-D tiled layout's cross-chip
         # traffic): ring-rotation count of the last solve and cumulative
         # frontier bytes moved between chips — the destination-sharded
@@ -336,6 +341,7 @@ class _AreaSolve:
         # _sync_spf_counters bookmarks (bytes already folded into counters)
         self._h2d_synced = 0
         self._d2h_synced = 0
+        self._device_syncs_synced = 0
         self._delta_cols_synced = 0
         self._delta_bytes_synced = 0
         self._delta_extracts_synced = 0
@@ -360,24 +366,36 @@ class _AreaSolve:
         zero-copy view of the device buffer, and the warm solver donates
         that buffer to the next event — a view would alias reused memory."""
         if self._d_host is None:
-            t0 = time.perf_counter()
-            self._d_host = np.array(self._d_dev)
+            fetch = phase_stage("d2h", self._pclock.build)
+            self._d_host = self._to_host(self._d_dev)
+            ms = fetch.stop()
             self.d2h_bytes += self._d_host.nbytes
             trace = self._last_trace
-            if trace is not None and trace.sampled:
+            if trace is not None:
                 # the lazy mirror fetch is this solve's d2h phase; it
                 # lands after the trace was recorded, so attribute it
                 # post-hoc (the ring holds the live object) and queue the
                 # histogram sample for the next counter sync
-                ms = (time.perf_counter() - t0) * 1e3
                 trace.phases["d2h"] = trace.phases.get("d2h", 0.0) + ms
                 trace.d2h_bytes += self._d_host.nbytes
-                if self._recorder is not None:
-                    self._recorder.observe_phase("d2h", ms)
+                self._recorder.observe_phase("d2h", ms)
             self._mem_register(
                 "mirror", "host", arrays=(self._d_host,)
             )
         return self._d_host
+
+    # -- blocking device reads (decision.spf.device_syncs) --------------
+
+    def _to_int(self, value) -> int:
+        """A device scalar read on the host: the host waits for the
+        program that computes it."""
+        self.device_syncs += 1
+        return int(value)
+
+    def _to_host(self, value) -> np.ndarray:
+        """An owned host copy of a device array, waited for likewise."""
+        self.device_syncs += 1
+        return np.array(value)
 
     # -- device-memory ledger seams (monitor/memledger.py) -------------
 
@@ -470,7 +488,23 @@ class _AreaSolve:
 
         return jax.device_put(jnp.asarray(x), NamedSharding(self.mesh, P()))
 
-    def _solve(self) -> None:
+    def _solve(self, refresh: bool = False) -> None:
+        """One solve under a phase clock of its own; with `refresh`, the
+        changelog is first replayed into the compiled graph."""
+        pc = self._pclock = PhaseClock(getattr(self._recorder, "build", None))
+        try:
+            if refresh:
+                pc.enter("refresh")
+                self.graph = refresh_graph(self.graph, self.link_state)
+            self._solve_phases(pc)
+        finally:
+            pc.stop()  # a fault mid-phase still ends its profiler span
+
+    def _solve_phases(self, pc: PhaseClock) -> None:
+        # `solve_ms` and the phases inside it start together, so that the
+        # phases add up to it (`refresh` lies before, `d2h` after)
+        pc.enter("prepare")
+        t0 = time.perf_counter()
         # named fault seam: the supervisor's error-classification/breaker
         # tests inject compile/runtime/device-loss faults here, exactly
         # where a real XLA dispatch would raise
@@ -496,18 +530,16 @@ class _AreaSolve:
         )
         # one device call for the whole batch; results stay device-resident
         # (the host mirror is fetched lazily through the `d` property).
-        # Timing covers patch build + dispatch; on the sliced-ELL paths the
-        # scalar `rounds` output forces completion of the same computation,
-        # so the measured wall time includes device execution there.
+        # Timing covers rows + patch build + dispatch; on the sliced-ELL
+        # paths the scalar `rounds` output forces completion of the same
+        # computation, so the measured wall time includes device execution
+        # there.
         inc_before = self.incremental_solves
         self._last_solve_delta = None  # set by a qualifying resident solve
         rec = self._recorder
-        pc = self._pclock = rec.begin() if rec is not None else NULL_CLOCK
         h2d0, d2h0, halo0 = self.h2d_bytes, self.d2h_bytes, self.halo_bytes
         misses0 = compile_cache_stats()["misses"] if rec is not None else 0
-        t0 = time.perf_counter()
         self.h2d_bytes += rows.nbytes
-        pc.seam("prepare")
         if self._use_tiled():
             self._admit_layout("tile2d")
             self._d_dev, self.rounds_last = self._tile_solve_resident(rows)
@@ -518,10 +550,10 @@ class _AreaSolve:
             from openr_tpu.parallel import sharded_batched_spf
 
             self._admit_layout("replicated")
+            pc.enter("h2d")
             self._d_dev = sharded_batched_spf(self.graph, rows, self.mesh)
             self.rounds_last = None  # edge-list form: rounds untracked
             self.full_solves += 1
-            pc.seam("relax", self._d_dev)
         else:
             self._admit_layout("bf")
             self._d_dev, self.rounds_last = self._bf_solve_resident(rows)
@@ -530,6 +562,7 @@ class _AreaSolve:
             (self._dev or {}).get("kind", "none"),
             arrays=(self._d_dev,),
         )
+        pc.stop()
         self.solve_ms_last = (time.perf_counter() - t0) * 1e3
         self.last_solve_warm = self.incremental_solves > inc_before
         self.device_solves += 1
@@ -591,7 +624,6 @@ class _AreaSolve:
                     compile_cache_stats()["misses"] - misses0
                 ),
                 breaker_state=rec.breaker_state,
-                sampled=pc.sampled,
                 phases=dict(pc.phases),
             )
             rec.record(self._last_trace, pc)
@@ -670,6 +702,7 @@ class _AreaSolve:
             or st["src_ref"] is not g.src
         ):
             tiling = tile_graph(g, g_ax)
+            self._pclock.enter("h2d")
             st = self._dev = {
                 "kind": "tile2d",
                 "src_ref": g.src,
@@ -717,6 +750,7 @@ class _AreaSolve:
                 and (len(changed) or ov_changed)
                 and self._d_dev is not None
             ):
+                self._pclock.enter("h2d")
                 w2_new = self._graph_sharded(tiling.tile_weights(g.w))
                 self.h2d_bytes += tiling.w.nbytes
                 ov_new = st["ov"]
@@ -733,7 +767,6 @@ class _AreaSolve:
                 fn = _tile_solver_warm(
                     tiling.shape_key() + (g.n_pad,), self.mesh
                 )
-                self._pclock.seam("h2d", w2_new, ov_new)
                 d, rounds, inv_rounds, col_changed, num_changed = fn(
                     jnp.asarray(rows, dtype=jnp.int32),
                     st["src_l"],
@@ -745,17 +778,17 @@ class _AreaSolve:
                     st["ov"],
                     self._d_dev,
                 )
+                self._pclock.enter("relax")
                 st["w2"] = w2_new
                 st["w_host"] = g.w.copy()
                 st["ov"] = ov_new
                 st["ov_host"] = g.overloaded.copy()
                 self.incremental_solves += 1
-                self.invalidation_rounds_last = int(inv_rounds)
-                rounds = int(rounds)
-                self._pclock.seam("relax", d)
+                self.invalidation_rounds_last = self._to_int(inv_rounds)
+                rounds = self._to_int(rounds)
                 # seed exchange + one ring per invalidation and relax round
                 self._account_halo(
-                    (g_ax - 1) * (1 + int(inv_rounds) + rounds)
+                    (g_ax - 1) * (1 + self.invalidation_rounds_last + rounds)
                 )
                 self._finish_delta(col_changed, num_changed, d, delta_ok)
                 return d, rounds
@@ -769,7 +802,7 @@ class _AreaSolve:
                 self.h2d_bytes += g.overloaded.nbytes
 
         fn = _tile_solver(st["tiling"].shape_key() + (g.n_pad,), self.mesh)
-        self._pclock.seam("h2d", st["w2"], st["ov"])
+        self._pclock.enter("h2d")
         d, rounds = fn(
             jnp.asarray(rows, dtype=jnp.int32),
             st["src_l"],
@@ -778,9 +811,9 @@ class _AreaSolve:
             st["hcols"],
             st["ov"],
         )
+        self._pclock.enter("relax")
         self.full_solves += 1
-        rounds = int(rounds)
-        self._pclock.seam("relax", d)
+        rounds = self._to_int(rounds)
         self._account_halo((g_ax - 1) * rounds)
         return d, rounds
 
@@ -809,6 +842,7 @@ class _AreaSolve:
         sell = g.sell
         st = self._dev
         if st is None or st.get("kind") != "sell" or st["src_ref"] is not g.src:
+            self._pclock.enter("h2d")
             st = self._dev = {
                 "kind": "sell",
                 "src_ref": g.src,
@@ -923,21 +957,14 @@ class _AreaSolve:
                             idx[k, : len(sel), 0] = sell.edge_row[sel]
                             idx[k, : len(sel), 1] = sell.edge_slot[sel]
                             vals[k, : len(sel)] = g.w[sel]
-                    args = (
-                        jnp.asarray(rows, dtype=jnp.int32),
-                        st["nbrs"],
-                        st["wgs"],
-                        st["ov"],
-                        jnp.asarray(idx),
-                        jnp.asarray(vals),
-                    )
                     self.h2d_bytes += idx.nbytes + vals.nbytes
-                    if (
+                    warm = (
                         self.warm_start
                         and rows_same
                         and fits_inc
                         and self._d_dev is not None
-                    ):
+                    )
+                    if warm:
                         inc_idx = np.full(
                             (nb, _PATCH_SLOTS, 2), 1 << 30, dtype=np.int32
                         )
@@ -946,7 +973,6 @@ class _AreaSolve:
                             if len(sel):
                                 inc_idx[k, : len(sel), 0] = sell.edge_row[sel]
                                 inc_idx[k, : len(sel), 1] = sell.edge_slot[sel]
-                        fn = _sell_solver_warm(sell.shape_key(), self.mesh)
                         self.h2d_bytes += inc_idx.nbytes
                         # DeltaPath qualification: the host-visible route
                         # inputs besides D are my own out-link metrics (the
@@ -956,7 +982,19 @@ class _AreaSolve:
                         delta_ok = not ov_changed and not np.any(
                             g.src[changed] == rows[0]
                         )
-                        self._pclock.seam("h2d", args[4], args[5])
+                    # the patch arrays are built: what follows uploads
+                    # them and enqueues the program
+                    self._pclock.enter("h2d")
+                    args = (
+                        jnp.asarray(rows, dtype=jnp.int32),
+                        st["nbrs"],
+                        st["wgs"],
+                        st["ov"],
+                        jnp.asarray(idx),
+                        jnp.asarray(vals),
+                    )
+                    if warm:
+                        fn = _sell_solver_warm(sell.shape_key(), self.mesh)
                         (
                             d,
                             new_wgs,
@@ -965,25 +1003,28 @@ class _AreaSolve:
                             col_changed,
                             num_changed,
                         ) = fn(*args, jnp.asarray(inc_idx), self._d_dev)
+                        self._pclock.enter("relax")
                         st["wgs"] = new_wgs
                         self.incremental_solves += 1
-                        self.invalidation_rounds_last = int(inv_rounds)
-                        self._pclock.seam("relax", d)
+                        self.invalidation_rounds_last = self._to_int(
+                            inv_rounds
+                        )
+                        rounds = self._to_int(rounds)
                         self._finish_delta(
                             col_changed, num_changed, d, delta_ok
                         )
-                        return d, int(rounds)
+                        return d, rounds
                     if len(changed):
                         fn = _sell_solver_patched(sell.shape_key(), self.mesh)
-                        self._pclock.seam("h2d", args[4], args[5])
                         d, new_wgs, rounds = fn(*args)
+                        self._pclock.enter("relax")
                         st["wgs"] = new_wgs
                         self.full_solves += 1
-                        self._pclock.seam("relax", d)
-                        return d, int(rounds)
+                        return d, self._to_int(rounds)
                     # overload-only event with warm start unavailable:
                     # nothing to patch — plain cold solve below
                 elif len(changed):
+                    self._pclock.enter("h2d")
                     wgs = list(st["wgs"])
                     for k, sel in enumerate(per_bucket):
                         if len(sel):
@@ -997,16 +1038,16 @@ class _AreaSolve:
                     st["wgs"] = tuple(wgs)
 
         fn = _sell_solver_counted(sell.shape_key(), self.mesh)
-        self._pclock.seam("h2d", st["ov"], *st["wgs"])
+        self._pclock.enter("h2d")
         d, rounds = fn(
             jnp.asarray(rows, dtype=jnp.int32),
             st["nbrs"],
             st["wgs"],
             st["ov"],
         )
+        self._pclock.enter("relax")
         self.full_solves += 1
-        self._pclock.seam("relax", d)
-        return d, int(rounds)
+        return d, self._to_int(rounds)
 
     def _bf_solve_resident(self, rows: np.ndarray):
         """Edge-list (non sliced-ELL) solve against persistent device
@@ -1026,6 +1067,7 @@ class _AreaSolve:
             st is None or st.get("kind") != "bf" or st["src_ref"] is not g.src
         )
         if structural:
+            self._pclock.enter("h2d")
             st = self._dev = {
                 "kind": "bf",
                 "src_ref": g.src,
@@ -1072,10 +1114,10 @@ class _AreaSolve:
             ):
                 # weight-only event: upload the new weight vector and let
                 # the device classify increases against the resident copy
+                delta_ok = not np.any(g.src[changed] == rows[0])
+                self._pclock.enter("h2d")
                 w_new = jnp.asarray(g.w)
                 self.h2d_bytes += g.w.nbytes
-                delta_ok = not np.any(g.src[changed] == rows[0])
-                self._pclock.seam("h2d", w_new)
                 d, rounds, inv_rounds, col_changed, num_changed = (
                     _bf_solver_warm(
                         jnp.asarray(rows, dtype=jnp.int32),
@@ -1087,19 +1129,22 @@ class _AreaSolve:
                         self._d_dev,
                     )
                 )
+                self._pclock.enter("relax")
                 st["w"] = w_new
                 st["w_host"] = g.w.copy()
                 self.incremental_solves += 1
-                self.invalidation_rounds_last = int(inv_rounds)
-                self._pclock.seam("relax", d)
+                self.invalidation_rounds_last = self._to_int(inv_rounds)
+                rounds = self._to_int(rounds)
                 self._finish_delta(col_changed, num_changed, d, delta_ok)
-                return d, int(rounds)
+                return d, rounds
             if len(changed):
                 st["w"] = self._replicated(g.w)
                 st["w_host"] = g.w.copy()
                 self.h2d_bytes += g.w.nbytes
 
-        self._pclock.seam("h2d", st["w"], st["ov"])
+        # this layout's cold program returns no round count: no host read
+        # waits for it here, its time falls to whoever reads D first
+        self._pclock.enter("h2d")
         d = _bf_fixpoint(
             jnp.asarray(rows, dtype=jnp.int32),
             st["src"],
@@ -1108,7 +1153,6 @@ class _AreaSolve:
             st["ov"],
         )
         self.full_solves += 1
-        self._pclock.seam("relax", d)
         return d, None
 
     def _nh_link_arrays(self):
@@ -1142,7 +1186,8 @@ class _AreaSolve:
         as full (mirrors reset, accumulated delta poisoned)."""
         if not delta_ok:
             return
-        num = int(num_changed)
+        self._pclock.enter("delta_extract")
+        num = self._to_int(num_changed)
         if num == 0:
             self._last_solve_delta = np.empty(0, dtype=np.int64)
             return
@@ -1166,11 +1211,11 @@ class _AreaSolve:
             col_changed, d_dev, jnp.asarray(nh_rows), jnp.asarray(nh_ws),
             cap=cap,
         )
-        cols = np.asarray(cols_d)
-        dcols = np.array(dcols_d)
-        nh = np.array(nh_d)
+        cols = self._to_host(cols_d)
+        dcols = self._to_host(dcols_d)
+        nh = self._to_host(nh_d)
         self.delta_extract_ms_last = (time.perf_counter() - t0) * 1e3
-        self._pclock.seam("delta_extract")  # host copies above are synced
+        self._pclock.enter("mirror_patch")
         xfer = cols.nbytes + dcols.nbytes + nh.nbytes + 4  # + count scalar
         self.d2h_bytes += xfer
         self.delta_bytes += xfer
@@ -1237,8 +1282,7 @@ class _AreaSolve:
         """Re-solve against the current LinkState snapshot if it moved."""
         if self.graph.version == self.link_state.version:
             return
-        self.graph = refresh_graph(self.graph, self.link_state)
-        self._solve()
+        self._solve(refresh=True)
 
     def cold_reference_d(self) -> np.ndarray:
         """Shadow cold solve from the HOST-side graph truth (the compiled
@@ -1669,6 +1713,10 @@ class TpuSpfSolver(SpfSolver):
         if d_d2h:
             solve._d2h_synced = solve.d2h_bytes
             self._bump("decision.spf.device_to_host_bytes", d_d2h)
+        d_syncs = solve.device_syncs - solve._device_syncs_synced
+        if d_syncs:
+            solve._device_syncs_synced = solve.device_syncs
+            self._bump("decision.spf.device_syncs", d_syncs)
         # DeltaPath extraction stats (docs/Monitoring.md): changed columns
         # and O(changes) copy-back bytes per warm event
         d_cols = solve.delta_columns - solve._delta_cols_synced
@@ -1698,7 +1746,7 @@ class TpuSpfSolver(SpfSolver):
             self._observe(
                 "decision.spf.delta_extract_ms", solve.delta_extract_ms_last
             )
-        # flight-recorder drain: sampled phase observations land in the
+        # flight-recorder drain: the solves' phase observations land in the
         # decision.spf.phase.*_ms histograms (the names are literals in
         # flight_recorder.PHASE_HISTOGRAMS, pinned to the docs table by
         # registry-drift), and the ring/eviction accounting rides the
@@ -1709,7 +1757,6 @@ class TpuSpfSolver(SpfSolver):
                 self._observe(hist_name, value)
             counters["decision.spf.traces_recorded"] = rec.recorded
             counters["decision.spf.traces_evicted"] = rec.evicted
-            counters["decision.spf.traces_sampled"] = rec.sampled_solves
         self._sync_apsp_counters(solve)
         from openr_tpu.apsp import apsp_compile_cache_stats
         from openr_tpu.ops.spf import compile_cache_stats

@@ -218,6 +218,14 @@ class Timer:
         self._observe(self._name, (time.perf_counter() - self._t0) * 1e3)
 
 
+def observe(histograms: Dict[str, Histogram], name: str, value: float) -> None:
+    """Records `value` into `histograms[name]`, created on first use."""
+    hist = histograms.get(name)
+    if hist is None:
+        hist = histograms[name] = Histogram()
+    hist.record(value)
+
+
 class HistogramsMixin:
     """Per-module histogram dict, the distribution sibling of CountersMixin
     (same `<module>.<name>` naming convention; `*_ms` suffix for latency)."""
@@ -230,11 +238,7 @@ class HistogramsMixin:
         return self.histograms
 
     def _observe(self, name: str, value: float) -> None:
-        histograms = self._ensure_histograms()
-        hist = histograms.get(name)
-        if hist is None:
-            hist = histograms[name] = Histogram()
-        hist.record(value)
+        observe(self._ensure_histograms(), name, value)
 
     def _timer(self, name: str) -> Timer:
         return Timer(self._observe, name)
