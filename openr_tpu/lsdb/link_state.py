@@ -388,8 +388,9 @@ class LinkState:
         # graph changelog for incremental compiled-graph refresh: entries are
         # ("link", Link) weight/up-down change, ("node", name) node-overload
         # change, ("structure", None) link/node add/remove. Consumers remember
-        # their read position (graph_log_pos); on overflow old entries are
-        # dropped and stale consumers rebuild from scratch
+        # their read position (graph_log_pos); at the cap the oldest half is
+        # dropped, so a consumer less than half a cap behind loses nothing
+        # and one that fell further behind rebuilds from scratch
         self._graph_log: List[Tuple[str, object]] = []
         self._graph_log_base = 0
         # counters (fb303 equivalents)
@@ -806,16 +807,19 @@ class LinkState:
     def graph_changes_since(
         self, pos: int
     ) -> Optional[List[Tuple[str, object]]]:
-        """Changelog entries since `pos`, or None when they were dropped
-        (consumer too stale: rebuild from scratch)."""
+        """Changelog entries since `pos`, or None when some of them were
+        dropped (consumer too stale: rebuild from scratch)."""
         if pos < self._graph_log_base:
             return None
         return self._graph_log[pos - self._graph_log_base :]
 
     def _log_graph(self, kind: str, obj: object = None) -> None:
         if len(self._graph_log) >= self._GRAPH_LOG_CAP:
-            self._graph_log_base += len(self._graph_log)
-            self._graph_log = []
+            # a sliding window: the newest half stays, positions stay
+            # absolute, and a trim every cap/2 entries is O(1) an entry
+            drop = self._GRAPH_LOG_CAP // 2
+            del self._graph_log[:drop]
+            self._graph_log_base += drop
         self._graph_log.append((kind, obj))
 
     # -- internals ---------------------------------------------------------

@@ -24,6 +24,7 @@ Reference semantics compiled in:
 
 from __future__ import annotations
 
+import logging
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -31,6 +32,8 @@ import numpy as np
 
 from openr_tpu.lsdb.link_state import LinkState
 from openr_tpu.lsdb.link_state import Link
+
+log = logging.getLogger(__name__)
 
 # int32-safe infinity: INF + max edge weight must not overflow int32
 INF = 1 << 29
@@ -328,20 +331,35 @@ def compile_edges(
     return graph
 
 
+def _recompile(link_state: LinkState, reason: str) -> CompiledGraph:
+    """refresh_graph's fall-back: O(E) of Python, and whoever holds state
+    keyed on the old snapshot (the resident solve, DeltaPath) starts cold."""
+    log.info(
+        "area %s: graph refresh falls back to a full compile (%s)",
+        link_state.area,
+        reason,
+    )
+    return compile_graph(link_state)
+
+
 def refresh_graph(graph: CompiledGraph, link_state: LinkState) -> CompiledGraph:
     """Bring a compiled snapshot up to date with its LinkState.
 
     Replays the LinkState graph changelog since the snapshot: pure
     weight/overload changes (link flap, metric change, drain) patch copies of
     the w/overloaded arrays in place — same shapes, no recompilation and no
-    O(E) Python rebuild; structural changes (link/node add/remove) or a
-    dropped changelog fall back to a full compile_graph. This is the
-    single-link-flap incremental event path (BASELINE.md config 2)."""
+    O(E) Python rebuild; structural changes (link/node add/remove), a
+    changelog whose entries since the snapshot were dropped, or an entry the
+    snapshot does not know fall back to a full compile_graph, which shares
+    no `link_edges` with the snapshot. This is the single-link-flap
+    incremental event path (BASELINE.md config 2)."""
     if graph.version == link_state.version:
         return graph
     changes = link_state.graph_changes_since(graph.log_pos)
-    if changes is None or any(kind == "structure" for kind, _ in changes):
-        return compile_graph(link_state)
+    if changes is None:
+        return _recompile(link_state, "log dropped")
+    if any(kind == "structure" for kind, _ in changes):
+        return _recompile(link_state, "structure")
 
     w = graph.w.copy()
     sell = graph.sell
@@ -352,7 +370,7 @@ def refresh_graph(graph: CompiledGraph, link_state: LinkState) -> CompiledGraph:
         if kind == "link":
             pos = graph.link_edges.get(obj)
             if pos is None:  # changelog raced a structural entry we missed
-                return compile_graph(link_state)
+                return _recompile(link_state, "unknown edge")
             up = obj.is_up()
             for p, metric in (
                 (pos[0], obj.metric_from_node(obj.n1)),
@@ -367,7 +385,7 @@ def refresh_graph(graph: CompiledGraph, link_state: LinkState) -> CompiledGraph:
         else:  # "node"
             i = graph.node_index.get(obj)
             if i is None:
-                return compile_graph(link_state)
+                return _recompile(link_state, "unknown node")
             overloaded[i] = link_state.is_node_overloaded(obj)
 
     new_sell = None

@@ -317,6 +317,10 @@ class _AreaSolve:
         self.d2h_bytes = 0
         # host reads that block on a device value (decision.spf.device_syncs)
         self.device_syncs = 0
+        # warm refreshes that ended in compile_graph, whatever the reason
+        # (decision.spf.graph_recompiles): each is a cold solve and a full
+        # route build
+        self.graph_recompiles = 0
         # halo-exchange accounting (the 2-D tiled layout's cross-chip
         # traffic): ring-rotation count of the last solve and cumulative
         # frontier bytes moved between chips — the destination-sharded
@@ -342,6 +346,7 @@ class _AreaSolve:
         self._h2d_synced = 0
         self._d2h_synced = 0
         self._device_syncs_synced = 0
+        self._graph_recompiles_synced = 0
         self._delta_cols_synced = 0
         self._delta_bytes_synced = 0
         self._delta_extracts_synced = 0
@@ -495,7 +500,11 @@ class _AreaSolve:
         try:
             if refresh:
                 pc.enter("refresh")
-                self.graph = refresh_graph(self.graph, self.link_state)
+                old = self.graph
+                self.graph = refresh_graph(old, self.link_state)
+                # a patched snapshot shares its parent's link_edges
+                if self.graph.link_edges is not old.link_edges:
+                    self.graph_recompiles += 1
             self._solve_phases(pc)
         finally:
             pc.stop()  # a fault mid-phase still ends its profiler span
@@ -1717,6 +1726,12 @@ class TpuSpfSolver(SpfSolver):
         if d_syncs:
             solve._device_syncs_synced = solve.device_syncs
             self._bump("decision.spf.device_syncs", d_syncs)
+        # bumped also by 0, so that the counter exists from the first sync
+        self._bump(
+            "decision.spf.graph_recompiles",
+            solve.graph_recompiles - solve._graph_recompiles_synced,
+        )
+        solve._graph_recompiles_synced = solve.graph_recompiles
         # DeltaPath extraction stats (docs/Monitoring.md): changed columns
         # and O(changes) copy-back bytes per warm event
         d_cols = solve.delta_columns - solve._delta_cols_synced

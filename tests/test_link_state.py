@@ -15,6 +15,44 @@ def build_link_state(edges, area="0", **kwargs):
     return ls
 
 
+class TestGraphChangelog:
+    """The compiled graph's changelog alone: a sliding window of the
+    newest entries under absolute positions."""
+
+    CAP = LinkState._GRAPH_LOG_CAP
+
+    @pytest.mark.parametrize("stride", [1, 4, 64, 1024])
+    def test_reader_that_keeps_up_gets_every_entry_once_in_order(self, stride):
+        ls = LinkState("0")
+        pos, got = ls.graph_log_pos, []
+        for i in range(3 * self.CAP):
+            ls._log_graph("link", i)
+            assert len(ls._graph_log) <= self.CAP
+            assert ls.graph_log_pos == i + 1  # absolute across trims
+            # read out of step with the cap, as a reader is after a load
+            if (i + 1) % stride == stride // 2:
+                changes = ls.graph_changes_since(pos)
+                assert changes is not None, i
+                got += changes
+                pos = ls.graph_log_pos
+        got += ls.graph_changes_since(pos)
+        assert ls._graph_log_base > 0  # the log was trimmed on the way
+        assert got == [("link", i) for i in range(3 * self.CAP)]
+        assert ls.graph_changes_since(ls.graph_log_pos) == []
+
+    def test_reader_left_behind_rebuilds_and_the_newest_half_is_kept(self):
+        ls = LinkState("0")
+        for i in range(3 * self.CAP):
+            ls._log_graph("link", i)
+            # whatever the moment of a trim, half a cap of entries is there
+            behind = max(0, ls.graph_log_pos - self.CAP // 2)
+            changes = ls.graph_changes_since(behind)
+            assert len(changes) == i + 1 - behind
+            assert changes[0] == ("link", behind) and changes[-1] == ("link", i)
+        assert ls.graph_changes_since(ls.graph_log_pos - self.CAP - 1) is None
+        assert ls.graph_changes_since(0) is None
+
+
 class TestHoldableValue:
     def test_bool_holds(self):
         hv = HoldableValue(True)

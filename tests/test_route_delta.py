@@ -836,3 +836,81 @@ class TestDeltaUnderLfa:
             nh.neighbor_node
             for nh in oracle.unicast_entries[IpPrefix(PFXS[0])].nexthops
         }
+
+
+class TestChangelogPastItsCap:
+    """The served path over more graph-changelog entries than the log
+    holds: a reader that keeps up loses none, so no warm metric change
+    turns into a graph recompile, a cold solve and a full route build."""
+
+    N = 6
+    EVENTS = 1100  # x 4 entries: the log passes LinkState._GRAPH_LOG_CAP
+
+    def _swap(self, h, restore, raise_):
+        """One metric swap, 4 changelog entries: the link that carried the
+        high metric goes back to 1 and another is raised, both directions."""
+        for link, metric in ((restore, 1), (raise_, 5)):
+            set_metric(h.dbs, h.ls, link[0], link[1], metric)
+            set_metric(h.dbs, h.ls, link[1], link[0], metric)
+
+    @pytest.mark.parametrize("structure_at", [None, 550])
+    def test_metric_swaps_stay_on_delta_path(self, structure_at):
+        me = "g0_0"
+        edges = grid_edges(self.N)
+        h = DeltaHarness(
+            edges,
+            me,
+            {
+                f"g{i}_{j}": [f"10.{i}.{j}.0/24"]
+                for i in range(self.N)
+                for j in range(self.N)
+                if (i, j) != (0, 0)
+            },
+        )
+        gone = ("g3_3", "g3_4")  # the structure case removes this link
+        links = [
+            (a, b) for a, b, _ in edges if me not in (a, b) and (a, b) != gone
+        ]
+        solve = h.solver._solves[("0", me)][1]
+        link_edges = solve.graph.link_edges
+        name = "decision.spf.graph_recompiles"
+        assert h.solver.counters[name] == 0  # there from the first sync
+        raised = links[-1]
+        self._swap(h, raised, raised)  # 2 of the 4 change nothing
+        assert h.step() is True
+        log_pos = h.ls.graph_log_pos
+        for k in range(self.EVENTS):
+            nxt = links[(7 * k) % len(links)]
+            self._swap(h, raised, nxt)
+            raised = nxt
+            structural = k == structure_at
+            if structural:
+                h.dbs[gone[0]] = dataclasses.replace(
+                    h.dbs[gone[0]],
+                    adjacencies=[
+                        adj
+                        for adj in h.dbs[gone[0]].adjacencies
+                        if adj.other_node_name != gone[1]
+                    ],
+                )
+                h.ls.update_adjacency_database(h.dbs[gone[0]])
+            builds = (h.builder.delta_builds, h.builder.full_builds)
+            h.db, _, used = h.builder.build(me, h.als, h.ps, h.db)
+            assert used is not structural, k
+            assert (h.builder.delta_builds, h.builder.full_builds) == (
+                builds[0] + used,
+                builds[1] + (not used),
+            )
+            # a patched graph keeps its parent's link_edges, a recompiled
+            # one has its own
+            assert (solve.graph.link_edges is not link_edges) is structural, k
+            link_edges = solve.graph.link_edges
+        assert h.ls.graph_log_pos - log_pos >= 4 * self.EVENTS
+        assert h.ls.graph_log_pos > LinkState._GRAPH_LOG_CAP
+        want = 0 if structure_at is None else 1
+        assert solve.graph_recompiles == want
+        assert h.solver.counters[name] == want
+        assert h.builder.full_builds == 1 + want  # the first build, always
+        assert_route_db_equal(
+            SpfSolver(me).build_route_db(me, h.als, h.ps), h.db
+        )

@@ -287,6 +287,37 @@ class TestIncrementalRefresh:
         assert g2.src is not g1.src  # full rebuild
         all_pairs_distance_check_graph(ls, g2)
 
+    @pytest.mark.parametrize(
+        "reason", ["log dropped", "structure", "unknown edge", "unknown node"]
+    )
+    def test_every_fallback_recompiles_and_says_why(self, reason, caplog):
+        from openr_tpu.ops.graph import refresh_graph
+
+        edges = [("a", "b", 1), ("b", "c", 1), ("a", "c", 5)]
+        ls = build_ls(edges)
+        g1 = compile_graph(ls)
+        if reason == "structure":
+            ls.update_adjacency_database(build_adj_dbs([("a", "c", 5)])["a"])
+        else:  # a weight change, which alone would be patched in place
+            ls.update_adjacency_database(
+                build_adj_dbs([("a", "b", 1), ("a", "c", 9)])["a"]
+            )
+        if reason == "log dropped":  # the reader fell a cap behind
+            for _ in range(ls._GRAPH_LOG_CAP):
+                ls._log_graph("node", "b")
+        elif reason == "unknown edge":
+            g1.link_edges = {}
+        elif reason == "unknown node":
+            ls._log_graph("node", "z")
+        with caplog.at_level("INFO", logger="openr_tpu.ops.graph"):
+            g2 = refresh_graph(g1, ls)
+        assert [r.getMessage() for r in caplog.records] == [
+            f"area 0: graph refresh falls back to a full compile ({reason})"
+        ]
+        assert g2.src is not g1.src and g2.link_edges is not g1.link_edges
+        assert g2.version == ls.version and g2.log_pos == ls.graph_log_pos
+        all_pairs_distance_check_graph(ls, g2)
+
     def test_refresh_noop_when_version_unchanged(self):
         from openr_tpu.ops.graph import refresh_graph
 
