@@ -1,7 +1,8 @@
 """The link-state database of a run, as plain data.
 
 `Lsdb` is what the reference reads: node names, a metric per directed
-adjacency, one prefix per node. It imports nothing of the program. The
+adjacency, which links are up, one prefix per node and whether the node
+announces it. It imports nothing of the program. The
 encoding that the daemon is fed (the program's own AdjacencyDatabase /
 PrefixDatabase types, serialized as KvStore values) is `WireEncoder`, kept
 apart so that the reference never sees a program object.
@@ -16,7 +17,7 @@ from __future__ import annotations
 
 import base64
 import zlib
-from typing import Dict, List, Tuple
+from typing import Dict, List, Set, Tuple
 
 from chipbench.topologies import Edge
 
@@ -41,7 +42,9 @@ def nexthop_v6(remote: str) -> str:
 
 
 class Lsdb:
-    """Nodes, directed metrics and prefixes; mutated by traffic events."""
+    """Nodes, directed metrics, link state and prefixes; mutated by traffic
+    events. Every link is up and every /24 announced until one says
+    otherwise."""
 
     def __init__(self, edges: List[Edge]) -> None:
         self.metric: Dict[str, Dict[str, int]] = {}
@@ -56,6 +59,15 @@ class Lsdb:
             node: f"10.{i // 256}.{i % 256}.0/24"
             for i, node in enumerate(self.nodes)
         }
+        self.down: Set[Tuple[str, str]] = set()  # directed: a down link both ways
+        self.withdrawn: Set[str] = set()  # nodes that do not announce their /24
+
+    def up_peers(self, node: str) -> Dict[str, int]:
+        """`node`'s adjacencies that are up: peer -> metric."""
+        peers = self.metric[node]
+        if not self.down:
+            return peers
+        return {p: m for p, m in peers.items() if (node, p) not in self.down}
 
     def set_metric(self, a: str, b: str, metric: int) -> Tuple[str, str]:
         """Both directions of link a<->b; returns the nodes whose
@@ -66,13 +78,43 @@ class Lsdb:
         self.metric[b][a] = metric
         return (a, b)
 
+    def set_link_up(self, a: str, b: str, up: bool) -> Tuple[str, ...]:
+        """Both directions of link a<->b, which keeps its metric; returns
+        the nodes whose adjacency database changed."""
+        if b not in self.metric.get(a, {}):
+            raise KeyError(f"no link {a}<->{b}")
+        if up == ((a, b) not in self.down):
+            return ()
+        if up:
+            self.down -= {(a, b), (b, a)}
+        else:
+            self.down |= {(a, b), (b, a)}
+        return (a, b)
+
+    def set_announced(self, node: str, announced: bool) -> Tuple[str, ...]:
+        """Whether `node` announces its /24 (`prefix_of` is the plan and
+        does not change); returns the nodes whose prefix database changed."""
+        if node not in self.metric:
+            raise KeyError(f"no node {node}")
+        if announced == (node not in self.withdrawn):
+            return ()
+        if announced:
+            self.withdrawn.discard(node)
+        else:
+            self.withdrawn.add(node)
+        return (node,)
+
 
 class WireEncoder:
     """Lsdb -> the daemon's KvStore keys, as ctrl `setKvStoreKeyVals` JSON.
 
     The one place of the benchmark that builds the program's types. The
-    serialized bytes of a node's adjacency database are cached by its
-    metrics, so an event's payload costs a dict lookup and a version bump.
+    serialized bytes of a node's adjacency database are cached by its up
+    adjacencies and their metrics, so an event's payload costs a dict
+    lookup and a version bump. A down link is in neither end's database
+    (upstream's LinkMonitor withdraws the adjacency and marks nothing); a
+    node that does not announce its /24 sends a prefix database with no
+    entry, which Decision reads as the withdrawal.
     """
 
     def __init__(self, lsdb: Lsdb) -> None:
@@ -87,8 +129,8 @@ class WireEncoder:
         from openr_tpu.types import Adjacency, AdjacencyDatabase
         from openr_tpu.utils import serializer
 
-        peers = self.lsdb.metric[node]
-        key = (node, tuple(peers.values()))
+        peers = self.lsdb.up_peers(node)
+        key = (node, tuple(peers.items()))
         cached = self._adj_bytes.get(key)
         if cached is None:
             db = AdjacencyDatabase(
@@ -114,9 +156,11 @@ class WireEncoder:
         from openr_tpu.types import IpPrefix, PrefixDatabase, PrefixEntry
         from openr_tpu.utils import serializer
 
-        db = PrefixDatabase(
-            node, [PrefixEntry(IpPrefix(self.lsdb.prefix_of[node]))], area=AREA
+        entries = (
+            [] if node in self.lsdb.withdrawn
+            else [PrefixEntry(IpPrefix(self.lsdb.prefix_of[node]))]
         )
+        db = PrefixDatabase(node, entries, area=AREA)
         return base64.b64encode(serializer.dumps(db)).decode()
 
     def key_vals(self, keys: List[str]) -> Dict[str, dict]:

@@ -5,8 +5,10 @@ other node's prefix, the set of the vantage's adjacencies that lie on some
 shortest path to that node, each with the path's metric. Distances come
 from scipy's Dijkstra, run from the vantage and from each of its
 neighbours: neighbour `u` is a first hop toward `d` exactly where
-metric(vantage, u) + dist_u(d) = dist_vantage(d). It imports nothing of
-the program and reads only `chipbench.lsdb.Lsdb`.
+metric(vantage, u) + dist_u(d) = dist_vantage(d). A link that is down is
+no edge, either way, and no first hop; a node that does not announce its
+/24 has no route. It imports nothing of the program and reads only
+`chipbench.lsdb.Lsdb`.
 
 A table is `{prefix: frozenset((address, interface, metric), ...)}`: the
 form in which `compare.py` also reads the platform agent's table.
@@ -48,38 +50,45 @@ class Reference:
             shape=(n, n),
         )
         self.refresh(lsdb.nodes)
-        self.neighbours = list(lsdb.metric[vantage])
-        self.hops = [
-            (nexthop_v4(vantage, peer), if_name(vantage, peer))
-            for peer in self.neighbours
-        ]
+        self.hop_of = {
+            peer: (nexthop_v4(vantage, peer), if_name(vantage, peer))
+            for peer in lsdb.metric[vantage]
+        }
         self.prefixes = [lsdb.prefix_of[node] for node in lsdb.nodes]
 
     def refresh(self, nodes: Iterable[str]) -> None:
-        """Re-reads the metrics of the links out of `nodes`."""
+        """Re-reads the metric and the up-state of the links out of
+        `nodes`: a down link weighs infinity, which Dijkstra never takes."""
+        down = self.lsdb.down
         for node in nodes:
             for peer, metric in self.lsdb.metric[node].items():
-                self.graph.data[self.slot[node, peer]] = metric
+                self.graph.data[self.slot[node, peer]] = (
+                    np.inf if (node, peer) in down else metric
+                )
 
     def table(self) -> Table:
-        """Every reachable node's prefix -> its ECMP next-hop set."""
+        """The prefix of every node that is reachable and announces it, on
+        the LSDB as it stands -> its ECMP next-hop set."""
         me = self.number[self.vantage]
-        sources = [me] + [self.number[peer] for peer in self.neighbours]
+        neighbours = self.lsdb.up_peers(self.vantage)
+        hops = [self.hop_of[peer] for peer in neighbours]
+        silent = {self.number[node] for node in self.lsdb.withdrawn}
+        sources = [me] + [self.number[peer] for peer in neighbours]
         dist = dijkstra(self.graph, directed=True, indices=sources)
         mask = np.zeros(dist.shape[1], dtype=np.int64)
-        for i, peer in enumerate(self.neighbours):
-            through = self.lsdb.metric[self.vantage][peer] + dist[1 + i]
+        for i, metric in enumerate(neighbours.values()):
+            through = metric + dist[1 + i]
             mask |= (through == dist[0]).astype(np.int64) << i
         sets: Dict[Tuple[int, int], NextHops] = {}
         table: Table = {}
         for node in np.flatnonzero(np.isfinite(dist[0])).tolist():
-            if node == me:
+            if node == me or node in silent:
                 continue
             key = (int(mask[node]), int(dist[0][node]))
             if key not in sets:
                 sets[key] = frozenset(
                     (address, iface, key[1])
-                    for i, (address, iface) in enumerate(self.hops)
+                    for i, (address, iface) in enumerate(hops)
                     if key[0] >> i & 1
                 )
             table[self.prefixes[node]] = sets[key]

@@ -45,7 +45,7 @@ from typing import Dict, List, Optional, Tuple  # noqa: E402
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
-TRACE_DIR = os.path.join(ROOT, ".chipbench_trace")
+TRACE_DIR = os.path.join(ROOT, f".chipbench_trace.{os.getpid()}")
 TRACE_SECONDS = 1.0  # of the window, in a --trace 1 run
 LOAD_WRITE_BYTES = 24 << 20  # of encoded values in one ctrl write of the load
 

@@ -194,9 +194,10 @@ def test_reference_ecmp_on_a_grid_known_by_hand():
     assert len(table) == 8
 
 
+@pytest.mark.parametrize("cell_name", [REHEARSAL, "rehearsal_fabric.prefix_churn"])
 @pytest.mark.parametrize("breakage", control.BREAKAGES)
-def test_control_breaks_a_guarantee_and_comes_out_not_correct(breakage):
-    cell = bench_run.resolve_cell(REHEARSAL)
+def test_control_breaks_a_guarantee_and_comes_out_not_correct(breakage, cell_name):
+    cell = bench_run.resolve_cell(cell_name)
     got, compared, _ = control.control_run(cell, seed=2**31 + 7, n_events=40, breakage=breakage)
     assert got is False
     assert all(v["limit"] == 0 for v in compared.values())
