@@ -1,11 +1,12 @@
 """`relax_roofline`: the least time the chip could take for ONE relaxation
 sweep of the configuration's graph (work.py: bytes over HBM bandwidth),
-over the mean device time per event of the warm-solve program, in %.
+over the mean device time per event of the solve program, in %.
 
-The warm-solve program is named by the source's `program` (`jit_solve`,
-the jitted inner function of `_sell_solver_warm`); its time per event is
-its total device time in the traced window over the events completed
-there.
+The solve program is named by the source's `program` (`jit_solve`: the
+jitted inner function of `_sell_solver_warm` and, on a cold solve, of
+`_sell_solver_counted`, which the trace prints under the same name); its
+time per event is its total device time in the traced window over the
+events completed there.
 """
 
 from chipbench import work

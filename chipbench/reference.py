@@ -5,7 +5,9 @@ other node's prefix, the set of the vantage's adjacencies that lie on some
 shortest path to that node, each with the path's metric. Distances come
 from scipy's Dijkstra, run from the vantage and from each of its
 neighbours: neighbour `u` is a first hop toward `d` exactly where
-metric(vantage, u) + dist_u(d) = dist_vantage(d). A link that is down is
+metric(vantage, u) + dist_u(d) = dist_vantage(d). The sets are the columns
+of a boolean [neighbours, nodes] matrix, so a vantage may have any number
+of neighbours (a spine switch of the Clos has 173). A link that is down is
 no edge, either way, and no first hop; a node that does not announce its
 /24 has no route. It imports nothing of the program and reads only
 `chipbench.lsdb.Lsdb`.
@@ -75,22 +77,27 @@ class Reference:
         silent = {self.number[node] for node in self.lsdb.withdrawn}
         sources = [me] + [self.number[peer] for peer in neighbours]
         dist = dijkstra(self.graph, directed=True, indices=sources)
-        mask = np.zeros(dist.shape[1], dtype=np.int64)
-        for i, metric in enumerate(neighbours.values()):
-            through = metric + dist[1 + i]
-            mask |= (through == dist[0]).astype(np.int64) << i
-        sets: Dict[Tuple[int, int], NextHops] = {}
+        # member[i, d]: neighbour i is a first hop toward d. A destination's
+        # set is its column, of any height; equal columns share one frozenset,
+        # keyed by the column's packed bytes and the distance
+        through = np.fromiter(neighbours.values(), float, len(hops))[:, None] + dist[1:]
+        member = through == dist[0]
+        columns = np.ascontiguousarray(np.packbits(member, axis=0).T)
+        sets: Dict[Tuple[bytes, int], NextHops] = {}
         table: Table = {}
         for node in np.flatnonzero(np.isfinite(dist[0])).tolist():
             if node == me or node in silent:
                 continue
-            key = (int(mask[node]), int(dist[0][node]))
+            key = (columns[node].tobytes(), int(dist[0][node]))
             if key not in sets:
                 sets[key] = frozenset(
-                    (address, iface, key[1])
-                    for i, (address, iface) in enumerate(hops)
-                    if key[0] >> i & 1
+                    (*hops[i], key[1]) for i in np.flatnonzero(member[:, node])
                 )
+                if not sets[key]:
+                    raise ValueError(
+                        f"{self.lsdb.nodes[node]} is reachable from "
+                        f"{self.vantage} over no first hop"
+                    )
             table[self.prefixes[node]] = sets[key]
         return table
 

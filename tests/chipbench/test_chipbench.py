@@ -89,9 +89,10 @@ def test_benchmark_json_names_units_and_files():
         assert m["better"] in ("lower", "higher")
     e2e = {m["name"] for m in bench["end_to_end"]}
     assert "setup_s" in e2e and len(e2e) >= 2
+    cells = {w["name"] for w in bench["workloads"]}
     for m in bench["end_to_end"]:
         assert 0.01 <= m["bound"] <= 0.25 and m["source"] in ("host_clock", "device_trace")
-    cells = {w["name"] for w in bench["workloads"]}
+        assert set(m.get("workloads", [])) <= cells
     configs = {c["name"] for c in bench["configs"]}
     assert configs == {w["config"] for w in bench["workloads"]}  # each is used
     for w in bench["workloads"]:
@@ -107,6 +108,9 @@ def test_benchmark_json_names_units_and_files():
         assert spec["layer"] == m["layer"] and spec["moves"] == m["moves"]
         assert spec["unit"] == m["unit"] and "workloads" not in spec
         assert m["moves"] in e2e and set(m.get("workloads", cells)) <= cells
+        # every cell that reports the metric reports what it should move
+        moved = next(e for e in bench["end_to_end"] if e["name"] == m["moves"])
+        assert set(m.get("workloads", cells)) <= set(moved.get("workloads", cells)), m["name"]
         if "trace" in spec["source"]:
             assert os.path.exists(
                 os.path.join(ROOT, "chipbench", "reducers", spec["source"]["trace"] + ".py")
@@ -194,7 +198,9 @@ def test_reference_ecmp_on_a_grid_known_by_hand():
     assert len(table) == 8
 
 
-@pytest.mark.parametrize("cell_name", [REHEARSAL, "rehearsal_fabric.prefix_churn"])
+@pytest.mark.parametrize("cell_name", [
+    REHEARSAL, "rehearsal_fabric.prefix_churn", "rehearsal_fabric.own_link_flaps",
+])
 @pytest.mark.parametrize("breakage", control.BREAKAGES)
 def test_control_breaks_a_guarantee_and_comes_out_not_correct(breakage, cell_name):
     cell = bench_run.resolve_cell(cell_name)

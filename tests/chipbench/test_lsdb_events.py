@@ -232,6 +232,13 @@ def test_fabric9976_prefix_churn_leaves_no_lsdb_state_twice_in_3000_events():
     assert max(written.values()) == 2
 
 
+def _cell_case(kind, config_name, cell_name):
+    """A cell of BENCHMARK.json as a case of the test below, by its files."""
+    config = bench_run.load_json("configs", f"{config_name}.json")
+    groups = bench_run.load_json("cells", f"{cell_name}.json")["groups"]
+    return kind, config["topology"], config["vantage"], {"groups": groups}
+
+
 @pytest.mark.parametrize("kind, topology, vantage, over", [
     ("prefix_swap", FABRIC, "rsw0_0",
      {"nodes": [{"node": "rsw{p}_{r}", "ranges": {"p": [1, 2], "r": [0, 5]}}]}),
@@ -241,7 +248,11 @@ def test_fabric9976_prefix_churn_leaves_no_lsdb_state_twice_in_3000_events():
      {"groups": [{"a": "rsw0_0", "b": "fsw0_{f}", "ranges": {"f": [0, 3]}}]}),
     ("link_down_swap", GRID, "g0_0",
      {"groups": [{"a": "g0_0", "b": "g0_1"}, {"a": "g0_0", "b": "g1_0"}]}),
-], ids=["prefix_swap-fabric", "prefix_swap-grid", "link_down_swap-own-fabric", "link_down_swap-own-grid"])
+    # the cell itself, on the real Clos: its configuration's topology and
+    # vantage, its own file's candidates
+    _cell_case("link_down_swap", "fabric9976", "fabric9976.own_link_flaps"),
+], ids=["prefix_swap-fabric", "prefix_swap-grid", "link_down_swap-own-fabric", "link_down_swap-own-grid",
+        "fabric9976.own_link_flaps"])
 def test_every_event_of_the_new_mixes_changes_routes_at_the_vantage(kind, topology, vantage, over):
     """In small, what the cells send: the reference says that a prefix event
     takes exactly one route away and brings exactly one, by exactly those
@@ -271,12 +282,19 @@ def test_every_event_of_the_new_mixes_changes_routes_at_the_vantage(kind, topolo
             assert all(key.startswith("adj:") for key in keys) and 2 <= len(keys) <= 3
             assert len(changed) >= (2 if i else 1), event
             assert len(after) == len(before)  # nobody is cut off
+            # one of the vantage's links is down, the others are up
+            assert len(lsdb.up_peers(vantage)) == len(lsdb.metric[vantage]) - 1
         before = after
 
 
 # -- (d) one whole run of each on the CPU ---------------------------------------
 
-@pytest.mark.parametrize("cell", ["rehearsal_fabric.prefix_churn", "rehearsal_fabric.own_link_flaps"])
+@pytest.mark.parametrize("cell", [
+    "rehearsal_fabric.prefix_churn", "rehearsal_fabric.own_link_flaps",
+    # a vantage of 70 neighbours, 71 solve rows: its route toward the other
+    # spine has 70 first hops, more than a machine word has bits
+    "rehearsal_fabric_ssw.metric_flaps",
+])
 def test_whole_cpu_run_of_the_new_mixes_is_correct(cell, capsys):
     """Each needs only its `cells/` file: `resolve_cell` takes a
     configuration.traffic pair that no `workloads` entry names. One second:
@@ -322,17 +340,35 @@ def _withdrawals_are_lost(monkeypatch):
     monkeypatch.setattr(delta.DeltaRouteBuilder, "_build_delta", build)
 
 
-@pytest.mark.parametrize("fault, number", [
-    (_ecmp_sets_lose_a_member, "table_mismatches"),
-    (_withdrawals_are_lost, "event_mismatches"),
-], ids=["ecmp_cut_in_fib", "withdrawal_lost_in_delta_build"])
-def test_a_prefix_event_answered_wrongly_is_not_correct(fault, number, capsys, monkeypatch):
+def _full_builds_answer_one_event_late(monkeypatch):
+    import openr_tpu.solver.delta as delta
+
+    real = delta.DeltaRouteBuilder._build_full
+    held = {}
+
+    def build(self, me, link_states, prefix_state, prev_db, policy_fn):
+        fresh, _, _ = real(self, me, link_states, prefix_state, prev_db, policy_fn)
+        held["db"], late = fresh, held.get("db", fresh)
+        return late, delta.get_route_delta(late, prev_db), False
+
+    monkeypatch.setattr(delta.DeltaRouteBuilder, "_build_full", build)
+
+
+@pytest.mark.parametrize("fault, number, cell", [
+    (_ecmp_sets_lose_a_member, "table_mismatches", "rehearsal_fabric.prefix_churn"),
+    (_withdrawals_are_lost, "event_mismatches", "rehearsal_fabric.prefix_churn"),
+    (_ecmp_sets_lose_a_member, "table_mismatches", "rehearsal_fabric.own_link_flaps"),
+    (_full_builds_answer_one_event_late, "event_mismatches", "rehearsal_fabric.own_link_flaps"),
+], ids=["ecmp_cut_in_fib", "withdrawal_lost_in_delta_build", "own_link-ecmp_cut_in_fib",
+        "own_link-stale_full_build"])
+def test_a_prefix_event_answered_wrongly_is_not_correct(fault, number, cell, capsys, monkeypatch):
     """The timed path broken underneath the harness, on the prefix mix: an
     announced /24 programmed over one first hop of four, and a withdrawn /24
-    that Decision never deletes."""
+    that Decision never deletes; on the vantage's own links: the same cut,
+    and a full route build that answers with the table of the event before."""
     fault(monkeypatch)
     rc = bench_run.main(
-        ["--workload", "rehearsal_fabric.prefix_churn", "--seed", str(BIG + 2),
+        ["--workload", cell, "--seed", str(BIG + 2),
          "--seconds", "1", "--allow-cpu", "--trace", "0"]
     )
     out, _ = capsys.readouterr()
