@@ -399,6 +399,11 @@ class SpfSolver(CountersMixin, HistogramsMixin):
         full path (the TPU backend overrides this)."""
         return None
 
+    def sync_counters(self, area_link_states) -> None:
+        """DeltaPath seam, called where a route build ends: a backend that
+        reads distances from a device-resident solve folds what the reads
+        left behind into its counters (the TPU backend overrides this)."""
+
     # ------------------------------------------------------------------
     # best announcing nodes
     # ------------------------------------------------------------------

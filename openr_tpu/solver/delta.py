@@ -62,7 +62,8 @@ class DeltaRouteBuilder:
 
     def __init__(self, solver, histograms: Optional[Dict] = None) -> None:
         self.solver = solver
-        # the owner's histogram dict (decision.delta_build_ms)
+        # the owner's histogram dict (decision.delta_build_ms,
+        # decision.full_build_ms)
         self.histograms = histograms
         # label -> set of nodes advertising it (collision detection for the
         # partial node-label rebuild); rebuilt lazily after any full build,
@@ -103,6 +104,7 @@ class DeltaRouteBuilder:
             log.warning("device delta poll failed: %s", exc)
         lfa_on = getattr(self.solver, "compute_lfa_paths", False)
         lfa_ready = getattr(self.solver, "lfa_delta_ready", None)
+        result = None
         if (
             changed_nodes is not None
             and not force_full
@@ -124,15 +126,28 @@ class DeltaRouteBuilder:
                     )
                 if out is not None:
                     self.delta_builds += 1
-                    return out[0], out[1], True
+                    result = out[0], out[1], True
             except Exception as exc:
                 # a delta-path bug must degrade to the full build, never
                 # wedge convergence
                 self.last_error = exc
                 log.exception("delta route build failed; falling back")
-        return self._build_full(
-            my_node_name, area_link_states, prefix_state, prev_db, policy_fn
-        )
+        if result is None:
+            # the poll has resolved the areas (refresh, solve and, where
+            # the delta was poisoned, the whole mirror's fetch, each under
+            # its own phase): what is left is the host's per-prefix work
+            with stage("decision.full_build", self.histograms, build):
+                result = self._build_full(
+                    my_node_name,
+                    area_link_states,
+                    prefix_state,
+                    prev_db,
+                    policy_fn,
+                )
+        # the build's distance reads fold nothing into the solver's
+        # counters one by one: once, here, full or delta
+        self.solver.sync_counters(area_link_states)
+        return result
 
     # ------------------------------------------------------------------
 

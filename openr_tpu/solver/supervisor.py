@@ -453,6 +453,15 @@ class SolverSupervisor(CountersMixin, HistogramsMixin):
         self._sync_backend_stats(self.primary)
         return delta
 
+    def sync_counters(self, area_link_states) -> None:
+        """The end of a DeltaPath route build: the primary folds what the
+        build's reads left into its counters, and they land here. (A full
+        build through build_route_db has done both already.)"""
+        if self.state != CLOSED:
+            return
+        self.primary.sync_counters(area_link_states)
+        self._sync_backend_stats(self.primary)
+
     def verify_route_delta(
         self, delta_db, my_node_name, area_link_states, prefix_state
     ):

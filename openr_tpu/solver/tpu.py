@@ -342,7 +342,9 @@ class _AreaSolve:
         # the last take had no device delta, the consumer must full-rebuild
         self._delta_pending: Optional[set] = set()
         self._last_solve_delta: Optional[np.ndarray] = None
-        # _sync_spf_counters bookmarks (bytes already folded into counters)
+        # _sync_spf_counters bookmarks (what is already folded into counters)
+        self._inc_synced = 0
+        self._full_synced = 0
         self._h2d_synced = 0
         self._d2h_synced = 0
         self._device_syncs_synced = 0
@@ -1636,10 +1638,13 @@ class TpuSpfSolver(SpfSolver):
         if cached is not None and cached[0] == id(link_state):
             solve = cached[1]
             before = solve.device_solves
-            inc0, full0 = solve.incremental_solves, solve.full_solves
             solve.refresh()  # incremental: patch arrays + one device call
-            self.device_solves += solve.device_solves - before
-            self._sync_spf_counters(solve, inc0, full0)
+            if solve.device_solves != before:
+                # the LinkState had moved and a solve ran: a distance
+                # read on a current solve folds nothing (the route build
+                # that reads ends in sync_counters)
+                self.device_solves += solve.device_solves - before
+                self._sync_spf_counters(solve)
             return solve
         if cached is not None:
             # a replaced LinkState for the same area: release the stale
@@ -1665,7 +1670,7 @@ class TpuSpfSolver(SpfSolver):
             on_capacity_refusal=self._note_capacity_refusal,
         )
         self.device_solves += solve.device_solves
-        self._sync_spf_counters(solve, 0, 0)
+        self._sync_spf_counters(solve)
         self._solves[key] = (id(link_state), solve)
         return solve
 
@@ -1680,17 +1685,20 @@ class TpuSpfSolver(SpfSolver):
         out, self._capacity_refusals = self._capacity_refusals, []
         return out
 
-    def _sync_spf_counters(
-        self, solve: _AreaSolve, inc0: int, full0: int
-    ) -> None:
+    def _sync_spf_counters(self, solve: _AreaSolve) -> None:
         """Fold an _AreaSolve's convergence + profiling stats into the
         decision.spf.* counters/histograms (merged into Decision's dicts
         for the monitor/ctrl API): incremental vs full solves and transfer
         bytes are monotonic, rounds/invalidation-rounds are gauges of the
         most recent solve, solve wall time lands in the warm/cold-split
-        latency histograms."""
-        d_inc = solve.incremental_solves - inc0
-        d_full = solve.full_solves - full0
+        latency histograms. Runs after a solve and at the end of a poll
+        and of a route build (`sync_counters`), never per distance read:
+        decision.spf.counter_syncs counts the runs."""
+        self._bump("decision.spf.counter_syncs")
+        d_inc = solve.incremental_solves - solve._inc_synced
+        d_full = solve.full_solves - solve._full_synced
+        solve._inc_synced = solve.incremental_solves
+        solve._full_synced = solve.full_solves
         counters = self._ensure_counters()
         if d_inc:
             self._bump("decision.spf.incremental_solves", d_inc)
@@ -1712,8 +1720,9 @@ class TpuSpfSolver(SpfSolver):
                 solve.solve_ms_last,
             )
         # transfer-byte deltas since the last sync (the lazy d mirror fetch
-        # lands on the NEXT sync — the fetch happens after this call, when
-        # the route pipeline first reads solve.d)
+        # happens after the solve's own sync, where the poll or the route
+        # pipeline first reads solve.d: its bytes land with the sync that
+        # ends that poll or that route build)
         d_h2d = solve.h2d_bytes - solve._h2d_synced
         if d_h2d:
             solve._h2d_synced = solve.h2d_bytes
@@ -1865,12 +1874,34 @@ class TpuSpfSolver(SpfSolver):
             cols = solve.take_route_delta()
             if cols is None:
                 ok = False  # keep draining the other areas' pending state
+                # the full build that must follow reads the whole mirror:
+                # fetched here, under its own phase, so that the build's
+                # stage (decision.full_build) holds host work alone
+                _ = solve.d
                 continue
             names = solve.graph.names
             changed.update(names[c] for c in cols if c < len(names))
+        self.sync_counters(area_link_states)
         if ok and self.compute_lfa_paths and me in changed:
             return None
         return changed if ok else None
+
+    def sync_counters(self, area_link_states: Dict[str, LinkState]) -> None:
+        """One counter sync per area's resident solve: what the reads
+        since the solve's own sync left behind (the lazy mirror fetch's
+        bytes, device sync and d2h phase, KSP and APSP work, the ledger's
+        gauges). Ends every poll and every route build."""
+        for link_state in area_link_states.values():
+            cached = self._solves.get((link_state.area, self.my_node_name))
+            if cached is not None and cached[0] == id(link_state):
+                self._sync_spf_counters(cached[1])
+
+    def build_route_db(self, my_node_name, area_link_states, prefix_state):
+        db = super().build_route_db(
+            my_node_name, area_link_states, prefix_state
+        )
+        self.sync_counters(area_link_states)
+        return db
 
     def lfa_delta_ready(self) -> bool:
         """DeltaPath-under-LFA capability gate (solver/delta.py): True when

@@ -914,3 +914,140 @@ class TestChangelogPastItsCap:
         assert_route_db_equal(
             SpfSolver(me).build_route_db(me, h.als, h.ps), h.db
         )
+
+
+def _every_node_announces(edges):
+    nodes = sorted({n for a, b, _ in edges for n in (a, b)})
+    return {n: [f"10.{i // 256}.{i % 256}.0/24"] for i, n in enumerate(nodes)}
+
+
+def _count_syncs(monkeypatch, solver):
+    """Calls of `solver._sync_spf_counters`, as a one-element list."""
+    calls = [0]
+    fold = solver._sync_spf_counters
+
+    def counted(solve):
+        calls[0] += 1
+        fold(solve)
+
+    monkeypatch.setattr(solver, "_sync_spf_counters", counted)
+    return calls
+
+
+class TestCounterSyncs:
+    """ISSUE 30: a route build folds the solve's statistics into the
+    counters after the solve, at the end of the poll and at the end of
+    the build: a constant number of times, whatever the number of
+    prefixes and of distance reads."""
+
+    @pytest.mark.parametrize("fabric", ["fabric", "grid"])
+    def test_full_build_syncs_a_constant_number_of_times(
+        self, monkeypatch, fabric
+    ):
+        per_size = []
+        for size in (1, 3):
+            if fabric == "fabric":
+                edges = fabric_edges(
+                    2 * size, planes=2, ssw_per_plane=2, fsw_per_pod=2,
+                    rsw_per_pod=4 * size,
+                )
+                me = "rsw0_0"
+            else:
+                edges, me = grid_edges(3 * size), "g0_0"
+            dbs = build_adj_dbs(edges)
+            ls = build_ls(edges)
+            ps = make_prefix_state(_every_node_announces(edges))
+            solver = TpuSpfSolver(me)
+            calls = _count_syncs(monkeypatch, solver)
+            builder = DeltaRouteBuilder(solver)
+            db, _, used = builder.build(me, {"0": ls}, ps, None, force_full=True)
+            assert not used and len(db.unicast_entries) == len(dbs) - 1
+            # the solve, the poll's end, build_route_db's end, the build's
+            assert calls[0] == solver.counters["decision.spf.counter_syncs"]
+            per_size.append((len(dbs), calls[0]))
+        (small, syncs_small), (large, syncs_large) = per_size
+        assert large >= 4 * small
+        assert syncs_small == syncs_large == 4
+
+    def test_delta_build_syncs_three_times_and_an_idle_one_twice(
+        self, monkeypatch
+    ):
+        side = 6
+        h = DeltaHarness(
+            grid_edges(side), "g0_0", _every_node_announces(grid_edges(side))
+        )
+        calls = _count_syncs(monkeypatch, h.solver)
+        corner = f"g{side - 1}_{side - 1}"
+        set_metric(h.dbs, h.ls, f"g{side - 2}_{side - 1}", corner, 9)
+        set_metric(h.dbs, h.ls, f"g{side - 1}_{side - 2}", corner, 9)
+        db, _, used = h.builder.build(h.me, h.als, h.ps, h.db)
+        assert used
+        assert calls[0] == 3  # the solve, the poll's end, the build's end
+        # no LSDB change (a prefix event): no solve, so the poll and the
+        # build's end alone
+        _, _, used = h.builder.build(
+            h.me, h.als, h.ps, db, dirty_prefixes={IpPrefix("10.0.3.0/24")}
+        )
+        assert used
+        assert calls[0] == 5
+
+    def test_counters_after_a_cold_and_a_warm_build_are_the_parents(self):
+        """What a cold build and a warm delta build leave in the
+        supervised solver's registry, pinned to what the parent of
+        ISSUE 30 (e0791a6, a sync on every distance read) left on the same
+        script: the lazy mirror fetch's bytes, sync and phase among it."""
+        side, me = 6, "g0_0"
+        edges = grid_edges(side)
+        dbs = build_adj_dbs(edges)
+        ls = build_ls(edges)
+        ps = make_prefix_state(
+            {n: [f"10.{i}.0.0/16"] for i, n in enumerate(sorted(dbs))}
+        )
+        sup = SolverSupervisor(
+            TpuSpfSolver(me), SpfSolver(me), SupervisorConfig()
+        )
+        builder = DeltaRouteBuilder(sup, {})
+        als = {"0": ls}
+
+        def left():
+            out = {
+                name: sup.counters.get(f"decision.spf.{name}")
+                for name in (
+                    "device_syncs", "device_to_host_bytes",
+                    "host_to_device_bytes", "delta_columns", "delta_bytes",
+                    "full_solves", "incremental_solves", "graph_recompiles",
+                    "traces_recorded",
+                )
+            }
+            for name, hist in sup._ensure_histograms().items():
+                if name.startswith("decision.spf."):
+                    out[name[len("decision.spf."):]] = hist.count
+            return out
+
+        db, _, used = builder.build(me, als, ps, None, force_full=True, build=1)
+        assert not used
+        assert left() == {
+            "device_syncs": 2, "device_to_host_bytes": 2048,
+            "host_to_device_bytes": 1248, "delta_columns": None,
+            "delta_bytes": None, "full_solves": 1,
+            "incremental_solves": None, "graph_recompiles": 0,
+            "traces_recorded": 1, "solve_ms": 1, "solve_cold_ms": 1,
+            "phase.prepare_ms": 1, "phase.h2d_ms": 1, "phase.relax_ms": 1,
+            "phase.d2h_ms": 1,
+        }
+        corner = f"g{side - 1}_{side - 1}"
+        set_metric(dbs, ls, f"g{side - 2}_{side - 1}", corner, 7)
+        set_metric(dbs, ls, f"g{side - 1}_{side - 2}", corner, 7)
+        _, _, used = builder.build(me, als, ps, db, build=2)
+        assert used
+        assert left() == {
+            "device_syncs": 8, "device_to_host_bytes": 2404,
+            "host_to_device_bytes": 2624, "delta_columns": 1,
+            "delta_bytes": 356, "full_solves": 1, "incremental_solves": 1,
+            "graph_recompiles": 0, "traces_recorded": 2, "solve_ms": 2,
+            "solve_cold_ms": 1, "solve_warm_ms": 1, "delta_extract_ms": 1,
+            "phase.refresh_ms": 1, "phase.prepare_ms": 2, "phase.h2d_ms": 2,
+            "phase.relax_ms": 2, "phase.delta_extract_ms": 1,
+            "phase.mirror_patch_ms": 1, "phase.d2h_ms": 1,
+        }
+        assert sup.counters["decision.spf.counter_syncs"] == 7

@@ -279,3 +279,101 @@ class TestCpuBackendSolveHistogram:
         hists = monitor.get_histograms()
         assert hists["decision.spf.solve_ms"]["count"] >= 1
         assert hists["convergence.e2e_ms"]["count"] == 1
+
+
+class TestFullBuildStage:
+    """ISSUE 30: `decision.full_build` is the host's work of a full route
+    build. The poll before it resolves the area (refresh, solve, the
+    whole mirror's fetch, each a phase of its own), so the stage holds no
+    `decision.spf.phase.*` stage; a delta build does not enter it."""
+
+    def _builds(self, monkeypatch):
+        import dataclasses
+
+        from openr_tpu.lsdb import LinkState, PrefixState
+        from openr_tpu.monitor import spans
+        from openr_tpu.solver import (
+            DeltaRouteBuilder,
+            SolverSupervisor,
+            SpfSolver,
+            SupervisorConfig,
+            TpuSpfSolver,
+        )
+        from openr_tpu.types import IpPrefix, PrefixDatabase, PrefixEntry
+
+        log = []
+
+        class Recorded:
+            def __init__(self, name, **kwargs):
+                self.name = name
+
+            def __enter__(self):
+                log.append(("enter", self.name))
+
+            def __exit__(self, *exc):
+                log.append(("exit", self.name))
+
+        monkeypatch.setattr(spans, "TraceAnnotation", Recorded)
+        me, side = "g0_0", 5
+        dbs = build_adj_dbs(grid_edges(side))
+        ls = LinkState("0")
+        ps = PrefixState()
+        for i, (node, db) in enumerate(sorted(dbs.items())):
+            ls.update_adjacency_database(db)
+            ps.update_prefix_database(
+                PrefixDatabase(
+                    node, [PrefixEntry(IpPrefix(f"10.{i}.0.0/16"))], area="0"
+                )
+            )
+        sup = SolverSupervisor(
+            TpuSpfSolver(me), SpfSolver(me), SupervisorConfig()
+        )
+        hists = {}
+        builder = DeltaRouteBuilder(sup, hists)
+        als = {"0": ls}
+
+        def move(a, b, metric):
+            dbs[a] = dataclasses.replace(
+                dbs[a],
+                adjacencies=[
+                    dataclasses.replace(adj, metric=metric)
+                    if adj.other_node_name == b
+                    else adj
+                    for adj in dbs[a].adjacencies
+                ],
+            )
+            ls.update_adjacency_database(dbs[a])
+
+        out = []
+        db = None
+        for k, event in enumerate(("first table", "far link", "own link")):
+            if event == "far link":
+                move("g3_4", "g4_4", 7)
+                move("g4_3", "g4_4", 7)
+            elif event == "own link":  # incident to me: no device delta
+                move("g0_0", "g0_1", 4)
+            del log[:]
+            db, _, used = builder.build(me, als, ps, db, build=k + 1)
+            out.append((used, list(log)))
+        return out, hists
+
+    def test_once_per_full_build_never_on_a_delta_build_no_phase_inside(
+        self, monkeypatch
+    ):
+        builds, hists = self._builds(monkeypatch)
+        assert [used for used, _ in builds] == [False, True, False]
+        for used, log in builds:
+            names = [name for what, name in log if what == "enter"]
+            # stages tile: each is left before the next is entered
+            assert [what for what, _ in log] == ["enter", "exit"] * len(names)
+            if used:
+                assert "decision.full_build" not in names
+                assert names[-1] == "decision.delta_build"
+                continue
+            # the solve's phases and the mirror's fetch, then the build
+            assert names.count("decision.full_build") == 1
+            assert names[-1] == "decision.full_build"
+            assert names[-2] == "decision.spf.phase.d2h"
+            assert all(n.startswith("decision.spf.phase.") for n in names[:-1])
+        assert hists["decision.full_build_ms"].count == 2
+        assert hists["decision.delta_build_ms"].count == 1

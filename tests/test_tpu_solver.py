@@ -628,6 +628,76 @@ class TestWarmStartDifferential:
         assert tpu.counters["decision.spf.rounds_last"] <= cold_rounds
 
 
+class TestDistanceReadsOutsideABuild:
+    """ISSUE 30: `_spf` / `_dist` fold the solve's statistics into the
+    counters only where a solve ran; a read on a current solve folds
+    nothing, and a LinkState that moved between two reads is still
+    re-solved before the second one answers."""
+
+    EDGES = [("a", "b", 1), ("b", "c", 1), ("a", "c", 5)]
+
+    def _set_bc(self, ls, dbs, metric):
+        import dataclasses
+
+        dbs["b"] = dataclasses.replace(
+            dbs["b"],
+            adjacencies=[
+                dataclasses.replace(adj, metric=metric)
+                if adj.other_node_name == "c"
+                else adj
+                for adj in dbs["b"].adjacencies
+            ],
+        )
+        ls.update_adjacency_database(dbs["b"])
+
+    @pytest.mark.parametrize("read", ["dist", "spf"])
+    def test_a_moved_link_state_is_re_solved_before_the_next_read(self, read):
+        dbs = build_adj_dbs(self.EDGES)
+        ls = build_ls(self.EDGES)
+        tpu = TpuSpfSolver("a")
+
+        def a_to_c():
+            if read == "dist":
+                return tpu._dist(ls, "a", "c")
+            return tpu._spf(ls, "a")["c"].metric
+
+        def syncs():
+            return tpu.counters["decision.spf.counter_syncs"]
+
+        assert a_to_c() == 2  # through b
+        assert (tpu.device_solves, syncs()) == (1, 1)
+        for _ in range(50):
+            assert a_to_c() == 2
+            assert tpu._dist(ls, "b", "c") == 1  # a neighbour's row
+        assert (tpu.device_solves, syncs()) == (1, 1)
+        self._set_bc(ls, dbs, 3)
+        assert a_to_c() == 4
+        assert (tpu.device_solves, syncs()) == (2, 2)
+        assert tpu.counters["decision.spf.incremental_solves"] == 1
+        self._set_bc(ls, dbs, 9)
+        assert a_to_c() == 5  # the direct link
+        assert tpu._spf(ls, "a")["c"].next_hops == {"c"}
+        assert (tpu.device_solves, syncs()) == (3, 3)
+
+    def test_what_a_read_leaves_lands_with_the_next_sync(self):
+        ls = build_ls(self.EDGES)
+        ps = make_prefix_state({"c": [PFXS[0]]})
+        tpu = TpuSpfSolver("a")
+        assert tpu._dist(ls, "a", "c") == 2  # solves, syncs, then fetches
+        solve = tpu._solves[("0", "a")][1]
+        assert solve.device_syncs == 2  # the round count, the mirror
+        assert tpu.counters["decision.spf.device_syncs"] == 1
+        assert "decision.spf.device_to_host_bytes" not in tpu.counters
+        # a route build ends in one sync, whatever it read
+        tpu.build_route_db("a", {"0": ls}, ps)
+        assert tpu.counters["decision.spf.device_syncs"] == 2
+        assert (
+            tpu.counters["decision.spf.device_to_host_bytes"]
+            == solve.d.nbytes
+        )
+        assert tpu.counters["decision.spf.counter_syncs"] == 2
+
+
 def all_pairs_distance_check_graph(ls, graph):
     """all_pairs_distance_check against a pre-built CompiledGraph."""
     d = np.asarray(batched_spf(graph, np.arange(graph.n_pad, dtype=np.int32)))
