@@ -10,7 +10,7 @@ process imports JAX (the only children are `make` building native/).
            its ctrl socket, then 32 topology events, each awaited until
            Fib has it; FIB == CPU oracle, every fallback/failure counter 0.
   stage B  every other jitted family through the TPU compiler once, at
-           the repo's own bench shapes, each against its tier-1 oracle.
+           the sizes of `CHIP` below, each against its tier-1 oracle.
   stage C  with >= 4 devices: stage A's load and 8 events on
            solver_mesh (4,1) and (2,2), with proof of spread.
 
@@ -41,7 +41,8 @@ AREA = "0"
 
 @dataclasses.dataclass(frozen=True)
 class Sizes:
-    """Problem sizes: the chip run's are the repo's own bench defaults."""
+    """Problem sizes: the chip run's follow BASELINE.md's configs (1k
+    grid, 100k-node WAN x 128 sources, 50k-node KSP)."""
 
     fabric: dict  # topology.fabric_edges arguments (3-tier Clos)
     wan_n: int  # batched_spf WAN nodes x wan_sources rows
@@ -714,7 +715,7 @@ def stage_b(sz: Sizes, seed: int) -> None:
         area_equals_native("_bf_solver_warm + _delta_extract")
     solve.close()
 
-    # -- ecmp_dag at its own bench size -----------------------------------
+    # -- ecmp_dag on the grid ---------------------------------------------
     from openr_tpu.ops.spf import ecmp_dag
     from openr_tpu.solver.native_spf import NativeSpfSolver
     from openr_tpu.topology import grid_edges

@@ -650,7 +650,7 @@ def _all_shortest_paths(graph, src, dst, limit=16):
 
 
 def _check_artifact_schema(artifact: dict) -> None:
-    """SOAK_r*/BENCH_r*/fleet artifacts are stamped with schema_version +
+    """SOAK_r*/fleet artifacts are stamped with schema_version +
     build fingerprint (utils/build_info.py). An unknown version means the
     offline render below may misread fields — warn and render best-effort
     anyway; a missing stamp just gets a note (pre-stamp artifacts stay

@@ -240,11 +240,6 @@ class StreamConfigSection:
     coalesce_budget: int = 4096
     # hard cap on concurrent subscriptions (typed server-busy beyond)
     max_subscribers: int = 1024
-    # encode each delta once per filter-equivalence class and share the
-    # bytes across subscribers (docs/Streaming.md "Shared-encode
-    # fan-out"); false restores the per-subscriber re-encode path for
-    # before/after measurement
-    shared_encode: bool = True
     # admission queue for runTeOptimize / getRouteDbComputed /
     # getConvergenceReport: concurrent cost units, bounded queue wait,
     # queue depth caps (global + per client — the fairness bound)

@@ -1011,9 +1011,7 @@ class CtrlServer:
             )
         return body
 
-    async def _write_frame(
-        self, writer, segments, drain: bool = True, legacy_path: bool = False
-    ) -> None:
+    async def _write_frame(self, writer, segments, drain: bool = True) -> None:
         """Per-subscriber delivery: splice the envelope around the
         (possibly shared) body in ONE transport write — writev-style,
         `writelines` joins the segments once instead of issuing one
@@ -1023,28 +1021,15 @@ class CtrlServer:
         `drain=False` while the subscriber queue still holds frames and
         drain once at burst end — the buffered bytes stay bounded by
         `subscriber_max_pending` frames, and a stalled client still
-        blocks its own task at the burst-end drain, nobody else's.
-
-        `legacy_path` (the `stream_config.shared_encode: false` A/B
-        baseline / rollback, docs/Streaming.md) restores the
-        pre-sharing delivery verbatim: one transport write per segment
-        and an unconditional per-frame drain — so the before/after
-        meters compare the old serving path against the new one, not a
-        half-upgraded hybrid."""
+        blocks its own task at the burst-end drain, nobody else's."""
         t0 = time.perf_counter()
-        if legacy_path:
-            total = 0
-            for seg in segments:
-                writer.write(seg)
-                total += len(seg)
-        else:
-            writer.writelines(segments)
-            total = sum(len(seg) for seg in segments)
+        writer.writelines(segments)
+        total = sum(len(seg) for seg in segments)
         if self.stream_manager is not None:
             self.stream_manager.note_deliver(
                 (time.perf_counter() - t0) * 1e3, total
             )
-        if drain or legacy_path:
+        if drain:
             await writer.drain()
 
     async def _ack_codec(self, writer, req_id, codec_name) -> None:
@@ -1089,9 +1074,6 @@ class CtrlServer:
             originators=set(originators),
             label=str(params.get("client") or ""),
         )
-        # shared_encode=false is the A/B baseline: serve exactly the way
-        # the pre-sharing code did (see _write_frame)
-        legacy_delivery = not self.stream_manager.config.shared_encode
         try:
             if codec_name == stream_codec.CODEC_BINARY:
                 await self._ack_codec(writer, req_id, codec_name)
@@ -1128,8 +1110,8 @@ class CtrlServer:
                     # filter-equivalence class, reused here
                     body = frame.body(codec_name)
                 else:
-                    # coalesced merges (and the shared_encode=false
-                    # path) are per-subscriber state: private encode
+                    # coalesced merges are per-subscriber state:
+                    # private encode
                     body = self._encode_body(
                         stream_codec.encode_kv_body, frame, codec_name
                     )
@@ -1142,7 +1124,6 @@ class CtrlServer:
                         codec_name, req_id, kind, seq, area, body, legacy
                     ),
                     drain=not (sub._frames or sub._resync_at is not None),
-                    legacy_path=legacy_delivery,
                 )
                 self.stream_manager.mark_delivered(sub, t_enq)
         # CancelledError must PROPAGATE: server shutdown cancels this
@@ -1191,7 +1172,6 @@ class CtrlServer:
         sub = self.stream_manager.add_route_subscriber(
             label=str(params.get("client") or "")
         )
-        legacy_delivery = not self.stream_manager.config.shared_encode
         try:
             if codec_name == stream_codec.CODEC_BINARY:
                 await self._ack_codec(writer, req_id, codec_name)
@@ -1233,7 +1213,6 @@ class CtrlServer:
                         codec_name, req_id, kind, seq, body
                     ),
                     drain=not (sub._frames or sub._resync_at is not None),
-                    legacy_path=legacy_delivery,
                 )
                 self.stream_manager.mark_delivered(sub, t_enq)
         # CancelledError must propagate (see _kvstore_stream)

@@ -48,8 +48,8 @@ flight-recorder forensics dump, and the fleet observer's `device_memory`
 SLO rule (headroom budget + leak trend over the live-bytes series).
 
 A process-global default ledger (`get_ledger()`) mirrors the process-
-wide compile caches: bench's raw-jit paths and the module-level solver
-factories share one accounting domain. Tests that need isolation
+wide compile caches: the module-level solver factories share one
+accounting domain. Tests that need isolation
 construct their own `MemLedger` and pass it down.
 """
 
@@ -165,10 +165,8 @@ class MemLedger:
         # chip's HBM, not four
         self._devices: Optional[Tuple[Any, ...]] = None
         self._externals: Dict[str, Callable[[], Dict[str, Any]]] = {}
-        # per-structure live/peak, folded onto the fixed gauge vocabulary
-        # (bench lines report the structure peak next to predict_fit)
+        # per-structure live bytes, folded onto the fixed gauge vocabulary
         self._struct_live: Dict[str, int] = {}
-        self._struct_peak: Dict[str, int] = {}
 
     @staticmethod
     def _fold_structure(structure: str) -> str:
@@ -178,10 +176,7 @@ class MemLedger:
     def _struct_delta(self, structure: str, delta: int) -> None:
         """Adjust one structure's live bytes (caller holds the lock)."""
         key = self._fold_structure(structure)
-        live = self._struct_live.get(key, 0) + delta
-        self._struct_live[key] = live
-        if live > self._struct_peak.get(key, 0):
-            self._struct_peak[key] = live
+        self._struct_live[key] = self._struct_live.get(key, 0) + delta
 
     # -- registration ---------------------------------------------------
 
@@ -318,14 +313,6 @@ class MemLedger:
         out = {name: 0 for name in STRUCT_GAUGES}
         with self._lock:
             out.update(self._struct_live)
-        return out
-
-    def structure_peak_bytes(self) -> Dict[str, int]:
-        """Peak live bytes per structure over the ledger's lifetime (the
-        bench lines' mem_peak_bytes source)."""
-        out = {name: 0 for name in STRUCT_GAUGES}
-        with self._lock:
-            out.update(self._struct_peak)
         return out
 
     def attach_external(
@@ -665,7 +652,7 @@ _LEDGER = MemLedger()
 
 def get_ledger() -> MemLedger:
     """The process-global ledger (the default accounting domain — the
-    compile caches and bench's raw-jit paths are process-wide, so the
-    default ledger is too). Tests needing isolation construct their own
-    `MemLedger` and pass it to the structures they build."""
+    compile caches are process-wide, so the default ledger is too).
+    Tests needing isolation construct their own `MemLedger` and pass it
+    to the structures they build."""
     return _LEDGER

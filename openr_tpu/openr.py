@@ -410,7 +410,6 @@ class OpenrDaemon:
                 subscriber_max_pending=stc.subscriber_max_pending,
                 coalesce_budget=stc.coalesce_budget,
                 max_subscribers=stc.max_subscribers,
-                shared_encode=stc.shared_encode,
             ),
             loop=loop,
         )

@@ -76,15 +76,6 @@ class SlicedEll:
             tuple(a.shape for a in self.nbr),
         )
 
-    def patched_wg(self, w_edges: np.ndarray) -> Tuple[np.ndarray, ...]:
-        """Fresh wg bucket arrays carrying `w_edges` (length e, dst-sorted
-        edge order) — the weight-variant path for benches/KSP rows."""
-        out = [a.copy() for a in self.wg]
-        for k in range(len(out)):
-            sel = self.edge_bucket == k
-            out[k][self.edge_row[sel], self.edge_slot[sel]] = w_edges[sel]
-        return tuple(out)
-
 
 @dataclass
 class CompiledGraph:

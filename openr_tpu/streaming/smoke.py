@@ -170,7 +170,6 @@ def run_stream_smoke() -> Dict[str, Any]:
         # one filter class while the whole cohort is attached
         assert live["kv_filter_classes"] == 1, summary
         assert live["kv_subscribers"] == subs, summary
-        assert live["shared_encode"] is True, summary
         # nothing overflowed: the invariant below would not hold otherwise
         assert summary["coalesced"] == 0, summary
         assert summary["resyncs"] == 0, summary

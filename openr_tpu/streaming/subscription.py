@@ -17,8 +17,7 @@ per-connection stream handlers:
     resulting `SharedFrame` memoizes its serialized body once per codec —
     per-subscriber work is a queue append plus an envelope splice and
     buffer write in the connection task (docs/Streaming.md
-    "Shared-encode fan-out"; `shared_encode: false` restores the
-    historical per-subscriber re-encode path for measurement).
+    "Shared-encode fan-out").
   - Each subscriber holds a **bounded** frame queue. When a slow client
     falls `max_pending` frames behind, the queue is coalesced: KvStore
     deltas merge per key (newest value wins, expiry/update cancel each
@@ -72,11 +71,6 @@ class StreamConfig:
     coalesce_budget: int = 4096
     # hard cap on concurrent subscriptions (typed server-busy beyond)
     max_subscribers: int = 1024
-    # encode each delta once per filter-equivalence class and share the
-    # bytes across the class (docs/Streaming.md "Shared-encode fan-out");
-    # off = the historical per-subscriber re-encode path, kept for
-    # before/after measurement on identical flap batches
-    shared_encode: bool = True
 
 
 class SharedFrame:
@@ -167,10 +161,10 @@ class _BaseSubscription:
     # -- publisher side (dispatch task) --------------------------------
 
     def offer(self, item: Any, t_enq: float) -> None:
-        """Non-blocking enqueue; never raises, never waits. Called by the
-        StreamManager dispatch task for every source-queue item (the
-        per-subscriber-filter path; the shared path pre-filters once per
-        class and calls `offer_shared`)."""
+        """Non-blocking enqueue of one unfiltered source item; never
+        raises, never waits. The dispatch task pre-filters once per
+        class and calls `offer_shared`; this is the per-subscriber
+        filter, as the tests of filtering and coalescing drive it."""
         if self.closed:
             return
         filtered = self._filter(item)
@@ -587,7 +581,6 @@ class StreamManager(CountersMixin, HistogramsMixin):
             "route_subscribers": len(self._route_subs),
             "kv_filter_classes": len(self._kv_classes),
             "route_filter_classes": len(self._route_classes),
-            "shared_encode": self.config.shared_encode,
             "max_subscribers": self.config.max_subscribers,
             "subscriber_max_pending": self.config.subscriber_max_pending,
             "coalesce_budget": self.config.coalesce_budget,
@@ -603,7 +596,6 @@ class StreamManager(CountersMixin, HistogramsMixin):
         classes: Dict[Tuple, List[_BaseSubscription]],
         kind: str,
     ) -> None:
-        shared = self.config.shared_encode
         try:
             while True:
                 item = await reader.get()
@@ -613,23 +605,19 @@ class StreamManager(CountersMixin, HistogramsMixin):
                     # named fault seam: an injected fan-out failure must
                     # degrade to marked resyncs, never silent loss
                     fault_point("ctrl.stream.publish", item)
-                    if shared:
-                        # filter ONCE per filter-equivalence class, wrap
-                        # the result in a SharedFrame whose body bytes
-                        # every class member reuses; per-subscriber work
-                        # is one queue append
-                        for members in list(classes.values()):
-                            if not members:
-                                continue
-                            filtered = members[0]._filter(item)
-                            if filtered is None:
-                                continue
-                            frame = SharedFrame(filtered, kind, self)
-                            for sub in list(members):
-                                sub.offer_shared(frame, t_enq)
-                    else:
-                        for sub in list(subs):
-                            sub.offer(item, t_enq)
+                    # filter ONCE per filter-equivalence class, wrap
+                    # the result in a SharedFrame whose body bytes
+                    # every class member reuses; per-subscriber work
+                    # is one queue append
+                    for members in list(classes.values()):
+                        if not members:
+                            continue
+                        filtered = members[0]._filter(item)
+                        if filtered is None:
+                            continue
+                        frame = SharedFrame(filtered, kind, self)
+                        for sub in list(members):
+                            sub.offer_shared(frame, t_enq)
                 except Exception:
                     self._bump("ctrl.stream.publish_errors")
                     for sub in list(subs):

@@ -1,4 +1,4 @@
-"""Demand-matrix and fixture builders for the TE service, bench and tests.
+"""Demand-matrix and fixture builders for the TE service and its tests.
 
 Demand specs are plain JSON (the `breeze decision te-optimize --demands
 file.json` format):
@@ -29,8 +29,8 @@ from openr_tpu.topology import Edge
 
 def congested_clos_fixture() -> Tuple[List[Edge], Dict]:
     """Deterministic 2-pod Clos with an express link and a skewed demand
-    matrix — the acceptance fixture (tests/test_te_service.py) and the
-    bench topology (bench.py te_optimize_ms).
+    matrix — the acceptance fixture (tests/test_te_service.py) and
+    chip_smoke.py stage B's TE topology.
 
     Two spines, two leaves per pod, every leaf dual-homed at metric 1,
     plus a direct l0_0—l1_0 express link. Under uniform weights the big

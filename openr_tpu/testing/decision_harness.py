@@ -355,50 +355,6 @@ def run_fault_smoke() -> dict:
         loop.close()
 
 
-def _measure_exporter_overhead(net) -> dict:
-    """Exporter-overhead measurement on a converged emulator run (the
-    bench 'exporter_scrape_render_ms' line): best full-registry render
-    latency across nodes (each render parsed back to keep the sample
-    honest — an exposition that stops parsing is a failure, not a fast
-    render), plus the per-record windowed-rollup cost measured by
-    replaying the run's real span samples into a fresh rollup."""
-    import os
-
-    from openr_tpu.monitor.exporter import parse_metrics_text
-    from openr_tpu.monitor.report import ConvergenceRollup
-
-    render_ms: List[float] = []
-    series = 0
-    for wrapper in net.wrappers.values():
-        wrapper.daemon.exporter.render()  # warm the self-metric families
-        t0 = time.perf_counter()
-        text = wrapper.daemon.exporter.render()
-        render_ms.append((time.perf_counter() - t0) * 1e3)
-        series = max(series, len(parse_metrics_text(text)["types"]))
-
-    spans = [
-        span for report in net.node_reports() for span in report["spans"]
-    ]
-    records = max(1, int(os.environ.get("BENCH_EXPORTER_RECORDS", "2000")))
-    rollup = ConvergenceRollup(window_s=60.0)
-    replayed = 0
-    t0 = time.perf_counter()
-    while spans and replayed < records:
-        for span in spans:
-            rollup.record_span(span)
-            replayed += 1
-            if replayed >= records:
-                break
-    elapsed = time.perf_counter() - t0
-    return {
-        "scrape_render_ms": round(min(render_ms), 4) if render_ms else 0.0,
-        "rollup_record_us": (
-            round(elapsed / replayed * 1e6, 3) if replayed else 0.0
-        ),
-        "metrics_series": series,
-    }
-
-
 # stage-duration keys every node's flap span must carry (the spark→fib
 # chain; flood-hop stages are topology-dependent and checked separately)
 TRACE_SMOKE_STAGES = (
@@ -554,115 +510,79 @@ def run_decision_backend_parity(
         loop.close()
 
 
-def run_bench_convergence(
-    nodes: int = 5,
-    flaps: int = 2,
-    backend: str = "tpu",
-    measure_exporter: bool = True,
-    subscribers: int = 0,
-    fleet_observer: bool = False,
-    codec: str = "json",
-    inproc_subscribers: int = 0,
-    shared_encode: bool = True,
-    stall_subscriber: bool = False,
-    max_subscribers: Optional[int] = None,
+# the flap batch's fixed settings: one value at every call
+_FLAP_BATCH_BACKEND = "cpu"
+# pinned SPF debounce window, so a wave does not carry 10-250 ms of
+# timer jitter
+_FLAP_BATCH_DEBOUNCE_MS = (10.0, 50.0)
+
+
+def run_flap_batch(
+    nodes: int,
+    flaps: int,
+    subscribers: int,
+    inproc_subscribers: int,
+    codec: str = "mixed",
     churn_keys: int = 0,
     churn_value_bytes: int = 4096,
-    debounce_ms: Optional[Tuple[float, float]] = None,
-    journal: bool = False,
-    chaos_loss: float = 0.0,
-    chaos_seed: int = 1,
 ) -> dict:
-    """Hello-to-programmed-route percentiles from an emulator flap run —
-    bench.py's second metric line (ROADMAP "relight the benchmark").
+    """A flap batch served to a subscriber cohort, judged by the fleet
+    observer — the scale leg of the soak round (testing/soak.py
+    `run_soak_round`, docs/Streaming.md "Fan-out at scale").
 
-    A `nodes`-node line topology converges, then the middle link fails and
-    restores `flaps` times; every event's spark→fib convergence span lands
-    in the per-node monitor rings and is folded network-wide by
-    `VirtualNetwork.convergence_report()` (the `breeze perf report` math).
-    Returns the aggregate e2e percentiles, so DeltaPath / solver wins show
-    up in the benchmark trajectory as `convergence.e2e_ms`, not just raw
-    SPF/s. The daemons run the requested Decision solver backend (tpu by
-    default: this is the path the delta extraction serves).
+    A `nodes`-node line topology converges, then the middle link fails
+    and restores `flaps` times while the fan-out serves:
 
-    With `subscribers` > 0 the same flap batch additionally carries N
-    concurrent `subscribeKvStore` streams (spread round-robin across the
-    nodes' real ctrl sockets) — bench.py's `stream_fanout_events_s` line:
-    the summary gains stream_{subscribers,frames,deltas,resyncs,
-    events_per_s} so delta-delivery throughput and the convergence-p95
-    cost of fan-out are measured on one run, plus the per-subscriber
-    frame-encode bill (`ctrl.stream.encode_ms/encode_bytes`, the
-    serving-wall hypothesis meters): stream_encode_{ms_total,frames,
-    bytes} and stream_encode_share — the fraction of the batch's wall
-    clock the fleet spent re-encoding frames per connection
-    (docs/Streaming.md).
+      - `subscribers` (>= 1) `subscribeKvStore` streams over the nodes'
+        real ctrl sockets, spread round-robin; `codec` picks their frame
+        codec: "json", "binary", or "mixed" (alternating);
+      - `inproc_subscribers` in-process subscribers per the same spread
+        (testing/fanout.py — the cohort half the fd limit forbids as
+        sockets), reported separately;
+      - the first socket subscriber (on n0, label "stalled") throttled
+        into overflow→resync through the `ctrl.stream.deliver` fault
+        point: slow-client isolation under load;
+      - `churn_keys` originations of `churn_value_bytes` each riding
+        every wave (flooded area-wide), so the frames are LSDB-sized
+        publications and not bare adjacency deltas.
 
-    With `fleet_observer=True` the fleet observer (openr_tpu/fleet)
-    attaches over the real ctrl sockets for the whole batch — bench.py's
-    `fleet_watch_overhead_ms` line: the summary gains
-    fleet_{tick_ms,scrape_ms,scrapes,ticks} so the continuous watchdog's
-    per-tick cost is measured on the same run whose convergence p95 the
-    detached baseline measured.
+    Admission control stays live: the per-node subscriber cap is sized
+    for the cohort plus headroom. The fleet observer (openr_tpu/fleet)
+    is attached over the real ctrl sockets for the whole batch; the
+    summary's `fleet_findings_by_kind` maps each finding kind to the
+    nodes it fired on, so a caller can check that a
+    `stream_backpressure` breach is attributable to n0 alone.
 
-    Scale/proof knobs (docs/Streaming.md "Shared-encode fan-out"):
-    `codec` picks the socket subscribers' frame codec — "json",
-    "binary", or "mixed" (round-robin, the soak-round cohort shape);
-    `inproc_subscribers` adds an in-process cohort per node
-    (testing/fanout.py — the 100k-subscriber half the fd limit forbids
-    as sockets), reported separately in the summary;
-    `shared_encode=False` restores the per-subscriber re-encode path
-    (before/after measurement on identical flap batches);
-    `stall_subscriber=True` throttles the first socket subscriber into
-    overflow→resync via the `ctrl.stream.deliver` fault point, proving
-    slow-client isolation live under load; `max_subscribers` raises the
-    per-node subscription cap for scale cohorts; `churn_keys` > 0
-    enriches every flap wave with that many production-sized key
-    originations (`churn_value_bytes` each, flooded area-wide) so the
-    fan-out legs serve LSDB-sized publications instead of bare
-    adjacency deltas — both A/B legs get the identical enriched
-    batch; `debounce_ms=(min, max)` pins the SPF debounce window so
-    A/B fan-out legs don't eat 10–250 ms of per-wave timer jitter in
-    their events/s denominators.
-
-    With `journal=True` every node records the flap batch into its
-    state journal (openr_tpu/journal, in-memory ring) — bench.py's
-    `journal_record_us` line: the summary gains journal_{records,
-    record_us,evicted,replay_verified} so the per-record overhead and
-    its convergence-p95 cost are measured on one run, and the final
-    state is replay-verified against the CPU oracle on every node
-    (docs/Journal.md)."""
+    Every event's spark→fib convergence span lands in the per-node
+    monitor rings and is folded by `VirtualNetwork.convergence_report()`.
+    The stream meters (`ctrl.stream.encode_*` / `deliver_*`) are
+    reported as deltas over the flap window: subscription-time snapshot
+    encodes are set-up, not serving."""
+    from openr_tpu.ctrl.client import CtrlClient
+    from openr_tpu.fleet import FleetConfig, FleetObserver
+    from openr_tpu.testing.fanout import InprocFanout
+    from openr_tpu.testing.faults import FaultInjector, injected
     from openr_tpu.testing.wrapper import VirtualNetwork, wait_until
 
+    assert subscribers >= 1, "the stalled subscriber is a socket subscriber"
     n = max(3, nodes)
     mid = n // 2
+    mid_link = (f"n{mid}", f"if{mid}r", f"n{mid + 1}", f"if{mid + 1}l")
 
     async def body() -> dict:
-        stream_overrides: dict = {"shared_encode": shared_encode}
-        if max_subscribers is not None:
-            stream_overrides["max_subscribers"] = max_subscribers
-        decision_overrides: dict = {"solver_backend": backend}
-        if debounce_ms is not None:
-            decision_overrides["debounce_min_ms"] = debounce_ms[0]
-            decision_overrides["debounce_max_ms"] = debounce_ms[1]
         overrides: dict = {
-            "decision_config": decision_overrides,
-            "stream_config": stream_overrides,
+            "decision_config": {
+                "solver_backend": _FLAP_BATCH_BACKEND,
+                "debounce_min_ms": _FLAP_BATCH_DEBOUNCE_MS[0],
+                "debounce_max_ms": _FLAP_BATCH_DEBOUNCE_MS[1],
+            },
+            "stream_config": {
+                "max_subscribers": (
+                    (subscribers + inproc_subscribers) // n + 64
+                )
+            },
         }
-        if journal:
-            overrides["journal_config"] = {"enabled": True}
-        # chaos_loss > 0: the flap batch runs over a seeded lossy fabric
-        # (KvStore RPC loss via testing/chaos.py; Spark stays clean so
-        # adjacency churn is the flaps', not the schedule's) — bench.py's
-        # `convergence_under_loss_p95_ms` line
-        mesh = None
-        if chaos_loss > 0.0:
-            from openr_tpu.testing.chaos import ChaosLinkSpec, ChaosMesh
-
-            mesh = ChaosMesh(seed=chaos_seed)
-            mesh.set_default(
-                ChaosLinkSpec(loss=chaos_loss, spark_loss=0.0)
-            )
-        net = VirtualNetwork(chaos=mesh)
+        net = VirtualNetwork()
         for i in range(n):
             net.add_node(
                 f"n{i}",
@@ -685,11 +605,9 @@ def run_bench_convergence(
             return codec
 
         async def watch(client, label, sub_codec) -> None:
-            # decode=False: the watchers are throughput meters — they
-            # read every frame off the socket but skip payload parsing
-            # (the server's fan-out is what's being measured, and at
-            # 2048 watchers on one box the consumer-side json.loads
-            # otherwise dominates the wall clock of BOTH A/B legs)
+            # decode=False: the watchers read every frame off the socket
+            # but skip payload parsing (at 2048 watchers on one box the
+            # consumer-side json.loads otherwise dominates the wall clock)
             try:
                 async for frame in client.subscribe(
                     "subscribeKvStore",
@@ -712,10 +630,7 @@ def run_bench_convergence(
                 pass
 
         def read_stream_meters() -> dict:
-            """Fleet-wide serving-wall meter totals (docs/Streaming.md):
-            sampled before and after the flap batch so the reported
-            stats cover the MEASURED WINDOW only — subscription-time
-            snapshot encodes are setup cost, not fan-out serving."""
+            """Fleet-wide stream meter totals (docs/Streaming.md)."""
             t = {
                 "encode_ms": 0.0,
                 "encode_frames": 0,
@@ -735,26 +650,17 @@ def run_bench_convergence(
                 dhist = sm.histograms.get("ctrl.stream.deliver_ms")
                 if dhist is not None:
                     t["deliver_ms"] += dhist.sum
-                t["encode_bytes"] += sm.counters.get(
-                    "ctrl.stream.encode_bytes", 0
-                )
-                t["deliver_bytes"] += sm.counters.get(
-                    "ctrl.stream.deliver_bytes", 0
-                )
-                t["deliveries"] += sm.counters.get(
-                    "ctrl.stream.delivered", 0
-                )
-                t["classes"] += sm.counters.get(
-                    "ctrl.stream.encode_classes", 0
-                )
-                t["class_hits"] += sm.counters.get(
-                    "ctrl.stream.encode_class_hits", 0
-                )
+                for key, counter in (
+                    ("encode_bytes", "ctrl.stream.encode_bytes"),
+                    ("deliver_bytes", "ctrl.stream.deliver_bytes"),
+                    ("deliveries", "ctrl.stream.delivered"),
+                    ("classes", "ctrl.stream.encode_classes"),
+                    ("class_hits", "ctrl.stream.encode_class_hits"),
+                ):
+                    t[key] += sm.counters.get(counter, 0)
             return t
 
         async def start_subscribers() -> None:
-            from openr_tpu.ctrl.client import CtrlClient
-
             wrappers = list(net.wrappers.values())
             for i in range(subscribers):
                 wrapper = wrappers[i % len(wrappers)]
@@ -762,20 +668,14 @@ def run_bench_convergence(
                     "127.0.0.1", wrapper.ctrl_port
                 ).connect()
                 sub_clients.append(client)
-                label = (
-                    "stalled"
-                    if (stall_subscriber and i == 0)
-                    else "bench"
-                )
+                label = "stalled" if i == 0 else "cohort"
                 sub_tasks.append(
                     asyncio.get_running_loop().create_task(
                         watch(client, label, _sub_codec(i))
                     )
                 )
 
-        async def start_inproc() -> None:
-            from openr_tpu.testing.fanout import InprocFanout
-
+        def start_inproc() -> None:
             wrappers = list(net.wrappers.values())
             base, extra = divmod(inproc_subscribers, len(wrappers))
             for i, wrapper in enumerate(wrappers):
@@ -807,17 +707,31 @@ def run_bench_convergence(
                 and "10.0.0.0/24" not in right
             )
 
-        observer = None
-        injector_ctx = None
-        try:
-            if stall_subscriber:
-                from openr_tpu.testing.faults import (
-                    FaultInjector,
-                    injected,
+        churn_wave = 0
+
+        def churn() -> None:
+            nonlocal churn_wave
+            if not churn_keys:
+                return
+            churn_wave += 1
+            kv = net.wrappers["n0"].daemon.kvstore
+            pad = (f"wave{churn_wave}:".encode() * (
+                churn_value_bytes // 6 + 1
+            ))[:churn_value_bytes]
+            for k in range(churn_keys):
+                kv.set_key(
+                    f"flapbatch:churn:{k}",
+                    Value(
+                        version=churn_wave,
+                        originator_id="n0",
+                        value=pad,
+                    ),
+                    area="0",
                 )
 
-                injector_ctx = injected(FaultInjector())
-                inj = injector_ctx.__enter__()
+        observer = None
+        try:
+            with injected(FaultInjector()) as inj:
                 inj.arm(
                     "ctrl.stream.deliver",
                     times=None,
@@ -826,220 +740,68 @@ def run_bench_convergence(
                         getattr(sub, "label", "") == "stalled"
                     ),
                 )
-            await wait_until(converged, timeout=60.0)
-            if subscribers:
+                await wait_until(converged, timeout=60.0)
                 await start_subscribers()
-                # every socket subscriber must have its snapshot before
-                # the flap clock starts: the initial dumps are private
-                # per-subscriber encodes (setup, not fan-out serving)
-                # and racing them into the measured window inflates
-                # encode_share with O(subscribers) setup cost
+                # every socket subscriber has its snapshot before the
+                # first flap: the initial dumps are private encodes
+                # (set-up), kept out of the window's meters
                 await wait_until(
                     lambda: counts["snapshots"] >= subscribers,
                     timeout=max(60.0, subscribers / 50.0),
                 )
-            if inproc_subscribers:
                 # no snapshot wait: in-process subscribers register
-                # directly on the manager (no initial dump rides their
-                # queues — testing/fanout.py), so attach has no encode
-                # cost to keep out of the window
-                await start_inproc()
-            if fleet_observer:
-                from openr_tpu.fleet import FleetConfig, FleetObserver
-
+                # directly on the manager, no initial dump rides their
+                # queues (testing/fanout.py)
+                start_inproc()
                 observer = FleetObserver.for_network(
                     net, config=FleetConfig(scrape_interval_s=0.2)
                 )
                 await observer.start()
-            churn_wave = 0
 
-            def churn() -> None:
-                """`churn_keys` production-sized key originations per
-                wave (flooded area-wide like any LSDB key), so the
-                fan-out serves realistic publication bodies — identical
-                content for both A/B legs."""
-                nonlocal churn_wave
-                if not churn_keys:
-                    return
-                churn_wave += 1
-                kv = net.wrappers["n0"].daemon.kvstore
-                pad = (f"wave{churn_wave}:".encode() * (
-                    churn_value_bytes // 6 + 1
-                ))[:churn_value_bytes]
-                for k in range(churn_keys):
-                    kv.set_key(
-                        f"bench:churn:{k}",
-                        Value(
-                            version=churn_wave,
-                            originator_id="n0",
-                            value=pad,
-                        ),
-                        area="0",
-                    )
-
-            meters0 = read_stream_meters()
-            t_stream0 = time.perf_counter()
-            for _ in range(max(1, flaps)):
-                net.fail_link(
-                    f"n{mid}", f"if{mid}r", f"n{mid + 1}", f"if{mid + 1}l"
-                )
-                churn()
-                await wait_until(partitioned, timeout=60.0)
-                net.restore_link(
-                    f"n{mid}", f"if{mid}r", f"n{mid + 1}", f"if{mid + 1}l"
-                )
-                churn()
-                await wait_until(converged, timeout=60.0)
-            if subscribers and not stall_subscriber:
-                # the batch isn't served until every watcher has it:
-                # the clock keeps running while deliveries drain, so a
-                # leg that lags its subscribers pays for the lag in
-                # events/s (frame counts stable over two 0.1s reads).
-                # Skipped when a subscriber is deliberately stalled —
-                # it trickles one frame per throttle period, so frame
-                # counts never go stable on a meaningful timescale.
-                stable = {"last": -1}
-
-                def watchers_drained() -> bool:
-                    now = counts["frames"]
-                    done = now == stable["last"]
-                    stable["last"] = now
-                    return done
-
-                await wait_until(
-                    watchers_drained, timeout=60.0, interval=0.1
-                )
-            stream_elapsed = time.perf_counter() - t_stream0
-            if subscribers or inproc_cohorts:
-                # drain: deliveries race the last convergence check
+                meters0 = read_stream_meters()
+                t_stream0 = time.perf_counter()
+                for _ in range(max(1, flaps)):
+                    net.fail_link(*mid_link)
+                    churn()
+                    await wait_until(partitioned, timeout=60.0)
+                    net.restore_link(*mid_link)
+                    churn()
+                    await wait_until(converged, timeout=60.0)
+                # no wait for the socket watchers to go quiet: the
+                # stalled one trickles a frame per throttle period
+                stream_elapsed = time.perf_counter() - t_stream0
+                # deliveries race the last convergence check
                 await asyncio.sleep(0.2)
-            if inproc_cohorts:
-                # let the pump tasks finish the backlog before reading
-                # their stats (bounded wait: queues are bounded too)
-                def inproc_drained() -> bool:
-                    return all(
-                        not sub._frames and sub._resync_at is None
-                        for cohort in inproc_cohorts
-                        for sub in cohort.subs
-                    )
+                if inproc_cohorts:
+                    # let the pump tasks finish the backlog before
+                    # reading their stats; the deadline scales with the
+                    # cohort (one core drains ~100k subscribers' final
+                    # frames in tens of seconds)
+                    def inproc_drained() -> bool:
+                        return all(
+                            not sub._frames and sub._resync_at is None
+                            for cohort in inproc_cohorts
+                            for sub in cohort.subs
+                        )
 
-                # the backlog scales with cohort size: one CPU core
-                # drains ~100k subscribers' final frames in tens of
-                # seconds, so the deadline must scale with the cohort
-                await wait_until(
-                    inproc_drained,
-                    timeout=max(30.0, inproc_subscribers / 500.0),
-                )
-                for cohort in inproc_cohorts:
-                    await cohort.stop()
-            agg = net.convergence_report()
-            exporter_stats = (
-                _measure_exporter_overhead(net) if measure_exporter else {}
-            )
-            encode_stats = {}
-            if subscribers or inproc_cohorts:
-                # the serving-wall meters (docs/Streaming.md): real body
-                # serializations (encode_*) vs per-subscriber splice-and-
-                # write work (deliver_*) vs shared-bytes reuse
-                # (encode_classes/encode_class_hits), summed fleet-wide
-                # and reported as WINDOW DELTAS against the pre-flap
-                # baseline (meters0) so subscription-time snapshot
-                # encodes never pollute the serving-wall share
+                    await wait_until(
+                        inproc_drained,
+                        timeout=max(30.0, inproc_subscribers / 500.0),
+                    )
+                    for cohort in inproc_cohorts:
+                        await cohort.stop()
+                agg = net.convergence_report()
                 meters1 = read_stream_meters()
-                ms_total = meters1["encode_ms"] - meters0["encode_ms"]
-                frames = (
-                    meters1["encode_frames"] - meters0["encode_frames"]
-                )
-                nbytes = meters1["encode_bytes"] - meters0["encode_bytes"]
-                deliver_ms = meters1["deliver_ms"] - meters0["deliver_ms"]
-                deliver_bytes = (
-                    meters1["deliver_bytes"] - meters0["deliver_bytes"]
-                )
-                deliveries = meters1["deliveries"] - meters0["deliveries"]
-                classes = meters1["classes"] - meters0["classes"]
-                class_hits = meters1["class_hits"] - meters0["class_hits"]
+                window = {k: meters1[k] - meters0[k] for k in meters1}
                 node_resyncs: dict = {}
                 for name, wrapper in net.wrappers.items():
-                    sm = wrapper.daemon.stream_manager
-                    resyncs = sm.counters.get("ctrl.stream.resyncs", 0)
+                    resyncs = wrapper.daemon.stream_manager.counters.get(
+                        "ctrl.stream.resyncs", 0
+                    )
                     if resyncs:
                         node_resyncs[name] = resyncs
-                encode_stats = {
-                    "stream_shared_encode": shared_encode,
-                    "stream_codec": codec,
-                    "stream_encode_ms_total": round(ms_total, 3),
-                    "stream_encode_frames": frames,
-                    "stream_encode_bytes": nbytes,
-                    "stream_encode_classes": classes,
-                    "stream_encode_class_hits": class_hits,
-                    "stream_class_hit_rate": round(
-                        class_hits / (class_hits + classes), 6
-                    )
-                    if (class_hits + classes)
-                    else 0.0,
-                    "stream_deliver_ms_total": round(deliver_ms, 3),
-                    "stream_deliver_bytes": deliver_bytes,
-                    "stream_deliveries": deliveries,
-                    "stream_node_resyncs": node_resyncs,
-                    "stream_encode_us_per_frame": round(
-                        ms_total / frames * 1e3, 3
-                    )
-                    if frames
-                    else 0.0,
-                    "stream_encode_share": round(
-                        (ms_total / 1e3) / stream_elapsed, 6
-                    )
-                    if stream_elapsed > 0
-                    else 0.0,
-                }
-                if inproc_cohorts:
-                    encode_stats["stream_inproc_subscribers"] = sum(
-                        c.stats["subscribers"] for c in inproc_cohorts
-                    )
-                    encode_stats["stream_inproc_frames"] = sum(
-                        c.stats["frames"] for c in inproc_cohorts
-                    )
-                    encode_stats["stream_inproc_resyncs"] = sum(
-                        c.stats["resyncs"] for c in inproc_cohorts
-                    )
-                    encode_stats["stream_inproc_bytes"] = sum(
-                        c.stats["bytes"] for c in inproc_cohorts
-                    )
-                if stall_subscriber:
-                    encode_stats["stream_stalled_kinds"] = sorted(
-                        set(stalled_kinds)
-                    )
-            journal_stats = {}
-            if journal:
-                j_records = j_evicted = j_verified = 0
-                rec_sum = 0.0
-                rec_count = 0
-                for wrapper in net.wrappers.values():
-                    jr = wrapper.daemon.journal
-                    j_records += jr.counters.get("journal.records", 0)
-                    j_evicted += jr.counters.get("journal.evicted", 0)
-                    hist = jr.histograms.get("journal.record_ms")
-                    if hist is not None:
-                        rec_sum += hist.sum
-                        rec_count += hist.count
-                    if jr.verify_replay().get("match"):
-                        j_verified += 1
-                journal_stats = {
-                    "journal_records": j_records,
-                    "journal_evicted": j_evicted,
-                    # sampled guard: record_ms holds every sample_every-th
-                    # record's cost, so the avg IS the per-record estimate
-                    "journal_record_us": (
-                        round(rec_sum / rec_count * 1e3, 3)
-                        if rec_count
-                        else 0.0
-                    ),
-                    "journal_replay_verified": j_verified,
-                    "journal_nodes": len(net.wrappers),
-                }
-            fleet_stats = {}
-            if observer is not None:
                 await observer.stop()
+                findings = list(observer.findings)
                 tick = observer.histograms.get("fleet.tick_ms")
                 scrape = observer.histograms.get("fleet.scrape_ms")
                 fleet_stats = {
@@ -1051,28 +813,17 @@ def run_bench_convergence(
                     "fleet_scrapes": observer.counters.get(
                         "fleet.scrapes", 0
                     ),
-                    "fleet_findings": len(observer.findings),
-                    # kind -> sorted node list, so callers can check a
-                    # breach is ATTRIBUTABLE (the soak round's judge:
-                    # stream_backpressure may only fire on the node
-                    # hosting the deliberately stalled subscriber)
+                    "fleet_findings": len(findings),
+                    # kind -> sorted node list: is a breach attributable?
                     "fleet_findings_by_kind": {
                         kind: sorted(
-                            {
-                                f.node
-                                for f in observer.findings
-                                if f.kind == kind
-                            }
+                            {f.node for f in findings if f.kind == kind}
                         )
-                        for kind in sorted(
-                            {f.kind for f in observer.findings}
-                        )
+                        for kind in sorted({f.kind for f in findings})
                     },
                 }
                 observer = None
         finally:
-            if injector_ctx is not None:
-                injector_ctx.__exit__(None, None, None)
             if observer is not None:
                 await observer.stop()
             for cohort in inproc_cohorts:
@@ -1080,48 +831,66 @@ def run_bench_convergence(
                     await cohort.stop()
             for task in sub_tasks:
                 task.cancel()
-            if sub_tasks:
-                await asyncio.gather(*sub_tasks, return_exceptions=True)
+            await asyncio.gather(*sub_tasks, return_exceptions=True)
             for client in sub_clients:
                 await client.close()
             await net.stop_all()
 
         e2e = agg["e2e_ms"]
-        stream_stats = {}
-        if subscribers or encode_stats:
-            stream_stats = {
-                "stream_subscribers": subscribers,
-                "stream_frames": counts["frames"],
-                "stream_deltas": counts["deltas"],
-                "stream_resyncs": counts["resyncs"],
-                "stream_events_per_s": (
-                    counts["deltas"] / stream_elapsed
-                    if stream_elapsed > 0
-                    else 0.0
-                ),
-                **encode_stats,
-            }
-        chaos_stats = {}
-        if mesh is not None:
-            chaos_stats = {
-                "chaos_loss": chaos_loss,
-                "chaos_seed": chaos_seed,
-                "chaos_kv_dropped": mesh.stats.get("kv_dropped", 0),
-            }
-        return {
+        encode_frames = window["encode_frames"]
+        class_total = window["class_hits"] + window["classes"]
+        summary = {
             "nodes": n,
             "flaps": max(1, flaps),
-            "backend": backend,
+            "backend": _FLAP_BATCH_BACKEND,
             "spans_total": agg["spans_total"],
             "e2e_p50_ms": e2e["p50"],
             "e2e_p95_ms": e2e["p95"],
             "e2e_max_ms": e2e["max"],
-            **exporter_stats,
-            **stream_stats,
+            "stream_subscribers": subscribers,
+            "stream_frames": counts["frames"],
+            "stream_deltas": counts["deltas"],
+            "stream_resyncs": counts["resyncs"],
+            "stream_events_per_s": (
+                counts["deltas"] / stream_elapsed
+                if stream_elapsed > 0
+                else 0.0
+            ),
+            "stream_codec": codec,
+            "stream_encode_ms_total": round(window["encode_ms"], 3),
+            "stream_encode_frames": encode_frames,
+            "stream_encode_bytes": window["encode_bytes"],
+            "stream_encode_classes": window["classes"],
+            "stream_encode_class_hits": window["class_hits"],
+            "stream_class_hit_rate": (
+                round(window["class_hits"] / class_total, 6)
+                if class_total
+                else 0.0
+            ),
+            "stream_deliver_ms_total": round(window["deliver_ms"], 3),
+            "stream_deliver_bytes": window["deliver_bytes"],
+            "stream_deliveries": window["deliveries"],
+            "stream_node_resyncs": node_resyncs,
+            "stream_encode_us_per_frame": (
+                round(window["encode_ms"] / encode_frames * 1e3, 3)
+                if encode_frames
+                else 0.0
+            ),
+            "stream_encode_share": (
+                round((window["encode_ms"] / 1e3) / stream_elapsed, 6)
+                if stream_elapsed > 0
+                else 0.0
+            ),
+            "stream_stalled_kinds": sorted(set(stalled_kinds)),
+            **{
+                f"stream_inproc_{key}": sum(
+                    c.stats[key] for c in inproc_cohorts
+                )
+                for key in ("subscribers", "frames", "resyncs", "bytes")
+            },
             **fleet_stats,
-            **journal_stats,
-            **chaos_stats,
         }
+        return summary
 
     loop = asyncio.new_event_loop()
     try:

@@ -18,7 +18,7 @@ runs a HYBRID cohort:
     landing in a counting sink instead of a socket.
 
 The cohort sizes are reported separately everywhere (SOAK artifact,
-bench summaries) so the accounting stays honest about what was a real
+flap-batch summary) so the accounting stays honest about what was a real
 socket and what was in-process.
 """
 

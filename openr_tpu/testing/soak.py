@@ -907,43 +907,27 @@ def run_soak_round(
     fanout_nodes: int = 8,
     fanout_flaps: int = 2,
     fanout_inproc: Optional[int] = None,
-    fanout_ab_runs: int = 2,
     out_dir: str = ".",
 ) -> Dict[str, Any]:
-    """The real soak round, wired into the artifact flow (the ROADMAP
-    "run the long soak at scale" item): one full chord+chaos+restart
-    soak with stream-mode scrapes AND the fleet observer attached (its
-    verdict embedded in the artifact), followed by the fan-out proof
-    (docs/Streaming.md "Shared-encode fan-out") — the convergence flap
-    batch run three ways:
-
-      1. `fanout_before`: `fanout_subscribers` socket subscriptions with
-         `shared_encode=false` — the historical per-subscriber re-encode
-         bill (the SOAK_r01 serving wall), measured fresh;
-      2. `fanout`: the SAME batch with sharing on — encode share and
-         delta throughput before/after on identical work;
-
-    Both A/B legs serve the flap batch ENRICHED with production-sized
-    key churn (`churn_keys`/`churn_value_bytes` per wave, flooded
-    area-wide — LSDB-sized publications, not bare adjacency deltas),
-    run with the SPF debounce window pinned (so events/s denominators
-    don't eat 10–250 ms of per-wave timer jitter), and each leg runs
-    `fanout_ab_runs` times with the best run kept (all runs recorded in
-    the artifact) — one emulated core serves 8 daemons plus 2048
-    watchers, so single-run wall clocks carry ±20% scheduler noise;
-      3. `fanout_scale`: the 100k-subscriber push — the socket cohort
-         (mixed JSON/binary codecs, admission control live, one
-         subscriber deliberately stalled into overflow→resync) plus the
-         in-process cohort (`fanout_inproc`, testing/fanout.py — the fd
-         limit forbids 100k real sockets; the artifact reports the
-         split honestly) with the fleet observer attached as SLO judge:
-         every `stream_backpressure` breach must be attributable to the
-         stalled subscriber's node, anything else fails the round.
+    """The soak round that writes a `SOAK_r<NN>.json` artifact: one full
+    chord+chaos+restart soak with stream-mode scrapes AND the fleet
+    observer attached (its verdict embedded in the artifact), followed
+    by `fanout_scale`, the 100k-subscriber push (docs/Streaming.md
+    "Fan-out at scale"): a flap batch
+    (decision_harness.run_flap_batch) enriched with production-sized
+    key churn and served to the socket cohort (mixed JSON/binary
+    codecs, admission control live, one subscriber deliberately stalled
+    into overflow→resync) plus the in-process cohort (`fanout_inproc`,
+    testing/fanout.py — the fd limit forbids 100k real sockets; the
+    artifact reports the split honestly), with the fleet observer
+    attached as SLO judge: every `stream_backpressure` breach must be
+    attributable to the stalled subscriber's node, anything else fails
+    the round.
 
     `fanout_inproc` defaults to SOAK_FANOUT_INPROC (98304: with the
-    2048-socket cohort the total crosses 100k). Writes `SOAK_r<NN>.json`;
-    returns the artifact dict."""
-    from openr_tpu.testing.decision_harness import run_bench_convergence
+    2048-socket cohort the total crosses 100k). Returns the artifact
+    dict."""
+    from openr_tpu.testing.decision_harness import run_flap_batch
 
     if cfg is None:
         nodes = int(os.environ.get("SOAK_ROUND_NODES", "96"))
@@ -980,116 +964,37 @@ def run_soak_round(
     soak_report = run_soak(cfg)
     soak_s = time.time() - t0
 
-    # the shared A/B batch shape: mixed codecs (the cohort shape),
-    # production-sized key churn riding every wave, debounce pinned
-    ab_kwargs: Dict[str, Any] = dict(
+    # the 100k hybrid cohort with the fleet observer as judge
+    t0 = time.time()
+    fanout_scale = run_flap_batch(
         nodes=fanout_nodes,
         flaps=fanout_flaps,
-        backend="cpu",
-        measure_exporter=False,
         subscribers=fanout_subscribers,
+        inproc_subscribers=fanout_inproc,
         codec="mixed",
         churn_keys=int(os.environ.get("SOAK_FANOUT_CHURN_KEYS", "8")),
         churn_value_bytes=int(
             os.environ.get("SOAK_FANOUT_CHURN_BYTES", "16384")
         ),
-        debounce_ms=(10.0, 50.0),
-    )
-
-    def best_of(runs: int, **kwargs) -> Tuple[Dict[str, Any], List[float]]:
-        """Best events/s of `runs` identical legs (every run's
-        throughput recorded): one core serves the whole emulation, so
-        the best run is the least scheduler-polluted measurement."""
-        best: Optional[Dict[str, Any]] = None
-        seen: List[float] = []
-        for _ in range(max(1, runs)):
-            leg = run_bench_convergence(**kwargs)
-            seen.append(round(leg.get("stream_events_per_s", 0.0), 1))
-            if best is None or leg.get(
-                "stream_events_per_s", 0.0
-            ) > best.get("stream_events_per_s", 0.0):
-                best = leg
-        return best, seen
-
-    # 1. before: sharing off — the per-subscriber re-encode bill
-    t0 = time.time()
-    fanout_before, before_runs = best_of(
-        fanout_ab_runs, shared_encode=False, **ab_kwargs
-    )
-    before_s = time.time() - t0
-
-    # 2. after: identical batch with the shared-encode path on
-    t0 = time.time()
-    fanout, after_runs = best_of(fanout_ab_runs, **ab_kwargs)
-    fanout_s = time.time() - t0
-    fanout_before["events_per_s_runs"] = before_runs
-    fanout["events_per_s_runs"] = after_runs
-
-    share_before = fanout_before.get("stream_encode_share", 0.0)
-    share = fanout.get("stream_encode_share", 0.0)
-    events_before = fanout_before.get("stream_events_per_s", 0.0)
-    events_after = fanout.get("stream_events_per_s", 0.0)
-    speedup = events_after / events_before if events_before else 0.0
-    per_frame = fanout.get("stream_encode_us_per_frame", 0.0)
-    fanout["verdict"] = (
-        f"{fanout_subscribers} subscribers x {fanout_nodes} nodes: "
-        f"shared-encode fan-out cut the encode share of the batch wall "
-        f"clock from {share_before * 100:.1f}% (per-subscriber "
-        f"re-encode) to {share * 100:.1f}% "
-        f"({fanout.get('stream_encode_classes', 0)} class encodes, "
-        f"{fanout.get('stream_encode_class_hits', 0)} shared reuses at "
-        f"{per_frame:.1f}us/encode) and moved delta delivery from "
-        f"{events_before:.0f} to {events_after:.0f} events/s "
-        f"({speedup:.2f}x) on identical flap batches — "
-        + (
-            "the serving wall is down: fan-out cost is now "
-            "O(filter-classes), not O(subscribers)"
-            if share <= 0.05 and speedup >= 2.0
-            else "below the >=2x / <=5%-share acceptance bar; "
-            "investigate before trusting the shared path"
-        )
-    )
-
-    # 3. scale: the 100k hybrid cohort with the fleet observer as judge
-    t0 = time.time()
-    fanout_scale = run_bench_convergence(
-        nodes=fanout_nodes,
-        flaps=fanout_flaps,
-        backend="cpu",
-        measure_exporter=False,
-        subscribers=fanout_subscribers,
-        fleet_observer=True,
-        codec="mixed",
-        churn_keys=ab_kwargs["churn_keys"],
-        churn_value_bytes=ab_kwargs["churn_value_bytes"],
-        debounce_ms=ab_kwargs["debounce_ms"],
-        inproc_subscribers=fanout_inproc,
-        stall_subscriber=True,
-        # every cohort member counts against the per-node cap; leave
-        # admission control LIVE but sized for the cohort plus headroom
-        max_subscribers=(
-            (fanout_subscribers + fanout_inproc) // fanout_nodes + 64
-        ),
     )
     scale_s = time.time() - t0
-    total_subs = fanout_subscribers + fanout_scale.get(
-        "stream_inproc_subscribers", 0
-    )
+    inproc_subs = fanout_scale["stream_inproc_subscribers"]
+    total_subs = fanout_subscribers + inproc_subs
     # the stalled socket subscriber is index 0 -> node n0: any
     # stream_backpressure finding elsewhere is an UNATTRIBUTED breach
-    backpressure_nodes = fanout_scale.get(
-        "fleet_findings_by_kind", {}
-    ).get("stream_backpressure", [])
+    backpressure_nodes = fanout_scale["fleet_findings_by_kind"].get(
+        "stream_backpressure", []
+    )
     unattributed = [nd for nd in backpressure_nodes if nd != "n0"]
     fanout_scale["verdict"] = (
         f"{total_subs} total subscribers "
         f"({fanout_subscribers} real sockets, mixed JSON/binary codecs, "
-        f"{fanout_scale.get('stream_inproc_subscribers', 0)} in-process "
+        f"{inproc_subs} in-process "
         f"via testing/fanout.py) across {fanout_nodes} nodes with one "
         f"deliberately stalled socket subscriber: encode share "
-        f"{fanout_scale.get('stream_encode_share', 0.0) * 100:.1f}%, "
+        f"{fanout_scale['stream_encode_share'] * 100:.1f}%, "
         f"class hit rate "
-        f"{fanout_scale.get('stream_class_hit_rate', 0.0):.3f}, "
+        f"{fanout_scale['stream_class_hit_rate']:.3f}, "
         f"stream_backpressure findings on "
         f"{backpressure_nodes or 'no nodes'} — "
         + (
@@ -1115,22 +1020,13 @@ def run_soak_round(
         "kind": "SOAK",
         "config": asdict(cfg),
         "soak_wall_s": round(soak_s, 1),
-        "fanout_before_wall_s": round(before_s, 1),
-        "fanout_wall_s": round(fanout_s, 1),
         "fanout_scale_wall_s": round(scale_s, 1),
         "soak": soak_report,
         "fleet_verdict": (soak_report.get("fleet") or {}).get("verdict"),
-        "fanout_before": fanout_before,
-        "fanout": fanout,
         "fanout_scale": fanout_scale,
         "fanout_total_subscribers": total_subs,
         "fanout_socket_subscribers": fanout_subscribers,
-        "fanout_inproc_subscribers": fanout_scale.get(
-            "stream_inproc_subscribers", 0
-        ),
-        "encode_share_before": share_before,
-        "encode_share_after": share,
-        "fanout_speedup": round(speedup, 3),
+        "fanout_inproc_subscribers": inproc_subs,
     }
     path = os.path.join(out_dir, f"SOAK_r{round_index:02d}.json")
     with open(path, "w") as fh:
@@ -1210,9 +1106,6 @@ def main(argv: Optional[List[str]] = None) -> int:
                 {
                     "soak": "PASS" if verdict["pass"] else "FAIL",
                     "fleet": "PASS" if fleet.get("pass") else "BREACH",
-                    "encode_share_before": artifact["encode_share_before"],
-                    "encode_share_after": artifact["encode_share_after"],
-                    "fanout_speedup": artifact["fanout_speedup"],
                     "total_subscribers": artifact[
                         "fanout_total_subscribers"
                     ],

@@ -1,4 +1,4 @@
-"""Topology-as-code builders for tests and benchmarks.
+"""Topology-as-code builders for the tests and `chip_smoke.py`.
 
 Equivalent of the fixture builders in openr/decision/tests/DecisionTestUtils.h
 (createGrid, createAdjacency) and the grid/fabric generators in

@@ -15,8 +15,8 @@ from typing import Dict
 VERSION = "1.0.0"  # single source of truth; breeze derives its banner from it
 PACKAGE = "openr-tpu"
 
-# SOAK_r*/BENCH_r* artifact field contract: bump when the shape of the
-# judged report / bench line changes, so offline renderers (`breeze perf
+# SOAK_r* artifact field contract: bump when the shape of the
+# judged report changes, so offline renderers (`breeze perf
 # soak-report`, `breeze fleet report`) can warn instead of misreading
 ARTIFACT_SCHEMA_VERSION = 1
 
@@ -24,7 +24,7 @@ ARTIFACT_SCHEMA_VERSION = 1
 def build_fingerprint() -> str:
     """`git describe --always --dirty` of the source tree, degrading to
     the package VERSION outside a checkout — stamped next to
-    ARTIFACT_SCHEMA_VERSION in every soak/bench artifact so a report
+    ARTIFACT_SCHEMA_VERSION in every soak artifact so a report
     line is always traceable to the exact code that produced it."""
     import os
     import subprocess

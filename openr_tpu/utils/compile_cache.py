@@ -1,6 +1,6 @@
 """Where JAX keeps compiled programs between processes.
 
-Every composition root (OpenrDaemon, bench.py, benchmarks/*, chip_smoke.py,
+Every composition root (OpenrDaemon, chipbench/run.py, chip_smoke.py,
 __graft_entry__.py) calls `ensure_compile_cache()` before its first
 compile. The directory is decided in exactly one way:
 

@@ -35,9 +35,18 @@ def test_node_name_required():
         Config.from_dict({})
 
 
-def test_unknown_field_rejected():
+@pytest.mark.parametrize(
+    "extra",
+    [
+        {"not_a_field": 1},
+        # a key that existed until PR 31 is rejected like any other
+        {"stream_config": {"shared_encode": False}},
+    ],
+    ids=["top_level", "stream_config.shared_encode"],
+)
+def test_unknown_field_rejected(extra):
     with pytest.raises(ValueError, match="unknown config field"):
-        Config.from_dict({"node_name": "n1", "not_a_field": 1})
+        Config.from_dict({"node_name": "n1", **extra})
 
 
 def test_load_file(tmp_path):
