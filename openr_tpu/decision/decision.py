@@ -38,6 +38,7 @@ from openr_tpu.solver import (
     TpuSpfSolver,
     get_route_delta,
 )
+from openr_tpu.solver.cpu import BACKEND_COUNTER_PREFIXES
 from openr_tpu.solver.rib_policy import RibPolicy
 from openr_tpu.types import (
     ADJ_DB_MARKER,
@@ -860,7 +861,7 @@ class Decision(CountersMixin, HistogramsMixin):
         # histogram objects are shared by reference — the solver keeps
         # recording into them, the monitor merges copies on export
         for key, value in self.solver.counters.items():
-            if key.startswith(("decision.spf.", "decision.mem.")):
+            if key.startswith(BACKEND_COUNTER_PREFIXES):
                 self.counters[key] = value
         for key, hist in self.solver._ensure_histograms().items():
             if key.startswith("decision.spf."):

@@ -398,6 +398,10 @@ class LinkState:
         # monotonically bumped on every topology change; lets external
         # solvers (TPU backend) cache compiled graphs per snapshot
         self.version = 0
+        # bumped where a link's next-hop address or adjacency label moved
+        # (no topology change, so `version` stands): what a backend keeps
+        # of a link's attributes it reads again
+        self.link_attr_version = 0
 
     # -- read API ----------------------------------------------------------
 
@@ -544,6 +548,8 @@ class LinkState:
 
         if change.topology_changed:
             self._invalidate()
+        if change.link_attributes_changed:
+            self.link_attr_version += 1
         return change
 
     def bulk_update_adjacency_databases(

@@ -45,6 +45,15 @@ from openr_tpu.types import (
 Metric = int
 INF_METRIC = 1 << 62
 
+# what a solver backend counts for Decision's registry: whoever owns the
+# backend (the supervisor, Decision) copies the counters named so
+BACKEND_COUNTER_PREFIXES = (
+    "decision.spf.",
+    "decision.mem.",
+    "decision.route_build_table_routes",
+    "decision.route_build_generic_routes",
+)
+
 
 @dataclass
 class BestPathCalResult:
@@ -369,29 +378,19 @@ class SpfSolver(CountersMixin, HistogramsMixin):
                     )
                 },
             )
-        min_metric, nh_nodes = self.get_next_hops_with_metric(
+        next_hops = self.next_hops_toward(
             my_node_name,
             {adj_db.this_node_name},
             False,
+            False,
+            top_label,
             area_link_states,
+            {area},
         )
-        if not nh_nodes:
+        if next_hops is None:
             self._bump("decision.no_route_to_label")
             return None
-        return RibMplsEntry(
-            top_label,
-            self.get_next_hops(
-                my_node_name,
-                {adj_db.this_node_name},
-                False,
-                False,
-                min_metric,
-                nh_nodes,
-                top_label,
-                area_link_states,
-                {area},
-            ),
-        )
+        return RibMplsEntry(top_label, next_hops)
 
     def poll_device_delta(self, area_link_states) -> Optional[set]:
         """DeltaPath seam: backends without device-resident distance state
@@ -574,25 +573,21 @@ class SpfSolver(CountersMixin, HistogramsMixin):
             get_prefix_forwarding_type(prefix_entries)
             == PrefixForwardingType.SR_MPLS
         )
-        min_metric, nh_nodes = self.get_next_hops_with_metric(
-            my_node_name, ret.nodes, per_destination, area_link_states
+        next_hops = self.next_hops_toward(
+            my_node_name,
+            ret.nodes,
+            is_v4,
+            per_destination,
+            None,
+            area_link_states,
+            ret.areas,
         )
-        if not nh_nodes:
+        if next_hops is None:
             self._bump("decision.no_route_to_prefix")
             return
         unicast_entries[prefix] = RibUnicastEntry(
             prefix=prefix,
-            nexthops=self.get_next_hops(
-                my_node_name,
-                ret.nodes,
-                is_v4,
-                per_destination,
-                min_metric,
-                nh_nodes,
-                None,
-                area_link_states,
-                ret.areas,
-            ),
+            nexthops=next_hops,
             best_prefix_entry=prefix_entries[ret.best_node][ret.best_area],
             best_area=ret.best_area,
         )
@@ -623,25 +618,21 @@ class SpfSolver(CountersMixin, HistogramsMixin):
         if len(best_next_hop) != 1:
             self._bump("decision.missing_loopback_addr")
             return
-        min_metric, nh_nodes = self.get_next_hops_with_metric(
-            my_node_name, dst_info.nodes, False, area_link_states
+        next_hops = self.next_hops_toward(
+            my_node_name,
+            dst_info.nodes,
+            is_v4,
+            False,
+            None,
+            area_link_states,
+            dst_info.areas,
         )
-        if not nh_nodes:
+        if next_hops is None:
             self._bump("decision.no_route_to_prefix")
             return
         unicast_entries[prefix] = RibUnicastEntry(
             prefix=prefix,
-            nexthops=self.get_next_hops(
-                my_node_name,
-                dst_info.nodes,
-                is_v4,
-                False,
-                min_metric,
-                nh_nodes,
-                None,
-                area_link_states,
-                dst_info.areas,
-            ),
+            nexthops=next_hops,
             best_prefix_entry=prefix_entries[dst_info.best_node][
                 dst_info.best_area
             ],
@@ -830,6 +821,38 @@ class SpfSolver(CountersMixin, HistogramsMixin):
                     min_cost_nodes = set()
                 min_cost_nodes.add(dst)
         return shortest, min_cost_nodes
+
+    def next_hops_toward(
+        self,
+        my_node_name: str,
+        dst_node_names: Set[str],
+        is_v4: bool,
+        per_destination: bool,
+        swap_label: Optional[int],
+        area_link_states: Dict[str, LinkState],
+        prefix_areas: Set[str],
+    ) -> Optional[Set[NextHop]]:
+        """The next hops of one route, toward the closest of
+        `dst_node_names`, or None where none of them is reachable: the
+        seam of the ECMP unicast routes and the node-label routes. A
+        backend that holds every destination's first hops at once (the
+        TPU's) answers it from there."""
+        min_metric, next_hop_nodes = self.get_next_hops_with_metric(
+            my_node_name, dst_node_names, per_destination, area_link_states
+        )
+        if not next_hop_nodes:
+            return None
+        return self.get_next_hops(
+            my_node_name,
+            dst_node_names,
+            is_v4,
+            per_destination,
+            min_metric,
+            next_hop_nodes,
+            swap_label,
+            area_link_states,
+            prefix_areas,
+        )
 
     def get_next_hops_with_metric(
         self,

@@ -47,6 +47,7 @@ import time
 from dataclasses import dataclass
 from typing import Dict, List, Optional
 
+from openr_tpu.solver.cpu import BACKEND_COUNTER_PREFIXES
 from openr_tpu.solver.routes import get_route_delta
 from openr_tpu.utils.backoff import ExponentialBackoff
 from openr_tpu.utils.counters import CountersMixin, HistogramsMixin
@@ -904,7 +905,7 @@ class SolverSupervisor(CountersMixin, HistogramsMixin):
         counters = getattr(backend, "counters", None)
         if isinstance(counters, dict):
             for key, value in counters.items():
-                if key.startswith(("decision.spf.", "decision.mem.")):
+                if key.startswith(BACKEND_COUNTER_PREFIXES):
                     self.counters[key] = value
         ensure = getattr(backend, "_ensure_histograms", None)
         if ensure is not None:

@@ -29,7 +29,7 @@ order).
 from __future__ import annotations
 
 import time
-from typing import Dict, List, Optional, Set, Tuple
+from typing import Dict, FrozenSet, List, Optional, Set, Tuple
 
 import numpy as np
 
@@ -56,6 +56,7 @@ from openr_tpu.solver.flight_recorder import (
     phase_stage,
 )
 from openr_tpu.testing.faults import fault_point
+from openr_tpu.types import MplsAction, MplsActionCode, NextHop
 
 
 class DeviceCapacityError(RuntimeError):
@@ -228,6 +229,118 @@ class _ApspSpfResult:
                     nhs.add(n)
         self._nh_cache[dest] = nhs
         return nhs
+
+
+_PHP = MplsAction(MplsActionCode.PHP)
+
+
+class _NextHopTable:
+    """Every destination's next hops, read off one resident solve.
+
+    A destination's column of `nh_mask` names its first-hop links (its
+    group), the distance row gives its metric, and the up-links'
+    attributes are read once for all destinations. A group is keyed by
+    what its column holds, not by which column it is: DeltaPath patches
+    columns in place, and a content key needs no invalidation. The table
+    goes where its mask goes (`_AreaSolve._drop_nh_mask`); the mask stays
+    the one place that decides a first hop."""
+
+    def __init__(self, solve: "_AreaSolve") -> None:
+        me = solve.me
+        self._solve = solve
+        self._mask = solve.nh_mask()[1]
+        self.attr_version = solve.link_state.link_attr_version
+        # mask row i is my i-th up-link: (neighbour, v4 and v6 next-hop
+        # address, interface, area)
+        self._links = [
+            (
+                link.other_node_name(me),
+                link.nh_v4_from_node(me),
+                link.nh_v6_from_node(me),
+                link.iface_from_node(me),
+                link.area,
+            )
+            for link in solve.nh_up_links()
+        ]
+        assert len(self._links) == len(self._mask)
+        self._groups: Dict[bytes, Tuple[tuple, ...]] = {}
+        # (group, metric, is_v4) -> the unicast next hops of every
+        # destination behind that group at that distance
+        self.unicast_sets: Dict[
+            Tuple[bytes, int, bool], FrozenSet[NextHop]
+        ] = {}
+
+    def next_hops(
+        self,
+        dst_node_names: Set[str],
+        is_v4: bool,
+        swap_label: Optional[int],
+    ) -> Optional[Set[NextHop]]:
+        """What `SpfSolver.next_hops_toward` gives without LFA and per-
+        destination actions, or None where no destination is reachable."""
+        solve = self._solve
+        node_index = solve.graph.node_index
+        from_me = solve.d[0]
+        if len(dst_node_names) == 1:
+            (dst,) = dst_node_names
+            col = node_index.get(dst)
+            if col is None:
+                return None
+            metric = int(from_me[col])
+            group_key = self._mask[:, col].tobytes()
+        else:
+            # the closest announcers (get_min_cost_nodes' rule): a link
+            # is a first hop toward the set where it is toward one of them
+            cols = [
+                col
+                for col in map(node_index.get, dst_node_names)
+                if col is not None
+            ]
+            if not cols:
+                return None
+            dists = from_me[cols]
+            metric = int(dists.min())
+            nearest = [c for c, m in zip(cols, dists) if m == metric]
+            group_key = self._mask[:, nearest].any(axis=1).tobytes()
+        if metric >= INF:
+            return None
+        group = self._groups.get(group_key)
+        if group is None:
+            member = np.frombuffer(group_key, dtype=np.bool_)
+            group = self._groups[group_key] = tuple(
+                self._links[i] for i in np.flatnonzero(member)
+            )
+        if not group:
+            return None  # toward myself
+        # a label's next hops are its own by construction (its SWAP
+        # carries it): what they share is the group's links and one action
+        swap = (
+            None
+            if swap_label is None
+            else MplsAction(MplsActionCode.SWAP, swap_label=swap_label)
+        )
+        next_hops = (
+            NextHop(
+                v4 if is_v4 else v6,
+                iface,
+                metric,
+                swap and (_PHP if neighbor in dst_node_names else swap),
+                False,
+                area,
+                0,
+                neighbor,
+            )
+            for neighbor, v4, v6, iface, area in group
+        )
+        if swap is not None:
+            return set(next_hops)
+        key = (group_key, metric, is_v4)
+        shared = self.unicast_sets.get(key)
+        if shared is None:
+            shared = self.unicast_sets[key] = frozenset(next_hops)
+        # a set of its own for every route: RibPolicy rewrites an entry's
+        # nexthops, and no sibling's may change with it
+        return set(shared)
 
 
 class _AreaSolve:
@@ -583,8 +696,7 @@ class _AreaSolve:
             # until the consumer takes it (and full-rebuilds)
             self._d_host = None
             self._mem_release("mirror")
-            self._nh_links = None
-            self._nh_mask = None
+            self._drop_nh_mask()
             self._delta_pending = None
         elif self._delta_pending is not None:
             # qualifying event: mirrors were patched in place during
@@ -593,6 +705,8 @@ class _AreaSolve:
         # KSP: (dest, k) -> traced edge-disjoint path set for src == me;
         # reset with the snapshot, so topology changes invalidate it for free
         self._ksp: Dict[Tuple[str, int], List[Path]] = {}
+        # source -> its _TpuSpfResult, one for all the reads of this solve
+        self._spf_results: Dict[str, _TpuSpfResult] = {}
         # APSP staleness guard (docs/Apsp.md): any event that poisons the
         # batch warm solve — cold start, patch overflow, structural
         # rebuild, overload change — also invalidates the resident
@@ -1166,27 +1280,53 @@ class _AreaSolve:
         self.full_solves += 1
         return d, None
 
+    def spf_result(self, source: str) -> _TpuSpfResult:
+        """The view from one source of the batch, the same object while
+        this solve stands."""
+        result = self._spf_results.get(source)
+        if result is None:
+            result = self._spf_results[source] = _TpuSpfResult(self, source)
+        return result
+
+    def nh_up_links(self) -> List[Link]:
+        """My ordered up-links into the batch: the rows of nh_mask."""
+        me = self.me
+        return [
+            link
+            for link in self.link_state.ordered_links_from_node(me)
+            if link.is_up() and link.other_node_name(me) in self.row_map
+        ]
+
     def _nh_link_arrays(self):
         """(names, batch rows [L], metrics [L], overloaded flags [L]) of
         my ordered up-links — the nh_mask triangle inputs, shared by the
         host mask build and the device delta extraction."""
-        ls = self.link_state
-        names: List[str] = []
-        rows: List[int] = []
-        ws: List[int] = []
-        ov: List[bool] = []
-        for link in ls.ordered_links_from_node(self.me):
-            if not link.is_up():
-                continue
-            n = link.other_node_name(self.me)
-            r = self.row_map.get(n)
-            if r is None:
-                continue
-            names.append(n)
-            rows.append(r)
-            ws.append(link.metric_from_node(self.me))
-            ov.append(ls.is_node_overloaded(n))
-        return names, rows, ws, ov
+        me, ls = self.me, self.link_state
+        links = self.nh_up_links()
+        names = [link.other_node_name(me) for link in links]
+        return (
+            names,
+            [self.row_map[n] for n in names],
+            [link.metric_from_node(me) for link in links],
+            [ls.is_node_overloaded(n) for n in names],
+        )
+
+    def _drop_nh_mask(self) -> None:
+        """The mask and what was read off it (rebuilt lazily)."""
+        self._nh_links = None
+        self._nh_mask = None
+        self._nh_table: Optional[_NextHopTable] = None
+
+    def next_hop_table(self) -> _NextHopTable:
+        """The next-hop table over the current mask; built again where a
+        link's next-hop address moved under it."""
+        table = self._nh_table
+        if (
+            table is None
+            or table.attr_version != self.link_state.link_attr_version
+        ):
+            table = self._nh_table = _NextHopTable(self)
+        return table
 
     def _finish_delta(self, col_changed, num_changed, d_dev, delta_ok) -> None:
         """Complete a qualifying warm solve's DeltaPath extraction: read the
@@ -1245,8 +1385,7 @@ class _AreaSolve:
                     mask_cols[i] &= cols_real == g.node_index[nm]
             self._nh_mask[:, cols_real] = mask_cols
         elif self._nh_mask is not None:
-            self._nh_mask = None  # up-link set moved: rebuild lazily
-            self._nh_links = None
+            self._drop_nh_mask()  # up-link set moved: rebuild lazily
         self._last_solve_delta = cols_real
 
     def take_route_delta(self) -> Optional[set]:
@@ -1572,6 +1711,19 @@ class TpuSpfSolver(SpfSolver):
         # overwrites its predecessor instead of leaking it; topology-version
         # tracking lives in _AreaSolve.refresh()
         self._solves: Dict[Tuple[str, str], Tuple[int, _AreaSolve]] = {}
+        # area name -> (LinkState, its version, what _area_solve answered
+        # for my node then): the reads of a route build look an area's
+        # solve up once, and a LinkState that moved is asked about again
+        self._resolved: Dict[
+            str, Tuple[LinkState, int, Optional[_AreaSolve]]
+        ] = {}
+        # routes since the last sync_counters whose next hops came from a
+        # solve's next-hop table / from the generic stack
+        self._table_routes = 0
+        self._generic_routes = 0
+        # bumped by 0: the counters exist from the start
+        self._bump("decision.route_build_table_routes", 0)
+        self._bump("decision.route_build_generic_routes", 0)
         self.device_solves = 0  # counter: batched device calls
         # device-memory observatory: the process-global ledger plus the
         # compile caches as an informational external source; headroom-
@@ -1672,6 +1824,24 @@ class TpuSpfSolver(SpfSolver):
         self.device_solves += solve.device_solves
         self._sync_spf_counters(solve)
         self._solves[key] = (id(link_state), solve)
+        return solve
+
+    def _my_solve(self, link_state: LinkState) -> Optional[_AreaSolve]:
+        """`_area_solve` for my node, asked once per LinkState version:
+        every read of a route build comes through here."""
+        memo = self._resolved.get(link_state.area)
+        if (
+            memo is not None
+            and memo[0] is link_state
+            and memo[1] == link_state.version
+        ):
+            return memo[2]
+        solve = self._area_solve(link_state, self.my_node_name)
+        self._resolved[link_state.area] = (
+            link_state,
+            link_state.version,
+            solve,
+        )
         return solve
 
     def _note_capacity_refusal(self, verdict: Dict) -> None:
@@ -1868,7 +2038,7 @@ class TpuSpfSolver(SpfSolver):
         changed: Set[str] = set()
         ok = True
         for link_state in area_link_states.values():
-            solve = self._area_solve(link_state, me)
+            solve = self._my_solve(link_state)
             if solve is None:
                 continue
             cols = solve.take_route_delta()
@@ -1890,11 +2060,17 @@ class TpuSpfSolver(SpfSolver):
         """One counter sync per area's resident solve: what the reads
         since the solve's own sync left behind (the lazy mirror fetch's
         bytes, device sync and d2h phase, KSP and APSP work, the ledger's
-        gauges). Ends every poll and every route build."""
+        gauges), and the build's routes by where their next hops came
+        from. Ends every poll and every route build."""
         for link_state in area_link_states.values():
             cached = self._solves.get((link_state.area, self.my_node_name))
             if cached is not None and cached[0] == id(link_state):
                 self._sync_spf_counters(cached[1])
+        self._bump("decision.route_build_table_routes", self._table_routes)
+        self._bump(
+            "decision.route_build_generic_routes", self._generic_routes
+        )
+        self._table_routes = self._generic_routes = 0
 
     def build_route_db(self, my_node_name, area_link_states, prefix_state):
         db = super().build_route_db(
@@ -1986,6 +2162,7 @@ class TpuSpfSolver(SpfSolver):
         for _, solve in self._solves.values():
             solve.close()
         self._solves.clear()
+        self._resolved.clear()
         self._ledger.fold_counters(self._ensure_counters())
 
     def audit_warm_state(self) -> List[dict]:
@@ -2023,9 +2200,9 @@ class TpuSpfSolver(SpfSolver):
     # -- SPF access seam -------------------------------------------------
 
     def _spf(self, link_state: LinkState, node: str):
-        solve = self._area_solve(link_state, self.my_node_name)
+        solve = self._my_solve(link_state)
         if solve is not None and node in solve.row_map:
-            return _TpuSpfResult(solve, node)
+            return solve.spf_result(node)
         # source outside the solved batch (not me / my neighbor): the
         # resident all-pairs matrix serves its whole row — LFA-style
         # qualification from an arbitrary perspective reads alt-neighbor
@@ -2043,7 +2220,7 @@ class TpuSpfSolver(SpfSolver):
     def _dist(self, link_state: LinkState, a: str, b: str) -> Optional[Metric]:
         if a == b:
             return 0
-        solve = self._area_solve(link_state, self.my_node_name)
+        solve = self._my_solve(link_state)
         if solve is not None:
             row = solve.row_map.get(a)
             col = solve.graph.node_index.get(b)
@@ -2061,10 +2238,63 @@ class TpuSpfSolver(SpfSolver):
                 return metric if metric < INF else None
         return link_state.get_metric_from_a_to_b(a, b)
 
+    def next_hops_toward(
+        self,
+        my_node_name: str,
+        dst_node_names: Set[str],
+        is_v4: bool,
+        per_destination: bool,
+        swap_label: Optional[int],
+        area_link_states: Dict[str, LinkState],
+        prefix_areas: Set[str],
+    ) -> Optional[Set[NextHop]]:
+        """From the area's next-hop table where the input allows: no LFA,
+        no per-destination action, and one area alone that holds my node,
+        among the prefix's. Anything else walks the generic stack."""
+        table = None
+        if (
+            not self.compute_lfa_paths
+            and not per_destination
+            and my_node_name == self.my_node_name
+        ):
+            table = self._next_hop_table(area_link_states, prefix_areas)
+        if table is not None:
+            next_hops = table.next_hops(dst_node_names, is_v4, swap_label)
+            if next_hops is not None:
+                self._table_routes += 1
+            return next_hops
+        next_hops = super().next_hops_toward(
+            my_node_name,
+            dst_node_names,
+            is_v4,
+            per_destination,
+            swap_label,
+            area_link_states,
+            prefix_areas,
+        )
+        if next_hops is not None:
+            self._generic_routes += 1
+        return next_hops
+
+    def _next_hop_table(
+        self, area_link_states: Dict[str, LinkState], prefix_areas: Set[str]
+    ) -> Optional[_NextHopTable]:
+        """The table of the one area that holds my node, where that area
+        is among the prefix's; None for any other input."""
+        mine = None
+        for area, link_state in area_link_states.items():
+            solve = self._my_solve(link_state)
+            if solve is None:
+                continue
+            if mine is not None or area not in prefix_areas:
+                return None
+            mine = solve
+        return mine.next_hop_table() if mine is not None else None
+
     def _kth_paths(
         self, link_state: LinkState, src: str, dest: str, k: int
     ) -> List[Path]:
-        solve = self._area_solve(link_state, self.my_node_name)
+        solve = self._my_solve(link_state)
         if solve is None or src != self.my_node_name:
             return link_state.get_kth_paths(src, dest, k)
         return solve.kth_paths(dest, k)
@@ -2072,6 +2302,6 @@ class TpuSpfSolver(SpfSolver):
     def _prefetch_kth_paths(
         self, link_state: LinkState, src: str, dests: List[str], k: int
     ) -> None:
-        solve = self._area_solve(link_state, self.my_node_name)
+        solve = self._my_solve(link_state)
         if solve is not None and src == self.my_node_name:
             solve.prefetch_ksp(dests, k)
