@@ -1874,6 +1874,10 @@ class TpuSpfSolver(SpfSolver):
             self._bump("decision.spf.incremental_solves", d_inc)
         if d_full:
             self._bump("decision.spf.full_solves", d_full)
+        # rows solved for, and the bucket-padded height of the resident
+        # [s_pad, n_pad] matrix that was computed for them
+        counters["decision.spf.rows_last"] = len(solve.sources)
+        counters["decision.spf.rows_padded_last"] = solve._d_dev.shape[0]
         if solve.rounds_last is not None:
             counters["decision.spf.rounds_last"] = solve.rounds_last
         if solve.invalidation_rounds_last is not None:
