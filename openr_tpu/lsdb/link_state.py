@@ -386,11 +386,16 @@ class LinkState:
         # memoization: (src, dest, k) -> [Path]
         self._kth_path_results: Dict[Tuple[str, str, int], List[Path]] = {}
         # graph changelog for incremental compiled-graph refresh: entries are
-        # ("link", Link) weight/up-down change, ("node", name) node-overload
-        # change, ("structure", None) link/node add/remove. Consumers remember
-        # their read position (graph_log_pos); at the cap the oldest half is
-        # dropped, so a consumer less than half a cap behind loses nothing
-        # and one that fell further behind rebuilds from scratch
+        # ("link", Link) weight/up-down change, ("link_added", Link) and
+        # ("link_removed", Link) a link that entered or left the LSDB while
+        # the node set stood (a consumer with slots for the link's key
+        # patches them; a returning Link is a new object of the same key),
+        # ("node", name) node-overload change, ("structure", None) wherever
+        # the node set may move: a node's first database, a deleted one,
+        # the bulk ingest. Consumers remember their read position
+        # (graph_log_pos); at the cap the oldest half is dropped, so a
+        # consumer less than half a cap behind loses nothing and one that
+        # fell further behind rebuilds from scratch
         self._graph_log: List[Tuple[str, object]] = []
         self._graph_log_base = 0
         # counters (fb303 equivalents)
@@ -490,7 +495,7 @@ class LinkState:
                 link.set_hold_up_ttl(hold_up_ttl)
                 change.topology_changed |= link.is_up()
                 self._add_link(link)
-                self._log_graph("structure")
+                self._log_graph("link_added", link)
                 i += 1
                 continue
             if j < len(old_links) and (
@@ -499,7 +504,7 @@ class LinkState:
                 link = old_links[j]
                 change.topology_changed |= link.is_up()
                 self._remove_link(link)
-                self._log_graph("structure")
+                self._log_graph("link_removed", link)
                 j += 1
                 continue
             # same link on both sides: diff attributes in place

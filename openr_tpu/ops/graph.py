@@ -84,6 +84,10 @@ class CompiledGraph:
     Down links are present in the arrays with INF weight (they never relax),
     so link flaps and metric changes are pure weight patches — the arrays
     keep their shape and identity and the jitted solver never recompiles.
+    A link that left the LSDB after the compile is a down link too: it
+    keeps its two slots at INF, and gets its metric back in them when a
+    link of the same key returns (refresh_graph). Slots of links that
+    never return stay INF until the next compile.
     """
 
     names: List[str]  # index -> node name (real nodes only)
@@ -97,8 +101,12 @@ class CompiledGraph:
     w: np.ndarray  # int32 [e_pad]; INF for down links and padding
     overloaded: np.ndarray  # bool [n_pad]
     # Link object -> its two directed-edge positions in the padded arrays
-    # (forward = n1->n2, reverse = n2->n1); lets callers mask individual
-    # links out of a solve (KSP link-ignore semantics, LinkState.cpp:760-789)
+    # (forward = n1->n2, reverse = n2->n1, of the Link that was compiled);
+    # lets callers mask individual links out of a solve (KSP link-ignore
+    # semantics, LinkState.cpp:760-789). Links compare by key, so a Link the
+    # LSDB made again finds the slots of the one it replaces, whichever of
+    # its ends is n1 now: read a slot's direction from src[p], not from its
+    # place in the pair
     link_edges: Dict[Link, Tuple[int, int]] = field(default_factory=dict)
     # snapshot markers for incremental refresh (refresh_graph)
     version: int = -1  # LinkState.version at compile time
@@ -112,6 +120,9 @@ class CompiledGraph:
     # parent_version. None/-2 for full builds.
     parent_version: int = -2
     changed_edges: Optional[np.ndarray] = None
+    # arrivals and withdrawals of links that the refresh which made this
+    # snapshot took as patches of slots it had (0 for full builds)
+    links_patched: int = 0
 
 
 # Degree-class merging: adjacent in-degrees merge while the extra padded
@@ -323,8 +334,9 @@ def compile_edges(
 
 
 def _recompile(link_state: LinkState, reason: str) -> CompiledGraph:
-    """refresh_graph's fall-back: O(E) of Python, and whoever holds state
-    keyed on the old snapshot (the resident solve, DeltaPath) starts cold."""
+    """refresh_graph's fall-back: O(E) of Python, new slots for every link,
+    and whoever holds state keyed on the old snapshot (the resident solve
+    and its device buffers, DeltaPath) starts cold."""
     log.info(
         "area %s: graph refresh falls back to a full compile (%s)",
         link_state.area,
@@ -336,14 +348,20 @@ def _recompile(link_state: LinkState, reason: str) -> CompiledGraph:
 def refresh_graph(graph: CompiledGraph, link_state: LinkState) -> CompiledGraph:
     """Bring a compiled snapshot up to date with its LinkState.
 
-    Replays the LinkState graph changelog since the snapshot: pure
-    weight/overload changes (link flap, metric change, drain) patch copies of
-    the w/overloaded arrays in place — same shapes, no recompilation and no
-    O(E) Python rebuild; structural changes (link/node add/remove), a
-    changelog whose entries since the snapshot were dropped, or an entry the
-    snapshot does not know fall back to a full compile_graph, which shares
-    no `link_edges` with the snapshot. This is the single-link-flap
-    incremental event path (BASELINE.md config 2)."""
+    Replays the LinkState graph changelog since the snapshot, in order,
+    into copies of the w/overloaded arrays — same shapes, same `src`, `dst`
+    and `link_edges`, no recompilation and no O(E) Python rebuild. A link
+    entry of any kind writes the two slots its key has: a metric or
+    overload change ("link") and an arrival ("link_added") leave the
+    link's metric there, INF while it is not up; a withdrawal
+    ("link_removed") leaves INF. A node's overload ("node") sets its flag.
+    Afterwards every slot of a link the LinkState holds reads what
+    compile_graph would write, and every other slot INF. What moves the
+    node set ("structure"), a changelog whose entries since the snapshot
+    were dropped, and a link or node the snapshot has no place for fall
+    back to a full compile_graph, which shares no `link_edges` with the
+    snapshot. This is the single-link-flap incremental event path
+    (BASELINE.md config 2)."""
     if graph.version == link_state.version:
         return graph
     changes = link_state.graph_changes_since(graph.log_pos)
@@ -356,28 +374,31 @@ def refresh_graph(graph: CompiledGraph, link_state: LinkState) -> CompiledGraph:
     sell = graph.sell
     wgs = [a.copy() for a in sell.wg] if sell is not None else None
     overloaded = graph.overloaded.copy()
+    names, src = graph.names, graph.src
     touched: List[int] = []
+    links_patched = 0
     for kind, obj in changes:
-        if kind == "link":
-            pos = graph.link_edges.get(obj)
-            if pos is None:  # changelog raced a structural entry we missed
-                return _recompile(link_state, "unknown edge")
-            up = obj.is_up()
-            for p, metric in (
-                (pos[0], obj.metric_from_node(obj.n1)),
-                (pos[1], obj.metric_from_node(obj.n2)),
-            ):
-                w[p] = metric if up else INF
-                touched.append(p)
-                if wgs is not None:
-                    wgs[sell.edge_bucket[p]][
-                        sell.edge_row[p], sell.edge_slot[p]
-                    ] = w[p]
-        else:  # "node"
+        if kind == "node":
             i = graph.node_index.get(obj)
             if i is None:
                 return _recompile(link_state, "unknown node")
             overloaded[i] = link_state.is_node_overloaded(obj)
+            continue
+        # "link", "link_added", "link_removed"
+        pos = graph.link_edges.get(obj)
+        if pos is None:  # a link that arrived after the compile
+            return _recompile(link_state, "unknown edge")
+        live = kind != "link_removed" and obj.is_up()
+        links_patched += kind != "link"
+        for p in pos:
+            # each slot by its own source node: a Link made again may have
+            # its ends the other way round from the one that was compiled
+            w[p] = obj.metric_from_node(names[src[p]]) if live else INF
+            touched.append(p)
+            if wgs is not None:
+                wgs[sell.edge_bucket[p]][
+                    sell.edge_row[p], sell.edge_slot[p]
+                ] = w[p]
 
     new_sell = None
     if sell is not None:
@@ -407,4 +428,5 @@ def refresh_graph(graph: CompiledGraph, link_state: LinkState) -> CompiledGraph:
         sell=new_sell,
         parent_version=graph.version,
         changed_edges=np.unique(np.asarray(touched, dtype=np.int64)),
+        links_patched=links_patched,
     )

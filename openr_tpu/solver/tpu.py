@@ -434,6 +434,11 @@ class _AreaSolve:
         # (decision.spf.graph_recompiles): each is a cold solve and a full
         # route build
         self.graph_recompiles = 0
+        # links that arrived in or left the LSDB and that a warm refresh
+        # took as patches of slots the snapshot had
+        # (decision.spf.graph_links_patched): without it, no recompiles
+        # cannot be told from no such event
+        self.graph_links_patched = 0
         # halo-exchange accounting (the 2-D tiled layout's cross-chip
         # traffic): ring-rotation count of the last solve and cumulative
         # frontier bytes moved between chips — the destination-sharded
@@ -462,6 +467,7 @@ class _AreaSolve:
         self._d2h_synced = 0
         self._device_syncs_synced = 0
         self._graph_recompiles_synced = 0
+        self._graph_links_patched_synced = 0
         self._delta_cols_synced = 0
         self._delta_bytes_synced = 0
         self._delta_extracts_synced = 0
@@ -620,6 +626,8 @@ class _AreaSolve:
                 # a patched snapshot shares its parent's link_edges
                 if self.graph.link_edges is not old.link_edges:
                     self.graph_recompiles += 1
+                elif self.graph is not old:
+                    self.graph_links_patched += self.graph.links_patched
             self._solve_phases(pc)
         finally:
             pc.stop()  # a fault mid-phase still ends its profiler span
@@ -1909,12 +1917,17 @@ class TpuSpfSolver(SpfSolver):
         if d_syncs:
             solve._device_syncs_synced = solve.device_syncs
             self._bump("decision.spf.device_syncs", d_syncs)
-        # bumped also by 0, so that the counter exists from the first sync
+        # bumped also by 0, so that the counters exist from the first sync
         self._bump(
             "decision.spf.graph_recompiles",
             solve.graph_recompiles - solve._graph_recompiles_synced,
         )
         solve._graph_recompiles_synced = solve.graph_recompiles
+        self._bump(
+            "decision.spf.graph_links_patched",
+            solve.graph_links_patched - solve._graph_links_patched_synced,
+        )
+        solve._graph_links_patched_synced = solve.graph_links_patched
         # DeltaPath extraction stats (docs/Monitoring.md): changed columns
         # and O(changes) copy-back bytes per warm event
         d_cols = solve.delta_columns - solve._delta_cols_synced

@@ -551,7 +551,10 @@ class TestDecisionDeltaPath:
     """End to end through Decision: a qualifying remote flap must be served
     by the delta route build and emit the same update the full path would."""
 
-    def test_remote_metric_flap_uses_delta_build(self):
+    @pytest.mark.parametrize("event", ["metric", "withdrawn"])
+    def test_remote_metric_flap_uses_delta_build(self, event):
+        """A remote metric bump, or a remote adjacency that leaves the
+        LSDB (a patch of the slots the compiled graph keeps for it)."""
         import asyncio
 
         from openr_tpu.decision import Decision, DecisionConfig
@@ -591,7 +594,7 @@ class TestDecisionDeltaPath:
             assert decision.counters.get(
                 "decision.route_build_delta_runs", 0
             ) == 0  # first build is full
-            # remote metric bump: c->d — c is not adjacent to me, so the
+            # remote event on c->d — c is not adjacent to me, so the
             # batch qualifies at the Decision layer too
             dbs["c"] = dataclasses.replace(
                 dbs["c"],
@@ -600,6 +603,7 @@ class TestDecisionDeltaPath:
                     if adj.other_node_name == "d"
                     else adj
                     for adj in dbs["c"].adjacencies
+                    if adj.other_node_name != "d" or event == "metric"
                 ],
             )
             pub2 = Publication(area="0")
@@ -609,10 +613,16 @@ class TestDecisionDeltaPath:
             kv_q.push(pub2)
             delta = await asyncio.wait_for(reader.get(), 10)
             assert decision.counters["decision.route_build_delta_runs"] == 1
-            routes = {e.prefix: e for e in delta.unicast_routes_to_update}
-            assert IpPrefix(PFXS[0]) in routes
-            entry = routes[IpPrefix(PFXS[0])]
-            assert {nh.metric for nh in entry.nexthops} == {8}
+            assert decision.counters["decision.spf.graph_recompiles"] == 0
+            if event == "metric":
+                routes = {e.prefix: e for e in delta.unicast_routes_to_update}
+                assert IpPrefix(PFXS[0]) in routes
+                entry = routes[IpPrefix(PFXS[0])]
+                assert {nh.metric for nh in entry.nexthops} == {8}
+                assert decision.counters["decision.spf.graph_links_patched"] == 0
+            else:  # e is cut off: its route goes, by the delta build
+                assert delta.unicast_routes_to_delete == [IpPrefix(PFXS[0])]
+                assert decision.counters["decision.spf.graph_links_patched"] == 1
             # the maintained route_db matches a from-scratch oracle build
             ls = LinkState("0")
             for db in dbs.values():
@@ -841,7 +851,8 @@ class TestDeltaUnderLfa:
 class TestChangelogPastItsCap:
     """The served path over more graph-changelog entries than the log
     holds: a reader that keeps up loses none, so no warm metric change
-    turns into a graph recompile, a cold solve and a full route build."""
+    turns into a graph recompile, a cold solve and a full route build,
+    and neither does a remote link that leaves the LSDB."""
 
     N = 6
     EVENTS = 1100  # x 4 entries: the log passes LinkState._GRAPH_LOG_CAP
@@ -853,8 +864,16 @@ class TestChangelogPastItsCap:
             set_metric(h.dbs, h.ls, link[0], link[1], metric)
             set_metric(h.dbs, h.ls, link[1], link[0], metric)
 
-    @pytest.mark.parametrize("structure_at", [None, 550])
-    def test_metric_swaps_stay_on_delta_path(self, structure_at):
+    @pytest.mark.parametrize(
+        "structure_at, new_link",
+        [(None, False), (550, False), (550, True)],
+        ids=["None", "550", "550-new-link"],
+    )
+    def test_metric_swaps_stay_on_delta_path(self, structure_at, new_link):
+        """At event 550 a remote link leaves the LSDB: the snapshot has its
+        slots, so that is a patch to INF, a warm solve and DeltaPath like
+        every other event. A link the snapshot never held (`new_link`) is
+        the one recompile, cold solve and full build of its run."""
         me = "g0_0"
         edges = grid_edges(self.N)
         h = DeltaHarness(
@@ -867,14 +886,17 @@ class TestChangelogPastItsCap:
                 if (i, j) != (0, 0)
             },
         )
-        gone = ("g3_3", "g3_4")  # the structure case removes this link
+        gone = ("g3_3", "g3_4")  # withdrawn at `structure_at`
+        diagonal = ("g3_3", "g4_4", 1)  # the link no snapshot has seen
         links = [
             (a, b) for a, b, _ in edges if me not in (a, b) and (a, b) != gone
         ]
         solve = h.solver._solves[("0", me)][1]
         link_edges = solve.graph.link_edges
         name = "decision.spf.graph_recompiles"
-        assert h.solver.counters[name] == 0  # there from the first sync
+        patched = "decision.spf.graph_links_patched"
+        # both there from the first sync
+        assert h.solver.counters[name] == h.solver.counters[patched] == 0
         raised = links[-1]
         self._swap(h, raised, raised)  # 2 of the 4 change nothing
         assert h.step() is True
@@ -883,8 +905,8 @@ class TestChangelogPastItsCap:
             nxt = links[(7 * k) % len(links)]
             self._swap(h, raised, nxt)
             raised = nxt
-            structural = k == structure_at
-            if structural:
+            recompiles = k == structure_at and new_link
+            if k == structure_at:
                 h.dbs[gone[0]] = dataclasses.replace(
                     h.dbs[gone[0]],
                     adjacencies=[
@@ -894,22 +916,38 @@ class TestChangelogPastItsCap:
                     ],
                 )
                 h.ls.update_adjacency_database(h.dbs[gone[0]])
+            if recompiles:
+                for node, db in build_adj_dbs([diagonal]).items():
+                    h.dbs[node] = dataclasses.replace(
+                        h.dbs[node],
+                        adjacencies=h.dbs[node].adjacencies + db.adjacencies,
+                    )
+                    h.ls.update_adjacency_database(h.dbs[node])
             builds = (h.builder.delta_builds, h.builder.full_builds)
             h.db, _, used = h.builder.build(me, h.als, h.ps, h.db)
-            assert used is not structural, k
+            assert used is not recompiles, k
             assert (h.builder.delta_builds, h.builder.full_builds) == (
                 builds[0] + used,
                 builds[1] + (not used),
             )
             # a patched graph keeps its parent's link_edges, a recompiled
             # one has its own
-            assert (solve.graph.link_edges is not link_edges) is structural, k
+            assert (solve.graph.link_edges is not link_edges) is recompiles, k
             link_edges = solve.graph.link_edges
+            if k == structure_at:  # the event itself, not only the last
+                assert_route_db_equal(
+                    SpfSolver(me).build_route_db(me, h.als, h.ps), h.db
+                )
         assert h.ls.graph_log_pos - log_pos >= 4 * self.EVENTS
         assert h.ls.graph_log_pos > LinkState._GRAPH_LOG_CAP
-        want = 0 if structure_at is None else 1
+        want = int(new_link)
         assert solve.graph_recompiles == want
         assert h.solver.counters[name] == want
+        # the withdrawal was absorbed, unless its refresh ended in the
+        # diagonal's recompile
+        want_patched = int(structure_at is not None and not new_link)
+        assert solve.graph_links_patched == want_patched
+        assert h.solver.counters[patched] == want_patched
         assert h.builder.full_builds == 1 + want  # the first build, always
         assert_route_db_equal(
             SpfSolver(me).build_route_db(me, h.als, h.ps), h.db

@@ -229,7 +229,9 @@ class TestBatchedSpf:
 class TestIncrementalRefresh:
     """refresh_graph must patch weight/overload arrays in place for
     non-structural events (metric change, drain) — same shapes, shared
-    src/dst identity — and fall back to a rebuild for structural ones."""
+    src/dst identity — and fall back to a rebuild for structural ones
+    (a link that leaves or returns to slots it has is not one:
+    tests/test_graph_link_patches.py)."""
 
     def test_metric_change_patches_in_place(self):
         from openr_tpu.ops.graph import refresh_graph
@@ -270,21 +272,19 @@ class TestIncrementalRefresh:
         all_pairs_distance_check_graph(ls, g2)
 
     def test_structural_change_rebuilds(self):
+        # a link the snapshot never held (both ends announce it only now)
+        # has no slots to patch: a full rebuild, with slots for it
         from openr_tpu.ops.graph import refresh_graph
-        from openr_tpu.types import AdjacencyDatabase
 
-        edges = [("a", "b", 1), ("b", "c", 1), ("a", "c", 5)]
+        edges = [("a", "b", 1), ("b", "c", 1)]
         ls = build_ls(edges)
         g1 = compile_graph(ls)
-        new_a = AdjacencyDatabase(
-            "a",
-            [x for x in build_adj_dbs(edges)["a"].adjacencies
-             if x.other_node_name != "b"],
-            area="0",
-        )
-        ls.update_adjacency_database(new_a)
+        dbs = build_adj_dbs(edges + [("a", "c", 5)])
+        ls.update_adjacency_database(dbs["a"])
+        ls.update_adjacency_database(dbs["c"])
         g2 = refresh_graph(g1, ls)
         assert g2.src is not g1.src  # full rebuild
+        assert g2.e == g1.e + 2 and g2.links_patched == 0
         all_pairs_distance_check_graph(ls, g2)
 
     @pytest.mark.parametrize(
@@ -296,8 +296,10 @@ class TestIncrementalRefresh:
         edges = [("a", "b", 1), ("b", "c", 1), ("a", "c", 5)]
         ls = build_ls(edges)
         g1 = compile_graph(ls)
-        if reason == "structure":
-            ls.update_adjacency_database(build_adj_dbs([("a", "c", 5)])["a"])
+        if reason == "structure":  # a node's first database
+            dbs = build_adj_dbs(edges + [("c", "d", 1)])
+            ls.update_adjacency_database(dbs["d"])
+            ls.update_adjacency_database(dbs["c"])
         else:  # a weight change, which alone would be patched in place
             ls.update_adjacency_database(
                 build_adj_dbs([("a", "b", 1), ("a", "c", 9)])["a"]
