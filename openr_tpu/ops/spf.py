@@ -1190,7 +1190,10 @@ def compile_cache_stats() -> dict:
     Each factory's lru_cache is keyed by (SlicedEll.shape_key(), mesh), so a
     miss is one new trace+XLA compile for a new bucket structure and a hit
     is an executable reused across LSDB events — the shape-bucketing design
-    working as intended. TpuSpfSolver surfaces these as the
+    working as intended. `_delta_extract`'s executables, one per power-of-two
+    bucket of changed columns, count among the misses: a deployment whose
+    events are of many sizes (a WAN: 8 buckets) meets a new one in steady
+    state, and pays for it there. TpuSpfSolver surfaces these as the
     decision.spf.compile_cache_{hits,misses} gauges (process-wide: the
     caches are module-level, shared by every solver instance)."""
     hits = misses = entries = 0
@@ -1210,7 +1213,16 @@ def compile_cache_stats() -> dict:
         hits += info.hits
         misses += info.misses
         entries += info.currsize
-    return {"hits": hits, "misses": misses, "entries": entries}
+    # the DeltaPath extraction is one jitted function with an executable
+    # per (shapes, cap bucket): the first event of each size paid a trace
+    # and a compile for it, a miss like the factories'. jit keeps no hit
+    # count, so its reuse is not among the hits
+    extract = _delta_extract._cache_size()
+    return {
+        "hits": hits,
+        "misses": misses + extract,
+        "entries": entries + extract,
+    }
 
 
 def compile_cache_memory() -> dict:

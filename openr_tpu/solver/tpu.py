@@ -1886,6 +1886,15 @@ class TpuSpfSolver(SpfSolver):
         # [s_pad, n_pad] matrix that was computed for them
         counters["decision.spf.rows_last"] = len(solve.sources)
         counters["decision.spf.rows_padded_last"] = solve._d_dev.shape[0]
+        # the solved graph's padded width and its sliced-ELL layout: degree
+        # classes after merging (0 on the edge-list form) and the padded
+        # slots a sweep reads, the directed edges and the layout's waste
+        sell = solve.graph.sell
+        counters["decision.spf.nodes_padded_last"] = solve.graph.n_pad
+        counters["decision.spf.sell_classes_last"] = len(sell.nbr) if sell else 0
+        counters["decision.spf.sell_slots_last"] = (
+            sum(a.size for a in sell.nbr) if sell else 0
+        )
         if solve.rounds_last is not None:
             counters["decision.spf.rounds_last"] = solve.rounds_last
         if solve.invalidation_rounds_last is not None:

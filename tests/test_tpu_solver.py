@@ -1060,3 +1060,104 @@ class TestDeviceBufferProvenance:
         want = SpfSolver("a").build_route_db("a", {"0": ls}, ps)
         assert got == want
         assert area._dev["w_ver"] == area.graph.version
+
+
+class TestSkewedWan:
+    """ISSUE 35: `wan_edges(2048, 4, 3)` from its node of highest degree,
+    the shape of the benchmark's `wan65536` in small: in-degrees 2-11 that
+    the sliced-ELL layout merges into more than one class with padded
+    slots, metrics 1-100, a deep shortest-path tree. Through a full build
+    and 32 warm DeltaPath events the routes are the CPU oracle's."""
+
+    def test_routes_equal_the_oracles_after_32_raises_and_restores(self):
+        import collections
+        import dataclasses
+
+        from openr_tpu.solver import DeltaRouteBuilder
+
+        edges = wan_edges(2048, degree=4, seed=3)
+        degree = collections.Counter(n for a, b, _ in edges for n in (a, b))
+        me = min(degree, key=lambda n: (-degree[n], int(n[1:])))
+        assert (me, degree[me]) == ("w1986", 11)
+        dbs = build_adj_dbs(edges)
+        ls = LinkState("0")
+        for db in dbs.values():
+            ls.update_adjacency_database(db)
+        als = {"0": ls}
+        ps = make_prefix_state({
+            n: [f"10.{i // 256}.{i % 256}.0/24"] for i, n in enumerate(sorted(dbs))
+        })
+
+        def set_metric(a, b, metric):  # both directions, as one event
+            for x, y in ((a, b), (b, a)):
+                dbs[x] = dataclasses.replace(dbs[x], adjacencies=[
+                    dataclasses.replace(adj, metric=metric)
+                    if adj.other_node_name == y else adj
+                    for adj in dbs[x].adjacencies
+                ])
+                ls.update_adjacency_database(dbs[x])
+
+        tpu = TpuSpfSolver(me)
+        builder = DeltaRouteBuilder(tpu)
+        db, _, used = builder.build(me, als, ps, None, force_full=True)
+        assert not used
+        assert_route_db_equal(SpfSolver(me).build_route_db(me, als, ps), db)
+        assert len(db.unicast_entries) == 2047
+
+        # the layout: degrees merged under the waste budget into several
+        # classes, some slots of which are padding; the gauges read it
+        graph = tpu._solves[("0", me)][1].graph
+        sell = graph.sell
+        slots = sum(a.size for a in sell.nbr)
+        assert 1 < len(sell.nbr) < len(set(degree.values()))
+        assert graph.e == 2 * len(edges) == 8192 < slots
+        assert graph.n_pad == 2048
+        gauges = {
+            "decision.spf.nodes_padded_last": 2048,
+            "decision.spf.sell_classes_last": len(sell.nbr),
+            "decision.spf.sell_slots_last": slots,
+            "decision.spf.rows_last": 12,
+            "decision.spf.rows_padded_last": 16,
+        }
+        assert {k: tpu.counters[k] for k in gauges} == gauges
+
+        # links of the vantage's shortest-path tree, none its own
+        dist = {n: r.metric for n, r in ls.get_spf_result(me).items()}
+        tree = sorted(
+            (a, b, m) for a, b, m in edges
+            if me not in (a, b) and abs(dist[a] - dist[b]) == m
+        )
+        from openr_tpu.ops.spf import _delta_extract, compile_cache_stats
+
+        misses0 = compile_cache_stats()["misses"]
+        extracts0 = _delta_extract._cache_size()
+        rng = random.Random(2**31 + 35)
+        raised = None
+        moved = 0
+        for k in range(32):
+            if raised is not None:
+                set_metric(*raised)  # the restore of the raise before it
+                raised = None
+            else:
+                a, b, m = tree[rng.randrange(len(tree))]
+                raised = (a, b, m)
+                set_metric(a, b, m + rng.randint(2, 17))
+            db, update, used = builder.build(me, als, ps, db)
+            assert used, k  # a metric change off the vantage's links stays warm
+            moved += len(update.unicast_routes_to_update)
+            assert_route_db_equal(SpfSolver(me).build_route_db(me, als, ps), db)
+        assert moved >= 32
+        assert tpu.counters["decision.spf.incremental_solves"] == 32
+        assert tpu.counters["decision.spf.full_solves"] == 1
+        assert tpu.counters["decision.spf.delta_columns"] > 0
+        assert {k: tpu.counters[k] for k in gauges} == gauges
+        # events of several sizes: each power-of-two bucket of changed
+        # columns is an executable of the extraction, counted as a miss
+        extracts = _delta_extract._cache_size() - extracts0
+        assert extracts >= 2
+        assert compile_cache_stats()["misses"] - misses0 >= extracts
+        assert (
+            tpu.counters["decision.spf.compile_cache_misses"]
+            >= compile_cache_stats()["misses"] - misses0
+        )
+        assert tpu.counters.get("decision.route_build_generic_routes", 0) == 0
