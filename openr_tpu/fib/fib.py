@@ -35,7 +35,7 @@ from typing import Dict, List, Optional, Set
 from openr_tpu.messaging import QueueClosedError, RQueue
 from openr_tpu.monitor.spans import stage
 from openr_tpu.platform import FIB_CLIENT_OPENR, FibService
-from openr_tpu.solver import DecisionRouteUpdate
+from openr_tpu.solver import DecisionRouteUpdate, RibMplsEntry
 from openr_tpu.types import (
     InterfaceDatabase,
     IpPrefix,
@@ -167,7 +167,11 @@ class _RouteState:
     """Fib.h:183-207 RouteState + the warm-boot stale sets."""
 
     unicast_routes: Dict[IpPrefix, UnicastRoute] = field(default_factory=dict)
-    mpls_routes: Dict[int, MplsRoute] = field(default_factory=dict)
+    # every label Decision gave a route for, in the order they came. A
+    # label in `_mpls_unread` has a value here that is out of date (None
+    # where it never had one) until `mpls_routes` is read
+    _mpls_routes: Dict[int, Optional[MplsRoute]] = field(default_factory=dict)
+    _mpls_unread: Dict[int, RibMplsEntry] = field(default_factory=dict)
     has_routes_from_decision: bool = False
     dirty_prefixes: Set[IpPrefix] = field(default_factory=set)
     dirty_labels: Set[int] = field(default_factory=set)
@@ -180,6 +184,30 @@ class _RouteState:
 
     def has_stale(self) -> bool:
         return bool(self.stale_prefixes or self.stale_labels)
+
+    def set_mpls_entry(self, entry: RibMplsEntry) -> None:
+        """Decision's label route, kept as it came: sorting its next hops
+        into an MplsRoute waits for a reader. With segment routing off an
+        event has none, and a table entry (routes.LabelNextHops) never
+        makes its next hops."""
+        self._mpls_routes.setdefault(entry.label, None)
+        self._mpls_unread[entry.label] = entry
+
+    def pop_mpls_route(self, label: int) -> None:
+        self._mpls_routes.pop(label, None)
+        self._mpls_unread.pop(label, None)
+
+    @property
+    def mpls_routes(self) -> Dict[int, MplsRoute]:
+        """The label routes as the agent would get them."""
+        if self._mpls_unread:
+            for label, entry in self._mpls_unread.items():
+                self._mpls_routes[label] = entry.to_mpls_route()
+            self._mpls_unread.clear()
+        return self._mpls_routes
+
+    def num_mpls_routes(self) -> int:
+        return len(self._mpls_routes)
 
 
 @owned_by("fib-loop")
@@ -482,17 +510,22 @@ class Fib(CountersMixin, HistogramsMixin):
             self.route_state.unicast_routes[route.dest] = route
             self.route_state.dirty_prefixes.discard(route.dest)
             unicast_to_update.append(route)
-        mpls_to_update: List[MplsRoute] = []
         for mpls_entry in delta.mpls_routes_to_update:
-            route = mpls_entry.to_mpls_route()
-            self.route_state.mpls_routes[route.top_label] = route
-            self.route_state.dirty_labels.discard(route.top_label)
-            mpls_to_update.append(route)
+            self.route_state.set_mpls_entry(mpls_entry)
+            self.route_state.dirty_labels.discard(mpls_entry.label)
+        mpls_to_update: List[MplsRoute] = []
+        if self.config.enable_segment_routing:
+            # the agent reads them: nobody else does in an event
+            mpls_routes = self.route_state.mpls_routes
+            mpls_to_update = [
+                mpls_routes[mpls_entry.label]
+                for mpls_entry in delta.mpls_routes_to_update
+            ]
         for dest in delta.unicast_routes_to_delete:
             self.route_state.unicast_routes.pop(dest, None)
             self.route_state.dirty_prefixes.discard(dest)
         for label in delta.mpls_routes_to_delete:
-            self.route_state.mpls_routes.pop(label, None)
+            self.route_state.pop_mpls_route(label)
             self.route_state.dirty_labels.discard(label)
 
         self._bump("fib.process_route_db")
@@ -683,12 +716,16 @@ class Fib(CountersMixin, HistogramsMixin):
             )
             for r in self.route_state.unicast_routes.values()
         ]
-        mpls = [
-            MplsRoute(
-                r.top_label, tuple(get_best_nexthops_mpls(list(r.nexthops)))
-            )
-            for r in self.route_state.mpls_routes.values()
-        ]
+        mpls: List[MplsRoute] = []
+        if self.config.enable_segment_routing:
+            # off, nothing is pushed, and no label route is read for it
+            mpls = [
+                MplsRoute(
+                    r.top_label,
+                    tuple(get_best_nexthops_mpls(list(r.nexthops))),
+                )
+                for r in self.route_state.mpls_routes.values()
+            ]
         if self.config.dryrun:
             self._note_sync_complete()
             return True
@@ -854,7 +891,7 @@ class Fib(CountersMixin, HistogramsMixin):
         counters["fib.num_unicast_routes"] = len(
             self.route_state.unicast_routes
         )
-        counters["fib.num_mpls_routes"] = len(self.route_state.mpls_routes)
+        counters["fib.num_mpls_routes"] = self.route_state.num_mpls_routes()
         counters["fib.num_routes"] = (
             counters["fib.num_unicast_routes"]
             + counters["fib.num_mpls_routes"]

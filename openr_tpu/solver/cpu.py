@@ -52,6 +52,7 @@ BACKEND_COUNTER_PREFIXES = (
     "decision.mem.",
     "decision.route_build_table_routes",
     "decision.route_build_generic_routes",
+    "decision.route_build_label_sets_made",
 )
 
 
@@ -362,7 +363,10 @@ class SpfSolver(CountersMixin, HistogramsMixin):
         """One node's MPLS node-label route (POP_AND_LOOKUP for my own
         label, SWAP/PHP nexthops toward everyone else's), or None when the
         node is unreachable. Collision arbitration stays with the caller.
-        Shared by build_route_db and the DeltaPath partial rebuild."""
+        Shared by build_route_db and the DeltaPath partial rebuild. Where
+        the seam answers with what determines the next hops (the TPU
+        backend's table: routes.LabelNextHops), the entry makes them when
+        they are read."""
         top_label = adj_db.node_label
         if adj_db.this_node_name == my_node_name:
             # our own label: POP_AND_LOOKUP

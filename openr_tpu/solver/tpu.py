@@ -29,7 +29,7 @@ order).
 from __future__ import annotations
 
 import time
-from typing import Dict, FrozenSet, List, Optional, Set, Tuple
+from typing import Dict, FrozenSet, List, Optional, Set, Tuple, Union
 
 import numpy as np
 
@@ -55,8 +55,9 @@ from openr_tpu.solver.flight_recorder import (
     SolveTrace,
     phase_stage,
 )
+from openr_tpu.solver.routes import LabelNextHops
 from openr_tpu.testing.faults import fault_point
-from openr_tpu.types import MplsAction, MplsActionCode, NextHop
+from openr_tpu.types import NextHop
 
 
 class DeviceCapacityError(RuntimeError):
@@ -231,9 +232,6 @@ class _ApspSpfResult:
         return nhs
 
 
-_PHP = MplsAction(MplsActionCode.PHP)
-
-
 class _NextHopTable:
     """Every destination's next hops, read off one resident solve.
 
@@ -263,7 +261,8 @@ class _NextHopTable:
             for link in solve.nh_up_links()
         ]
         assert len(self._links) == len(self._mask)
-        self._groups: Dict[bytes, Tuple[tuple, ...]] = {}
+        # what a column holds -> (its links, their neighbours' names)
+        self._groups: Dict[bytes, Tuple[Tuple[tuple, ...], FrozenSet[str]]] = {}
         # (group, metric, is_v4) -> the unicast next hops of every
         # destination behind that group at that distance
         self.unicast_sets: Dict[
@@ -275,9 +274,14 @@ class _NextHopTable:
         dst_node_names: Set[str],
         is_v4: bool,
         swap_label: Optional[int],
-    ) -> Optional[Set[NextHop]]:
+        label_sets_made: List[int],
+    ) -> Union[Set[NextHop], LabelNextHops, None]:
         """What `SpfSolver.next_hops_toward` gives without LFA and per-
-        destination actions, or None where no destination is reachable."""
+        destination actions, or None where no destination is reachable. A
+        label's next hops are its own by construction (its SWAP carries
+        it), and with segment routing off nobody reads them: they are
+        given as what determines them, and made where they are read
+        (counted in `label_sets_made`)."""
         solve = self._solve
         node_index = solve.graph.node_index
         from_me = solve.d[0]
@@ -307,37 +311,40 @@ class _NextHopTable:
         group = self._groups.get(group_key)
         if group is None:
             member = np.frombuffer(group_key, dtype=np.bool_)
-            group = self._groups[group_key] = tuple(
-                self._links[i] for i in np.flatnonzero(member)
+            links = tuple(self._links[i] for i in np.flatnonzero(member))
+            group = self._groups[group_key] = (
+                links, frozenset(link[0] for link in links)
             )
-        if not group:
+        links, neighbors = group
+        if not links:
             return None  # toward myself
-        # a label's next hops are its own by construction (its SWAP
-        # carries it): what they share is the group's links and one action
-        swap = (
-            None
-            if swap_label is None
-            else MplsAction(MplsActionCode.SWAP, swap_label=swap_label)
-        )
-        next_hops = (
-            NextHop(
-                v4 if is_v4 else v6,
-                iface,
+        if swap_label is not None:
+            return LabelNextHops(
+                links,
                 metric,
-                swap and (_PHP if neighbor in dst_node_names else swap),
-                False,
-                area,
-                0,
-                neighbor,
+                is_v4,
+                swap_label,
+                # PHP over the destination's own link; elsewhere the one
+                # empty frozenset, not an object per route
+                neighbors & dst_node_names or frozenset(),
+                label_sets_made,
             )
-            for neighbor, v4, v6, iface, area in group
-        )
-        if swap is not None:
-            return set(next_hops)
         key = (group_key, metric, is_v4)
         shared = self.unicast_sets.get(key)
         if shared is None:
-            shared = self.unicast_sets[key] = frozenset(next_hops)
+            shared = self.unicast_sets[key] = frozenset(
+                NextHop(
+                    v4 if is_v4 else v6,
+                    iface,
+                    metric,
+                    None,
+                    False,
+                    area,
+                    0,
+                    neighbor,
+                )
+                for neighbor, v4, v6, iface, area in links
+            )
         # a set of its own for every route: RibPolicy rewrites an entry's
         # nexthops, and no sibling's may change with it
         return set(shared)
@@ -1729,9 +1736,13 @@ class TpuSpfSolver(SpfSolver):
         # solve's next-hop table / from the generic stack
         self._table_routes = 0
         self._generic_routes = 0
+        # next-hop sets of the table's label routes that a reader made
+        # (routes.LabelNextHops.make): every such route carries this tally
+        self._label_sets_made = [0]
         # bumped by 0: the counters exist from the start
         self._bump("decision.route_build_table_routes", 0)
         self._bump("decision.route_build_generic_routes", 0)
+        self._bump("decision.route_build_label_sets_made", 0)
         self.device_solves = 0  # counter: batched device calls
         # device-memory observatory: the process-global ledger plus the
         # compile caches as an informational external source; headroom-
@@ -2086,8 +2097,9 @@ class TpuSpfSolver(SpfSolver):
         """One counter sync per area's resident solve: what the reads
         since the solve's own sync left behind (the lazy mirror fetch's
         bytes, device sync and d2h phase, KSP and APSP work, the ledger's
-        gauges), and the build's routes by where their next hops came
-        from. Ends every poll and every route build."""
+        gauges), the build's routes by where their next hops came from,
+        and the label routes' next-hop sets that were read since. Ends
+        every poll and every route build."""
         for link_state in area_link_states.values():
             cached = self._solves.get((link_state.area, self.my_node_name))
             if cached is not None and cached[0] == id(link_state):
@@ -2097,6 +2109,10 @@ class TpuSpfSolver(SpfSolver):
             "decision.route_build_generic_routes", self._generic_routes
         )
         self._table_routes = self._generic_routes = 0
+        # a reader downstream of the build (Fib, ctrl) shows at the next sync
+        self.counters["decision.route_build_label_sets_made"] = (
+            self._label_sets_made[0]
+        )
 
     def build_route_db(self, my_node_name, area_link_states, prefix_state):
         db = super().build_route_db(
@@ -2273,7 +2289,7 @@ class TpuSpfSolver(SpfSolver):
         swap_label: Optional[int],
         area_link_states: Dict[str, LinkState],
         prefix_areas: Set[str],
-    ) -> Optional[Set[NextHop]]:
+    ) -> Union[Set[NextHop], LabelNextHops, None]:
         """From the area's next-hop table where the input allows: no LFA,
         no per-destination action, and one area alone that holds my node,
         among the prefix's. Anything else walks the generic stack."""
@@ -2285,7 +2301,9 @@ class TpuSpfSolver(SpfSolver):
         ):
             table = self._next_hop_table(area_link_states, prefix_areas)
         if table is not None:
-            next_hops = table.next_hops(dst_node_names, is_v4, swap_label)
+            next_hops = table.next_hops(
+                dst_node_names, is_v4, swap_label, self._label_sets_made
+            )
             if next_hops is not None:
                 self._table_routes += 1
             return next_hops

@@ -313,9 +313,13 @@ class TestIncrementalRefresh:
             ls._log_graph("node", "z")
         with caplog.at_level("INFO", logger="openr_tpu.ops.graph"):
             g2 = refresh_graph(g1, ls)
-        assert [r.getMessage() for r in caplog.records] == [
-            f"area 0: graph refresh falls back to a full compile ({reason})"
-        ]
+        # the graph's own: a collection inside the block may log a task that
+        # an earlier test of this worker left pending (asyncio's logger)
+        assert [
+            r.getMessage()
+            for r in caplog.records
+            if r.name == "openr_tpu.ops.graph"
+        ] == [f"area 0: graph refresh falls back to a full compile ({reason})"]
         assert g2.src is not g1.src and g2.link_edges is not g1.link_edges
         assert g2.version == ls.version and g2.log_pos == ls.graph_log_pos
         all_pairs_distance_check_graph(ls, g2)
