@@ -949,6 +949,7 @@ def _perf_report(client: BlockingCtrlClient, args) -> None:
     every named node (--hosts host:port,... — or just the connected one)
     and render the aggregate (monitor/report.py)."""
     from openr_tpu.monitor.report import aggregate_convergence_reports
+    from openr_tpu.monitor.spans import account_line
 
     reports = [client.call("getConvergenceReport")]
     for endpoint in [h for h in (args.hosts or "").split(",") if h]:
@@ -990,6 +991,10 @@ def _perf_report(client: BlockingCtrlClient, args) -> None:
             f"slowest hop: {slowest['stage']} on {slowest['node']} "
             f"({ms(slowest['ms'])}ms)"
         )
+    for sample in agg.get("slowest") or []:
+        # the slowest events the nodes' rollup windows kept, each with
+        # the account its Fib closed (monitor/spans.py account_line)
+        print(f"slow event on {sample.get('node_name', '?')}: {account_line(sample)}")
     flood = agg["flood"]
     print(
         f"flood: {flood['received']} received, "

@@ -23,7 +23,7 @@ from __future__ import annotations
 import asyncio
 import time
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Set
+from typing import Dict, List, Optional, Set, Tuple
 
 from openr_tpu.lsdb import LinkState, PrefixState
 from openr_tpu.messaging import QueueClosedError, RQueue, ReplicateQueue
@@ -801,6 +801,7 @@ class Decision(CountersMixin, HistogramsMixin):
             # oldest-event recv -> debounce fire, on the monotonic clock
             self._observe("decision.debounce_ms", span.mark("decision.debounce"))
 
+        solver_counts0 = self._solver_counts()
         t0 = time.perf_counter()
         try:
             new_db, delta, used_delta = self._delta_builder.build(
@@ -835,10 +836,28 @@ class Decision(CountersMixin, HistogramsMixin):
             )
             return
         build_ms = (time.perf_counter() - t0) * 1e3
+        if span is not None:
+            # what the marks do not say of this build, for the event's
+            # account (Fib._finish_span): the build has synced the counters
+            misses, syncs = self._solver_counts()
+            span.notes = {
+                "full_build": int(not used_delta),
+                "compile_misses": misses - solver_counts0[0],
+                "device_syncs": syncs - solver_counts0[1],
+            }
         with stage("decision.emit", self.histograms, build):
             self._emit(
                 new_db, delta, used_delta, build_ms, span, perf_events
             )
+
+    def _solver_counts(self) -> Tuple[int, int]:
+        """(executables compiled, blocking device reads) so far, as the
+        solver's last counter sync left them; 0 on the CPU backend."""
+        counters = self.solver.counters
+        return (
+            counters.get("decision.spf.compile_cache_misses", 0),
+            counters.get("decision.spf.device_syncs", 0),
+        )
 
     def _emit(
         self, new_db, delta, used_delta, build_ms, span, perf_events

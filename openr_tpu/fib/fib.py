@@ -28,12 +28,20 @@ from __future__ import annotations
 
 import asyncio
 import logging
+import statistics
 import time
+from collections import deque
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Set
+from typing import Deque, Dict, List, Optional, Set
 
 from openr_tpu.messaging import QueueClosedError, RQueue
-from openr_tpu.monitor.spans import stage
+from openr_tpu.monitor.spans import (
+    ACCOUNT_STAGE_PREFIX,
+    GC_WATCH,
+    account_line,
+    stage,
+    take_build_stages,
+)
 from openr_tpu.platform import FIB_CLIENT_OPENR, FibService
 from openr_tpu.solver import DecisionRouteUpdate, RibMplsEntry
 from openr_tpu.types import (
@@ -51,6 +59,12 @@ from openr_tpu.utils.counters import CountersMixin, HistogramsMixin
 from openr_tpu.utils.ownership import owned_by
 
 log = logging.getLogger(__name__)
+
+# a finished span is slow where its total is over SLOW_FACTOR x the median
+# total of the SLOW_WINDOW spans before it, once SLOW_MIN have finished
+SLOW_FACTOR = 3.0
+SLOW_WINDOW = 32
+SLOW_MIN = 8
 
 # Constants.h kPerfBufferSize / kConvergenceMaxDuration
 PERF_BUFFER_SIZE = 10
@@ -268,7 +282,12 @@ class Fib(CountersMixin, HistogramsMixin):
         self._stale_deadline_handle: Optional[asyncio.TimerHandle] = None
         self._restart_anchor_ts: Optional[float] = None
         self._forensics = None  # lazy FlightRecorder (PR 13 dump path)
-        self.counters: Dict[str, int] = {}
+        # total_ms of the last finished spans: what "slow" is measured by
+        self._recent_totals_ms: Deque[float] = deque(maxlen=SLOW_WINDOW)
+        self.counters: Dict[str, int] = {
+            "convergence.slow_events": 0,
+            "convergence.slow_events_unexplained": 0,
+        }
         self.histograms: Dict = {}
 
     def loop(self) -> asyncio.AbstractEventLoop:
@@ -500,33 +519,38 @@ class Fib(CountersMixin, HistogramsMixin):
             perf_events.add(self.config.my_node_name, "FIB_ROUTE_DB_RECVD")
         span = getattr(delta, "span", None)
         if span is not None:
-            span.mark("fib.recv")
+            # Decision's `decision.route_build` mark -> taken up here: the
+            # queue hop (with what `decision.emit` does after its mark)
+            self._observe("fib.queue_wait_ms", span.mark("fib.recv"))
 
-        unicast_to_update: List[UnicastRoute] = []
-        for entry in delta.unicast_routes_to_update:
-            if entry.do_not_install:
-                continue
-            route = entry.to_unicast_route()
-            self.route_state.unicast_routes[route.dest] = route
-            self.route_state.dirty_prefixes.discard(route.dest)
-            unicast_to_update.append(route)
-        for mpls_entry in delta.mpls_routes_to_update:
-            self.route_state.set_mpls_entry(mpls_entry)
-            self.route_state.dirty_labels.discard(mpls_entry.label)
-        mpls_to_update: List[MplsRoute] = []
-        if self.config.enable_segment_routing:
-            # the agent reads them: nobody else does in an event
-            mpls_routes = self.route_state.mpls_routes
-            mpls_to_update = [
-                mpls_routes[mpls_entry.label]
-                for mpls_entry in delta.mpls_routes_to_update
-            ]
-        for dest in delta.unicast_routes_to_delete:
-            self.route_state.unicast_routes.pop(dest, None)
-            self.route_state.dirty_prefixes.discard(dest)
-        for label in delta.mpls_routes_to_delete:
-            self.route_state.pop_mpls_route(label)
-            self.route_state.dirty_labels.discard(label)
+        # the update into Fib's own tables, before the first call to the
+        # agent: tiles with fib.program, under the event's build
+        with stage("fib.apply", self.histograms, getattr(span, "build", None)):
+            unicast_to_update: List[UnicastRoute] = []
+            for entry in delta.unicast_routes_to_update:
+                if entry.do_not_install:
+                    continue
+                route = entry.to_unicast_route()
+                self.route_state.unicast_routes[route.dest] = route
+                self.route_state.dirty_prefixes.discard(route.dest)
+                unicast_to_update.append(route)
+            for mpls_entry in delta.mpls_routes_to_update:
+                self.route_state.set_mpls_entry(mpls_entry)
+                self.route_state.dirty_labels.discard(mpls_entry.label)
+            mpls_to_update: List[MplsRoute] = []
+            if self.config.enable_segment_routing:
+                # the agent reads them: nobody else does in an event
+                mpls_routes = self.route_state.mpls_routes
+                mpls_to_update = [
+                    mpls_routes[mpls_entry.label]
+                    for mpls_entry in delta.mpls_routes_to_update
+                ]
+            for dest in delta.unicast_routes_to_delete:
+                self.route_state.unicast_routes.pop(dest, None)
+                self.route_state.dirty_prefixes.discard(dest)
+            for label in delta.mpls_routes_to_delete:
+                self.route_state.pop_mpls_route(label)
+                self.route_state.dirty_labels.discard(label)
 
         self._bump("fib.process_route_db")
         await self._update_routes(
@@ -912,15 +936,63 @@ class Fib(CountersMixin, HistogramsMixin):
         and the finished stage trace goes out as one CONVERGENCE_TRACE
         LogSample through the monitor queue. All math runs on the
         monotonic clock (Span/perf_counter) — wall-clock steps never skew
-        these, unlike the PerfEvents-derived fib.convergence_time_ms."""
+        these, unlike the PerfEvents-derived fib.convergence_time_ms.
+
+        The sample carries the event's account beside the marks (build,
+        `stage.<name>_ms`, `unstaged_ms`, `gc_full_ms`, Decision's notes
+        on the build, `slow`: docs/Monitoring.md "The event's account").
+        Slow is a total over SLOW_FACTOR x the median of the SLOW_WINDOW
+        spans before it, once SLOW_MIN have finished; a slow span with no
+        full collection inside and no compile in its build is unexplained,
+        counted, and logged with its account."""
         self._observe("fib.program_ms", (time.perf_counter() - t0) * 1e3)
         if span is None:
             return
         span.mark("fib.program")
-        self._observe("convergence.e2e_ms", span.elapsed_ms())
+        end = span.marks[-1][1]
+        total_ms = (end - span.t0) * 1e3
+        self._observe("convergence.e2e_ms", total_ms)
         self._bump("fib.convergence_spans")
+        # the event's account, closed where the event ends: the stages that
+        # ran under its build, what no stage and no queue hop owns, and the
+        # full collections that started inside it
+        stages = (
+            take_build_stages(span.build, end) if span.build is not None else []
+        )
+        unstaged_ms = span.unstaged_ms(stages)
+        self._observe("convergence.unstaged_ms", unstaged_ms)
+        gc_full_ms = GC_WATCH.full_pause_ms_between(span.t0, end)
+        recent = self._recent_totals_ms
+        slow = (
+            len(recent) >= SLOW_MIN
+            and total_ms > SLOW_FACTOR * statistics.median(recent)
+        )
+        recent.append(total_ms)
+        unexplained = (
+            slow and not gc_full_ms and not span.notes.get("compile_misses")
+        )
+        if slow:
+            self._bump("convergence.slow_events")
+        if unexplained:
+            self._bump("convergence.slow_events_unexplained")
+        if self._log_sample_fn is None and not unexplained:
+            return
+        sample = span.to_log_sample()
+        sample.add_double("gc_full_ms", gc_full_ms)
+        sample.add_double("unstaged_ms", unstaged_ms)
+        sample.add_int("slow", slow)
+        by_stage: Dict[str, float] = {}
+        for name, lo, hi in stages:
+            by_stage[name] = by_stage.get(name, 0.0) + (hi - lo) * 1e3
+        for name, ms in by_stage.items():
+            sample.add_double(f"{ACCOUNT_STAGE_PREFIX}{name}_ms", ms)
+        if unexplained:
+            log.warning(
+                "slow event with no full collection and no compile in it: %s",
+                account_line(sample.values()),
+            )
         if self._log_sample_fn is not None:
-            self._log_sample_fn(span.to_log_sample())
+            self._log_sample_fn(sample)
 
     def log_perf_events(self, perf_events: Optional[PerfEvents]) -> None:
         """Convergence measurement (Fib.cpp:760-843)."""

@@ -23,6 +23,7 @@ routes latency, not local solve time.
 
 from __future__ import annotations
 
+import heapq
 import math
 import time
 from typing import Any, Dict, Iterable, List, Optional, Tuple
@@ -87,6 +88,14 @@ class ConvergenceRollup:
         `evicted_events`; their samples stay in the cumulative layer, so
         window eviction loses trend resolution, never data).
 
+    Beside the aggregates each window keeps the whole samples of its
+    `SLOWEST_KEPT` slowest spans (by `total_ms`; a bounded heap), with the
+    account Fib closed for them: `gc_full_ms`, `unstaged_ms`, the
+    `stage.<name>_ms` of the stages that ran under the event's build, what
+    Decision noted of the build. The ring evicts a sample within seconds;
+    the one record that says between which marks an event of seconds fell
+    stays for as long as its window does (`slowest()`, `snapshot()`).
+
     Memory is O(max_windows x stages), independent of event rate; one
     record is O(stages) Histogram.record calls. Snapshots are
     JSON-serializable (sparse histograms) and merge across nodes —
@@ -95,6 +104,7 @@ class ConvergenceRollup:
     """
 
     TOTAL_STAGE = "total"
+    SLOWEST_KEPT = 8
 
     def __init__(
         self,
@@ -110,7 +120,8 @@ class ConvergenceRollup:
         self.evicted_events = 0
         self.window_evictions = 0
         self.cumulative: Dict[str, Histogram] = {}
-        # ordered oldest->newest: (window index, {"events": n, stages})
+        # ordered oldest->newest: (window index, {"events": n, stages,
+        # "slowest": heap of (total_ms, events_total at record, values)})
         self._windows: List[Tuple[int, Dict[str, Any]]] = []
 
     def record_span(
@@ -128,6 +139,14 @@ class ConvergenceRollup:
             self.evicted_events += 1
         else:
             window["events"] += 1
+            total = stages.get(self.TOTAL_STAGE)
+            if total is not None:
+                heap = window["slowest"]
+                item = (total, self.events_total, values)
+                if len(heap) < self.SLOWEST_KEPT:
+                    heapq.heappush(heap, item)
+                elif total > heap[0][0]:
+                    heapq.heapreplace(heap, item)
         for stage, ms in stages.items():
             cum = self.cumulative.get(stage)
             if cum is None:
@@ -160,7 +179,7 @@ class ConvergenceRollup:
                 and len(self._windows) >= self.max_windows
             ):
                 return None
-        window: Dict[str, Any] = {"events": 0, "stages": {}}
+        window: Dict[str, Any] = {"events": 0, "stages": {}, "slowest": []}
         self._windows.append((index, window))
         self._windows.sort(key=lambda iw: iw[0])
         while len(self._windows) > self.max_windows:
@@ -187,8 +206,17 @@ class ConvergenceRollup:
             "stages": window["stages"],
         }
 
+    def slowest(self) -> List[Dict[str, Any]]:
+        """The samples of the `SLOWEST_KEPT` slowest spans of the retained
+        windows, slowest first (what `getConvergenceReport` serves as
+        `slowest`)."""
+        return _slowest_samples(
+            values for _, w in self._windows for _, _, values in w["slowest"]
+        )
+
     def snapshot(self) -> Dict[str, Any]:
-        """JSON-serializable export (sparse histograms), the shape served
+        """JSON-serializable export (sparse histograms; each window with
+        the samples of its slowest spans, slowest first), the shape served
         inside node_convergence_report and merged network-wide by
         merge_rollup_snapshots."""
         return {
@@ -209,10 +237,22 @@ class ConvergenceRollup:
                         stage: h.to_sparse()
                         for stage, h in sorted(window["stages"].items())
                     },
+                    "slowest": _slowest_samples(
+                        values for _, _, values in window["slowest"]
+                    ),
                 }
                 for index, window in self._windows
             ],
         }
+
+
+def _slowest_samples(
+    samples: Iterable[Dict[str, Any]], keep: int = ConvergenceRollup.SLOWEST_KEPT
+) -> List[Dict[str, Any]]:
+    """Span samples by `total_ms`, the `keep` slowest first."""
+    return sorted(
+        samples, key=lambda values: values.get("total_ms", 0.0), reverse=True
+    )[:keep]
 
 
 def merge_rollup_snapshots(
@@ -222,7 +262,8 @@ def merge_rollup_snapshots(
     live Histogram objects: same-start windows merge across nodes (the
     wall clock is the shared axis). Returns {"window_s", "events_total",
     "evicted_events", "window_evictions", "cumulative": {stage: Histogram},
-    "windows": [{"start", "events", "stages": {stage: Histogram}}]}."""
+    "windows": [{"start", "events", "stages": {stage: Histogram},
+    "slowest": [sample values]}]}."""
     window_s = 0.0
     events_total = evicted = window_evictions = 0
     cumulative: Dict[str, Histogram] = {}
@@ -243,9 +284,13 @@ def merge_rollup_snapshots(
         for window in snap.get("windows") or []:
             start = float(window.get("start", 0.0))
             merged = windows.setdefault(
-                start, {"start": start, "events": 0, "stages": {}}
+                start,
+                {"start": start, "events": 0, "stages": {}, "slowest": []},
             )
             merged["events"] += int(window.get("events", 0))
+            merged["slowest"] = _slowest_samples(
+                merged["slowest"] + list(window.get("slowest") or [])
+            )
             for stage, sparse in (window.get("stages") or {}).items():
                 hist = Histogram.from_sparse(sparse)
                 if stage in merged["stages"]:
@@ -311,6 +356,9 @@ def node_convergence_report(
         "floods": floods,
         "flood": flood_stats,
         "rollup": rollup.snapshot() if rollup is not None else None,
+        # out of the ring's reach: the slowest spans of the retained
+        # windows, each with the account Fib closed for it
+        "slowest": rollup.slowest() if rollup is not None else [],
     }
 
 
@@ -390,6 +438,9 @@ def aggregate_convergence_reports(
             for stage, samples in sorted(stage_samples.items())
         },
         "slowest_stage": slowest,
+        "slowest": _slowest_samples(
+            sample for r in reports for sample in r.get("slowest") or []
+        ),
         "rollup": _aggregate_rollups(reports),
         "flood": {
             "received": received,

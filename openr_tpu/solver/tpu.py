@@ -500,8 +500,10 @@ class _AreaSolve:
         that buffer to the next event — a view would alias reused memory."""
         if self._d_host is None:
             fetch = phase_stage("d2h", self._pclock.build)
-            self._d_host = self._to_host(self._d_dev)
-            ms = fetch.stop()
+            try:
+                self._d_host = self._to_host(self._d_dev)
+            finally:
+                ms = fetch.stop()  # a failed read leaves no stage open
             self.d2h_bytes += self._d_host.nbytes
             trace = self._last_trace
             if trace is not None:

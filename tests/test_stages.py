@@ -35,10 +35,17 @@ WARM_EVENT_STAGES = [
     "decision.spf.phase.mirror_patch",
     "decision.delta_build",
     "decision.emit",
+    "fib.apply",
     "fib.program",
 ]
 # the stretches that are histograms only: an umbrella or a queue hop
-HISTOGRAM_ONLY = ["decision.queue_wait_ms", "decision.debounce_ms", "fib.program_ms"]
+HISTOGRAM_ONLY = [
+    "decision.queue_wait_ms",
+    "decision.debounce_ms",
+    "fib.queue_wait_ms",
+    "fib.program_ms",
+    "convergence.unstaged_ms",
+]
 
 
 class RecordedAnnotation:
