@@ -11,7 +11,7 @@ every topology; tests enforce it.
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Set, Tuple
+from typing import Dict, Iterable, List, Optional, Set, Tuple
 
 from openr_tpu.lsdb.link_state import Link, LinkState, Path, path_a_in_path_b
 from openr_tpu.utils.counters import CountersMixin, HistogramsMixin
@@ -51,6 +51,7 @@ BACKEND_COUNTER_PREFIXES = (
     "decision.spf.",
     "decision.mem.",
     "decision.route_build_table_routes",
+    "decision.route_build_table_reads",
     "decision.route_build_generic_routes",
     "decision.route_build_label_sets_made",
 )
@@ -208,15 +209,13 @@ class SpfSolver(CountersMixin, HistogramsMixin):
         self._bump("decision.route_build_runs")
 
         # ---- unicast best paths (IP and IP2MPLS) ----
-        for prefix, prefix_entries in prefix_state.prefixes.items():
-            self.build_unicast_route(
-                route_db.unicast_entries,
-                my_node_name,
-                prefix,
-                prefix_entries,
-                area_link_states,
-                prefix_state,
-            )
+        self.build_unicast_routes(
+            route_db.unicast_entries,
+            my_node_name,
+            prefix_state.prefixes.items(),
+            area_link_states,
+            prefix_state,
+        )
 
         # ---- MPLS node-label routes (Decision.cpp:415-501) ----
         label_to_node: Dict[int, Tuple[str, RibMplsEntry]] = {}
@@ -267,6 +266,33 @@ class SpfSolver(CountersMixin, HistogramsMixin):
                 )
         return route_db
 
+    def build_unicast_routes(
+        self,
+        unicast_entries: Dict[IpPrefix, RibUnicastEntry],
+        my_node_name: str,
+        prefixes: Iterable[Tuple[IpPrefix, Dict[str, Dict[str, PrefixEntry]]]],
+        area_link_states: Dict[str, LinkState],
+        prefix_state: PrefixState,
+    ) -> None:
+        """The unicast routes of one build, asked for together. `prefixes`
+        gives (prefix, its advertisements) pairs; every prefix that
+        somebody advertises gets its entry, if it has a route, in
+        `unicast_entries`, in the order given. The seam of the full build
+        and of the DeltaPath partial rebuild. Here it is a loop over
+        `build_unicast_route`; a backend that holds every destination's
+        first hops at once (the TPU's) resolves once a build what is the
+        same for all of them."""
+        for prefix, prefix_entries in prefixes:
+            if prefix_entries:
+                self.build_unicast_route(
+                    unicast_entries,
+                    my_node_name,
+                    prefix,
+                    prefix_entries,
+                    area_link_states,
+                    prefix_state,
+                )
+
     def build_unicast_route(
         self,
         unicast_entries: Dict[IpPrefix, RibUnicastEntry],
@@ -278,9 +304,8 @@ class SpfSolver(CountersMixin, HistogramsMixin):
     ) -> None:
         """One prefix's best-path selection + nexthop assembly (the body of
         build_route_db's unicast loop), writing the entry — if any — into
-        `unicast_entries`. Exposed as a seam so the DeltaPath route build
-        (solver/delta.py) can recompute exactly the prefixes a device
-        delta names instead of looping the whole table."""
+        `unicast_entries`: what `build_unicast_routes` does for each prefix
+        that it does not answer together with others."""
         has_bgp = has_non_bgp = missing_mv = False
         for node, areas in prefix_entries.items():
             for entry in areas.values():

@@ -54,6 +54,29 @@ from openr_tpu.types import is_mpls_label_valid
 log = logging.getLogger(__name__)
 
 
+class _ChangedRoutes:
+    """Where the solver writes a partial rebuild's unicast entries. An
+    entry is compared with the previous db's where it arrives, after the
+    policy hook, and kept only if it differs: DeltaPath rebuilds the
+    prefixes of every column in which any row of the solve moved, a dozen
+    times those whose route from here changes, and an unchanged route's
+    entry dies at once instead of living to the build's end."""
+
+    def __init__(self, prev_entries, policy_fn, changed: list) -> None:
+        self._prev_entries = prev_entries
+        self._policy_fn = policy_fn
+        self._changed = changed
+        self.routed: Set = set()  # prefixes that got an entry, changed or not
+
+    def __setitem__(self, prefix, entry) -> None:
+        self.routed.add(prefix)
+        if self._policy_fn is not None:
+            self._policy_fn(entry)
+        old_entry = self._prev_entries.get(prefix)
+        if old_entry is None or old_entry != entry:
+            self._changed.append(entry)
+
+
 class DeltaRouteBuilder:
     """Builds (new route db, update) per rebuild, taking the O(changes)
     partial path whenever the solver offers a device delta and the event
@@ -184,29 +207,24 @@ class DeltaRouteBuilder:
         dirty |= set(prefix_state.mpls_forwarding_prefixes)
 
         update = DecisionRouteUpdate()
-        scratch: Dict = {}
-        for prefix in sorted(dirty):
-            prefix_entries = prefix_state.prefixes.get(prefix)
-            new_entry = None
-            if prefix_entries:
-                self.solver.build_unicast_route(
-                    scratch,
-                    my_node_name,
-                    prefix,
-                    prefix_entries,
-                    area_link_states,
-                    prefix_state,
-                )
-                new_entry = scratch.pop(prefix, None)
-            old_entry = prev_db.unicast_entries.get(prefix)
-            if new_entry is None:
-                if old_entry is not None:
-                    update.unicast_routes_to_delete.append(prefix)
-                continue
-            if policy_fn is not None:
-                policy_fn(new_entry)
-            if old_entry is None or old_entry != new_entry:
-                update.unicast_routes_to_update.append(new_entry)
+        ordered = sorted(dirty)
+        advertised = prefix_state.prefixes
+        rebuilt = _ChangedRoutes(
+            prev_db.unicast_entries, policy_fn, update.unicast_routes_to_update
+        )
+        self.solver.build_unicast_routes(
+            rebuilt,
+            my_node_name,
+            ((prefix, advertised.get(prefix)) for prefix in ordered),
+            area_link_states,
+            prefix_state,
+        )
+        update.unicast_routes_to_delete.extend(
+            prefix
+            for prefix in ordered
+            if prefix not in rebuilt.routed
+            and prefix in prev_db.unicast_entries
+        )
 
         # node-label routes of the changed destinations (their distance /
         # nexthop set moved); adjacency-label routes depend only on my own

@@ -29,7 +29,16 @@ order).
 from __future__ import annotations
 
 import time
-from typing import Dict, FrozenSet, List, Optional, Set, Tuple, Union
+from typing import (
+    Dict,
+    FrozenSet,
+    Iterable,
+    List,
+    Optional,
+    Set,
+    Tuple,
+    Union,
+)
 
 import numpy as np
 
@@ -55,9 +64,17 @@ from openr_tpu.solver.flight_recorder import (
     SolveTrace,
     phase_stage,
 )
-from openr_tpu.solver.routes import LabelNextHops
+from openr_tpu.lsdb.prefix_state import PrefixState
+from openr_tpu.solver.routes import LabelNextHops, RibUnicastEntry
 from openr_tpu.testing.faults import fault_point
-from openr_tpu.types import NextHop
+from openr_tpu.types import (
+    IpPrefix,
+    NextHop,
+    PrefixEntry,
+    PrefixForwardingAlgorithm,
+    PrefixForwardingType,
+    PrefixType,
+)
 
 
 class DeviceCapacityError(RuntimeError):
@@ -76,6 +93,42 @@ _PATCH_SLOTS = 64
 # destination columns changed, the full [S, n_pad] mirror is the cheaper
 # copy-back and the event is served as a full rebuild instead
 _DELTA_MAX_FRAC = 0.5
+
+
+# what became of a prefix in `TpuSpfSolver.build_unicast_routes`, where it is
+# not the number of its next-hop set
+_NO_ROUTE = -1
+_ONE_BY_ONE = -2
+
+
+def _run_starts(ids: np.ndarray) -> np.ndarray:
+    """Where each run of equal values starts in `ids` (not empty)."""
+    new = np.empty(len(ids), dtype=bool)
+    new[0] = True
+    np.not_equal(ids[1:], ids[:-1], out=new[1:])
+    return np.flatnonzero(new)
+
+
+def _nearest_announcers(
+    item: np.ndarray, dist: np.ndarray, reach: np.ndarray, drained: np.ndarray
+) -> np.ndarray:
+    """Which of a batch's announcers its routes go toward. Announcer k
+    belongs to prefix `item[k]` (ascending) and lies at `dist[k]`: of each
+    prefix's reachable announcers the healthy ones, or all where all are
+    drained, and of those the nearest."""
+    toward = reach
+    if drained.any():
+        healthy = reach & ~drained
+        some_healthy = np.zeros(item[-1] + 1, dtype=bool)
+        some_healthy[item[healthy]] = True
+        toward = np.where(some_healthy[item], healthy, reach)
+    toward = np.flatnonzero(toward)
+    if not len(toward):
+        return toward
+    starts = _run_starts(item[toward])
+    nearest = np.minimum.reduceat(dist[toward], starts)
+    lengths = np.diff(starts, append=len(toward))
+    return toward[dist[toward] == np.repeat(nearest, lengths)]
 
 
 class _NodeView:
@@ -308,14 +361,7 @@ class _NextHopTable:
             group_key = self._mask[:, nearest].any(axis=1).tobytes()
         if metric >= INF:
             return None
-        group = self._groups.get(group_key)
-        if group is None:
-            member = np.frombuffer(group_key, dtype=np.bool_)
-            links = tuple(self._links[i] for i in np.flatnonzero(member))
-            group = self._groups[group_key] = (
-                links, frozenset(link[0] for link in links)
-            )
-        links, neighbors = group
+        links, neighbors = self._group(group_key)
         if not links:
             return None  # toward myself
         if swap_label is not None:
@@ -329,6 +375,78 @@ class _NextHopTable:
                 neighbors & dst_node_names or frozenset(),
                 label_sets_made,
             )
+        # a set of its own for every route: RibPolicy rewrites an entry's
+        # nexthops, and no sibling's may change with it
+        return set(self._shared_set(group_key, links, metric, is_v4))
+
+    def read_unicast(
+        self,
+        cols: np.ndarray,
+        starts: np.ndarray,
+        metrics: np.ndarray,
+        is_v4: np.ndarray,
+    ) -> Tuple[np.ndarray, List[FrozenSet[NextHop]]]:
+        """`next_hops` without a label, for all the routes of a build in
+        one read. Route r goes toward the destinations whose columns are
+        `cols[starts[r]:starts[r + 1]]`, all at distance `metrics[r]` (its
+        nearest announcers). Returns, for each route, which of the returned
+        sets holds its next hops (-1 where no link leads there: toward
+        myself), and the sets: shared, so a route takes a copy."""
+        routes = len(starts)
+        if not routes or not self._links:
+            return np.full(routes, -1, dtype=np.intp), []
+        member = self._mask[:, cols]  # [links, destinations]
+        if routes < len(cols):
+            # a link is a first hop toward a set where it is toward one
+            member = np.logical_or.reduceat(member, starts, axis=1)
+        member = np.ascontiguousarray(member.T)
+        # routes of one (group, metric, family) share a set: one sort of
+        # the three laid side by side finds them
+        keys = np.concatenate(
+            (
+                np.packbits(member, axis=1),
+                metrics.astype("<i4")[:, None].view(np.uint8),
+                is_v4.astype(np.uint8)[:, None],
+            ),
+            axis=1,
+        )
+        _, first, which = np.unique(
+            keys.view(np.dtype((np.void, keys.shape[1]))).ravel(),
+            return_index=True,
+            return_inverse=True,
+        )
+        sets: List[FrozenSet[NextHop]] = []
+        for route in first.tolist():
+            group_key = member[route].tobytes()
+            links, _ = self._group(group_key)
+            sets.append(
+                self._shared_set(
+                    group_key, links, int(metrics[route]), bool(is_v4[route])
+                )
+                if links
+                else frozenset()
+            )
+        has_links = np.fromiter(map(bool, sets), dtype=bool, count=len(sets))
+        return np.where(has_links[which], which, -1), sets
+
+    def _group(
+        self, group_key: bytes
+    ) -> Tuple[Tuple[tuple, ...], FrozenSet[str]]:
+        """(links, their neighbours' names) of what a column holds."""
+        group = self._groups.get(group_key)
+        if group is None:
+            member = np.frombuffer(group_key, dtype=np.bool_)
+            links = tuple(self._links[i] for i in np.flatnonzero(member))
+            group = self._groups[group_key] = (
+                links, frozenset(link[0] for link in links)
+            )
+        return group
+
+    def _shared_set(
+        self, group_key: bytes, links, metric: int, is_v4: bool
+    ) -> FrozenSet[NextHop]:
+        """The unicast next hops of every destination behind a group at
+        one distance in one family, made once."""
         key = (group_key, metric, is_v4)
         shared = self.unicast_sets.get(key)
         if shared is None:
@@ -345,9 +463,7 @@ class _NextHopTable:
                 )
                 for neighbor, v4, v6, iface, area in links
             )
-        # a set of its own for every route: RibPolicy rewrites an entry's
-        # nexthops, and no sibling's may change with it
-        return set(shared)
+        return shared
 
 
 class _AreaSolve:
@@ -1738,11 +1854,15 @@ class TpuSpfSolver(SpfSolver):
         # solve's next-hop table / from the generic stack
         self._table_routes = 0
         self._generic_routes = 0
+        # questions a build asked a next-hop table since then: one route's
+        # (`next_hops`) or all the plain routes' at once (`read_unicast`)
+        self._table_reads = 0
         # next-hop sets of the table's label routes that a reader made
         # (routes.LabelNextHops.make): every such route carries this tally
         self._label_sets_made = [0]
         # bumped by 0: the counters exist from the start
         self._bump("decision.route_build_table_routes", 0)
+        self._bump("decision.route_build_table_reads", 0)
         self._bump("decision.route_build_generic_routes", 0)
         self._bump("decision.route_build_label_sets_made", 0)
         self.device_solves = 0  # counter: batched device calls
@@ -2107,10 +2227,11 @@ class TpuSpfSolver(SpfSolver):
             if cached is not None and cached[0] == id(link_state):
                 self._sync_spf_counters(cached[1])
         self._bump("decision.route_build_table_routes", self._table_routes)
+        self._bump("decision.route_build_table_reads", self._table_reads)
         self._bump(
             "decision.route_build_generic_routes", self._generic_routes
         )
-        self._table_routes = self._generic_routes = 0
+        self._table_routes = self._table_reads = self._generic_routes = 0
         # a reader downstream of the build (Fib, ctrl) shows at the next sync
         self.counters["decision.route_build_label_sets_made"] = (
             self._label_sets_made[0]
@@ -2282,6 +2403,192 @@ class TpuSpfSolver(SpfSolver):
                 return metric if metric < INF else None
         return link_state.get_metric_from_a_to_b(a, b)
 
+    def build_unicast_routes(
+        self,
+        unicast_entries: Dict[IpPrefix, RibUnicastEntry],
+        my_node_name: str,
+        prefixes: Iterable[Tuple[IpPrefix, Dict[str, Dict[str, PrefixEntry]]]],
+        area_link_states: Dict[str, LinkState],
+        prefix_state: PrefixState,
+    ) -> None:
+        """The build's plain prefixes answered in one read of the next-hop
+        table; the rest one by one, through `build_unicast_route`.
+
+        Once a build: the one area that holds my node, its table, my
+        distance row, who is drained. A prefix is plain where every
+        advertisement is non-BGP, SP_ECMP and IP-forwarded and lies in
+        that area: what takes `next_hops_toward` to the table. Their
+        announcers become columns; reachability, the drained announcers'
+        filter (`_maybe_filter_drained_nodes`' rule) and the nearest
+        announcers (`get_min_cost_nodes`' rule) are array work over all
+        of them, and `_NextHopTable.read_unicast` reads each nearest
+        column's first hops once and makes a next-hop set once a (group,
+        metric, family). Once a route: its entry, with a set of its own
+        (RibPolicy rewrites an entry's next hops in place, and no
+        sibling's may change with it). Entries, their order in
+        `unicast_entries` and the counters are `build_unicast_route`'s.
+        With LFA on, for another node's view, or where not exactly one
+        area holds my node, every prefix goes one by one."""
+        mine = None
+        if not self.compute_lfa_paths and my_node_name == self.my_node_name:
+            mine = self._my_one_area(area_link_states)
+        if mine is None:
+            return super().build_unicast_routes(
+                unicast_entries,
+                my_node_name,
+                prefixes,
+                area_link_states,
+                prefix_state,
+            )
+        area, solve = mine
+        index_of = solve.graph.node_index.get
+        bgp = PrefixType.BGP
+        ip = PrefixForwardingType.IP
+        sp_ecmp = PrefixForwardingAlgorithm.SP_ECMP
+        v4_enabled = self.enable_v4
+        # in the order given, side by side: the prefix; its
+        # advertisements; how many announcers it asks the table about,
+        # else what became of it (_NO_ROUTE, _ONE_BY_ONE); a lone
+        # announcer's advertisement. And the columns of all the
+        # announcers asked about
+        batch: List[IpPrefix] = []
+        adverts: List[Dict[str, Dict[str, PrefixEntry]]] = []
+        asks: List[int] = []
+        lone: List[Optional[PrefixEntry]] = []
+        cols: List[int] = []
+        v4_disabled = 0
+        for prefix, prefix_entries in prefixes:
+            if not prefix_entries:
+                continue
+            batch.append(prefix)
+            adverts.append(prefix_entries)
+            mark = len(cols)
+            for node, areas in prefix_entries.items():
+                entry = areas.get(area)
+                if (
+                    entry is None
+                    or len(areas) != 1
+                    or entry.type == bgp
+                    or entry.forwarding_type != ip
+                    or entry.forwarding_algorithm != sp_ecmp
+                ):
+                    asked = _ONE_BY_ONE
+                    break
+                cols.append(index_of(node, -1))
+            else:
+                asked = len(cols) - mark
+                if my_node_name in prefix_entries:
+                    asked = _NO_ROUTE  # mine: no route needed
+                elif not v4_enabled and prefix.is_v4:
+                    v4_disabled += 1
+                    asked = _NO_ROUTE
+            if asked < 0:
+                del cols[mark:]
+            asks.append(asked)
+            lone.append(entry if asked == 1 else None)
+        if v4_disabled:
+            self._bump("decision.skipped_unicast_route", v4_disabled)
+
+        # all the announcers asked about, as arrays: reachable, healthy,
+        # nearest; then the table's one read
+        count = np.maximum(asks, 0)
+        # a prefix asked about has no route until the table gives it one
+        fate = np.minimum(asks, _NO_ROUTE)
+        col = np.asarray(cols, dtype=np.intp)
+        reach = np.zeros(0, dtype=bool)
+        sets: List[FrozenSet[NextHop]] = []
+        if len(col):
+            item = np.repeat(np.arange(len(count)), count)
+            # a node the graph does not hold reads the last column: masked
+            dist = np.where(col >= 0, solve.d[0][col], INF)
+            reach = dist < INF
+            near = _nearest_announcers(
+                item,
+                dist,
+                reach,
+                self._drained_announcers(
+                    area, area_link_states, solve, adverts, count, col
+                ),
+            )
+            routes = 0
+            if len(near):
+                starts = _run_starts(item[near])
+                routed = item[near][starts]
+                self._table_reads += 1
+                which, sets = solve.next_hop_table().read_unicast(
+                    col[near],
+                    starts,
+                    dist[near][starts],
+                    np.fromiter(
+                        (batch[i].is_v4 for i in routed.tolist()),
+                        dtype=bool,
+                        count=len(routed),
+                    ),
+                )
+                fate[routed] = which
+                routes = int(np.count_nonzero(which >= 0))
+                self._table_routes += routes
+            unrouted = int(np.count_nonzero(count)) - routes
+            if unrouted:
+                self._bump("decision.no_route_to_prefix", unrouted)
+
+        # a route: its entry and its own copy of the set
+        reached = reach.tolist()
+        first = (np.cumsum(count) - count).tolist()
+        for prefix, prefix_entries, which_set, best_entry, at in zip(
+            batch, adverts, fate.tolist(), lone, first
+        ):
+            if which_set >= 0:
+                if best_entry is None:
+                    # the smallest reachable announcer's name, drained
+                    # or not (get_best_announcing_nodes)
+                    best = min(
+                        node
+                        for node, ok in zip(
+                            prefix_entries,
+                            reached[at : at + len(prefix_entries)],
+                        )
+                        if ok
+                    )
+                    best_entry = prefix_entries[best][area]
+                unicast_entries[prefix] = RibUnicastEntry(
+                    prefix, set(sets[which_set]), best_entry, area
+                )
+            elif which_set == _ONE_BY_ONE:
+                self.build_unicast_route(
+                    unicast_entries,
+                    my_node_name,
+                    prefix,
+                    prefix_entries,
+                    area_link_states,
+                    prefix_state,
+                )
+
+    @staticmethod
+    def _drained_announcers(
+        area, area_link_states, solve, adverts, count, col
+    ) -> np.ndarray:
+        """Which of a batch's announcers (by column, in the batch's order)
+        are drained, in my area or in any other."""
+        drained = solve.graph.overloaded[col]
+        others = [
+            ls for name, ls in area_link_states.items() if name != area
+        ]
+        if others:
+            # an area that does not hold my node has its say on who is
+            # drained all the same
+            drained = drained | np.fromiter(
+                (
+                    any(ls.is_node_overloaded(node) for ls in others)
+                    for prefix_entries, asked in zip(adverts, count.tolist())
+                    if asked
+                    for node in prefix_entries
+                ),
+                dtype=bool,
+                count=len(col),
+            )
+        return drained
+
     def next_hops_toward(
         self,
         my_node_name: str,
@@ -2303,6 +2610,7 @@ class TpuSpfSolver(SpfSolver):
         ):
             table = self._next_hop_table(area_link_states, prefix_areas)
         if table is not None:
+            self._table_reads += 1
             next_hops = table.next_hops(
                 dst_node_names, is_v4, swap_label, self._label_sets_made
             )
@@ -2327,15 +2635,24 @@ class TpuSpfSolver(SpfSolver):
     ) -> Optional[_NextHopTable]:
         """The table of the one area that holds my node, where that area
         is among the prefix's; None for any other input."""
+        mine = self._my_one_area(area_link_states)
+        if mine is None or mine[0] not in prefix_areas:
+            return None
+        return mine[1].next_hop_table()
+
+    def _my_one_area(
+        self, area_link_states: Dict[str, LinkState]
+    ) -> Optional[Tuple[str, _AreaSolve]]:
+        """(area, my solve there) where one area alone holds my node."""
         mine = None
         for area, link_state in area_link_states.items():
             solve = self._my_solve(link_state)
             if solve is None:
                 continue
-            if mine is not None or area not in prefix_areas:
+            if mine is not None:
                 return None
-            mine = solve
-        return mine.next_hop_table() if mine is not None else None
+            mine = (area, solve)
+        return mine
 
     def _kth_paths(
         self, link_state: LinkState, src: str, dest: str, k: int

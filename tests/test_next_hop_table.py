@@ -74,7 +74,13 @@ def generic_stack(solver):
     def base_seam(*args):
         return SpfSolver.next_hops_toward(solver, *args)
 
+    def one_by_one(*args):
+        return SpfSolver.build_unicast_routes(solver, *args)
+
     solver.next_hops_toward = base_seam
+    # the plain prefixes of a build go to the table together, past the
+    # seam above: here they go one by one, through it
+    solver.build_unicast_routes = one_by_one
     return solver
 
 

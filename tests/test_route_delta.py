@@ -1089,3 +1089,420 @@ class TestCounterSyncs:
             "phase.mirror_patch_ms": 1, "phase.d2h_ms": 1,
         }
         assert sup.counters["decision.spf.counter_syncs"] == 7
+
+
+# ---------------------------------------------------------------------------
+# The plural seam (ISSUE 38): a build's prefixes asked for together
+# ---------------------------------------------------------------------------
+
+SEAM_COUNTERS = (
+    "decision.no_route_to_prefix",
+    "decision.skipped_unicast_route",
+    "decision.incompatible_forwarding_type",
+    "decision.missing_loopback_addr",
+)
+TABLE_ROUTES = "decision.route_build_table_routes"
+TABLE_READS = "decision.route_build_table_reads"
+GENERIC_ROUTES = "decision.route_build_generic_routes"
+
+
+class _Adverts(dict):
+    """(node, area) -> the entries it advertises there, gathered before
+    any PrefixDatabase is made (one replaces the one before it)."""
+
+    def prefix_state(self):
+        ps = PrefixState()
+        for (node, area), entries in self.items():
+            ps.update_prefix_database(PrefixDatabase(node, entries, area=area))
+        return ps
+
+
+def _advertise(ps, node, entries, area="0"):
+    ps.setdefault((node, area), []).extend(entries)
+
+
+def _every_node(dbs, ps, v6=True, area="0"):
+    for i, node in enumerate(sorted(dbs)):
+        entries = [PrefixEntry(IpPrefix(f"10.{i // 256}.{i % 256}.0/24"))]
+        if v6:
+            entries.append(PrefixEntry(IpPrefix(f"fc00:{i:x}::/64")))
+        _advertise(ps, node, entries, area)
+
+
+def _win_if_present(value):
+    from openr_tpu.types import CompareType, MetricEntity, MetricVector
+
+    return MetricVector(
+        version=1,
+        metrics=(
+            MetricEntity(
+                id=10, priority=10, op=CompareType.WIN_IF_PRESENT,
+                metric=(value,),
+            ),
+        ),
+    )
+
+
+def _seam_fabric():
+    edges = fabric_edges(
+        3, planes=2, ssw_per_plane=2, fsw_per_pod=2, rsw_per_pod=3
+    )
+    return "rsw0_0", build_adj_dbs(edges)
+
+
+def _seam_grid():
+    return "g0_0", build_adj_dbs(grid_edges(4))
+
+
+def _seam_wan():
+    from openr_tpu.topology import wan_edges
+
+    return "w3", build_adj_dbs(wan_edges(24, degree=4, seed=11))
+
+
+def _seam_anycast(dbs, ps):
+    # two announcers at equal distance (3 and 3), three at unequal
+    # (2, 4, 6), two of three at the nearest distance, v4 and v6
+    for node in ("g0_3", "g3_0"):
+        _advertise(ps, node, [
+            PrefixEntry(IpPrefix("10.200.0.0/16")),
+            PrefixEntry(IpPrefix("fc00:200::/48")),
+        ])
+    for node in ("g1_1", "g2_2", "g3_3"):
+        _advertise(ps, node, [PrefixEntry(IpPrefix("10.201.0.0/16"))])
+    for node in ("g0_2", "g2_0", "g3_3"):
+        _advertise(ps, node, [PrefixEntry(IpPrefix("fc00:202::/48"))])
+
+
+def _seam_unreachable(dbs, ps):
+    # an announcer the graph does not hold, alone and beside one it holds
+    _advertise(ps, "ghost", [
+        PrefixEntry(IpPrefix("10.210.0.0/16")),
+        PrefixEntry(IpPrefix("10.211.0.0/16")),
+    ])
+    _advertise(ps, "g2_2", [PrefixEntry(IpPrefix("10.211.0.0/16"))])
+
+
+def _seam_island(dbs, ps):
+    # announcers the graph holds and nothing reaches
+    for node in ("island_a", "island_b"):
+        _advertise(ps, node, [PrefixEntry(IpPrefix("10.212.0.0/16"))])
+    _advertise(ps, "island_a", [PrefixEntry(IpPrefix("10.213.0.0/16"))])
+    _advertise(ps, "g1_3", [PrefixEntry(IpPrefix("10.213.0.0/16"))])
+
+
+def _seam_drained(dbs, ps):
+    # a drained announcer alone keeps its route; beside a healthy one,
+    # nearer and smaller by name, it loses the route and stays the best
+    # entry's; all drained, the nearest of them
+    _advertise(ps, "g1_1", [
+        PrefixEntry(IpPrefix("10.220.0.0/16")),
+        PrefixEntry(IpPrefix("10.221.0.0/16")),
+        PrefixEntry(IpPrefix("10.222.0.0/16")),
+    ])
+    _advertise(ps, "g3_3", [PrefixEntry(IpPrefix("10.221.0.0/16"))])
+    _advertise(ps, "g2_1", [PrefixEntry(IpPrefix("10.222.0.0/16"))])
+
+
+def _seam_mine(dbs, ps):
+    _advertise(ps, "g0_0", [PrefixEntry(IpPrefix("10.230.0.0/16"))])
+    _advertise(ps, "g2_2", [PrefixEntry(IpPrefix("10.230.0.0/16"))])
+
+
+def _seam_other_classes(dbs, ps):
+    """BGP, mixed-type, KSP2 and SR_MPLS prefixes among plain ones."""
+    from openr_tpu.types import (
+        PrefixForwardingAlgorithm,
+        PrefixForwardingType,
+        PrefixType,
+    )
+
+    for node, value in (("g3_3", 7), ("g0_3", 9)):
+        _advertise(ps, node, [
+            PrefixEntry(
+                IpPrefix("10.240.0.0/16"), type=PrefixType.BGP,
+                mv=_win_if_present(value),
+            ),
+            PrefixEntry(
+                IpPrefix(f"192.168.0.{value}/32"), type=PrefixType.LOOPBACK
+            ),
+        ])
+    # a BGP announcer without a loopback: no route, and a counter
+    _advertise(ps, "g2_3", [PrefixEntry(
+        IpPrefix("10.241.0.0/16"), type=PrefixType.BGP, mv=_win_if_present(1)
+    )])
+    # mixed-type: skipped, and counted
+    _advertise(ps, "g1_2", [PrefixEntry(IpPrefix("10.242.0.0/16"))])
+    _advertise(ps, "g2_1", [PrefixEntry(
+        IpPrefix("10.242.0.0/16"), type=PrefixType.BGP, mv=_win_if_present(1)
+    )])
+    _advertise(ps, "g3_2", [
+        PrefixEntry(
+            IpPrefix("10.243.0.0/16"),
+            forwarding_type=PrefixForwardingType.SR_MPLS,
+            forwarding_algorithm=PrefixForwardingAlgorithm.KSP2_ED_ECMP,
+        ),
+        PrefixEntry(
+            IpPrefix("10.244.0.0/16"),
+            forwarding_type=PrefixForwardingType.SR_MPLS,
+        ),
+        # KSP2 over IP forwarding: incompatible, and counted
+        PrefixEntry(
+            IpPrefix("10.245.0.0/16"),
+            forwarding_algorithm=PrefixForwardingAlgorithm.KSP2_ED_ECMP,
+        ),
+    ])
+    # one announcer IP, one SR_MPLS: the minimum is IP, one by one
+    _advertise(ps, "g1_3", [PrefixEntry(IpPrefix("10.246.0.0/16"))])
+    _advertise(ps, "g3_1", [PrefixEntry(
+        IpPrefix("10.246.0.0/16"),
+        forwarding_type=PrefixForwardingType.SR_MPLS,
+    )])
+
+
+def _seam_two_areas(dbs, ps):
+    # announced in my area and in one that does not hold me
+    _advertise(ps, "g2_2", [PrefixEntry(IpPrefix("10.250.0.0/16"))], "0")
+    _advertise(ps, "g2_2", [PrefixEntry(IpPrefix("10.250.0.0/16"))], "1")
+    _advertise(ps, "far_b", [PrefixEntry(IpPrefix("10.251.0.0/16"))], "1")
+
+
+def _island_links():
+    return build_adj_dbs([("island_a", "island_b", 1)])
+
+
+# name -> (network, what else is advertised, extra adjacency databases,
+# drained nodes, solver options, whether a second area stands beside mine)
+SEAM_CASES = {
+    "fabric": (_seam_fabric, None, None, (), {}, False),
+    "grid": (_seam_grid, None, None, (), {}, False),
+    "wan": (_seam_wan, None, None, (), {}, False),
+    "anycast": (_seam_grid, _seam_anycast, None, (), {}, False),
+    "unreachable": (_seam_grid, _seam_unreachable, None, (), {}, False),
+    "island": (_seam_grid, _seam_island, _island_links, (), {}, False),
+    "drained": (
+        _seam_grid, _seam_drained, None, ("g1_1", "g2_1"), {}, False,
+    ),
+    "mine": (_seam_grid, _seam_mine, None, (), {}, False),
+    "other_classes": (_seam_grid, _seam_other_classes, None, (), {}, False),
+    "v4_off": (
+        _seam_grid, _seam_anycast, None, (), {"enable_v4": False}, False,
+    ),
+    "lfa": (
+        _seam_grid, _seam_anycast, None, (), {"compute_lfa_paths": True},
+        False,
+    ),
+    "two_areas": (_seam_grid, _seam_two_areas, None, ("g3_3",), {}, True),
+}
+
+
+def _seam_network(case):
+    network, extra, extra_links, drained, solver_kw, second_area = (
+        SEAM_CASES[case]
+    )
+    me, dbs = network()
+    ps = _Adverts()
+    _every_node(dbs, ps)
+    if extra is not None:
+        extra(dbs, ps)
+    if extra_links is not None:
+        dbs.update(extra_links())
+    ls = LinkState("0")
+    for node, db in dbs.items():
+        if node in drained and not second_area:
+            db = dataclasses.replace(db, is_overloaded=True)
+        ls.update_adjacency_database(db)
+    als = {"0": ls}
+    if second_area:
+        # an area that does not hold my node: its say on who is drained
+        # counts, and a prefix announced there too goes one by one
+        other = LinkState("1")
+        for db in build_adj_dbs(
+            [("far_a", "far_b", 1), ("far_b", "g2_2", 1), ("g3_3", "far_a", 1)],
+            area="1",
+        ).values():
+            if db.this_node_name in drained:
+                db = dataclasses.replace(db, is_overloaded=True)
+            other.update_adjacency_database(db)
+        als["1"] = other
+        _advertise(ps, "g3_3", [PrefixEntry(IpPrefix("10.252.0.0/16"))])
+        _advertise(ps, "g1_1", [PrefixEntry(IpPrefix("10.252.0.0/16"))])
+    return me, als, ps.prefix_state(), solver_kw
+
+
+def _moved(solver, before):
+    return {
+        name: solver.counters.get(name, 0) - before.get(name, 0)
+        for name in SEAM_COUNTERS + (TABLE_ROUTES, GENERIC_ROUTES)
+    }
+
+
+class TestPluralSeam:
+    """`build_unicast_routes` against a loop of `build_unicast_route` on
+    the same backend and against the CPU oracle on the same LSDB: the same
+    entries in the same order, the same counters bumped as often."""
+
+    @pytest.mark.parametrize("case", sorted(SEAM_CASES))
+    def test_batch_equals_one_by_one_and_the_oracle(self, case):
+        me, als, ps, solver_kw = _seam_network(case)
+        pairs = list(ps.prefixes.items())
+
+        together = TpuSpfSolver(me, **solver_kw)
+        before = dict(together.counters)
+        got = {}
+        together.build_unicast_routes(got, me, pairs, als, ps)
+        together.sync_counters(als)
+        got_moved = _moved(together, before)
+
+        singly = TpuSpfSolver(me, **solver_kw)
+        before = dict(singly.counters)
+        want = {}
+        for prefix, prefix_entries in pairs:
+            singly.build_unicast_route(
+                want, me, prefix, prefix_entries, als, ps
+            )
+        singly.sync_counters(als)
+
+        oracle_solver = SpfSolver(me, **solver_kw)
+        oracle = {}
+        oracle_solver.build_unicast_routes(oracle, me, pairs, als, ps)
+
+        assert list(got) == list(want) == list(oracle)
+        for prefix, entry in want.items():
+            for other in (got[prefix], oracle[prefix]):
+                assert other == entry, prefix
+                assert other.best_area == entry.best_area, prefix
+                assert other.best_prefix_entry is entry.best_prefix_entry
+            assert got[prefix].nexthops is not entry.nexthops
+        assert got_moved == _moved(singly, before)
+        for name in SEAM_COUNTERS:
+            assert got_moved[name] == oracle_solver.counters.get(name, 0), name
+        # the cases are what they say: routes came out, and the counters
+        # they are about moved
+        assert got
+        if case in ("unreachable", "island"):
+            assert got_moved["decision.no_route_to_prefix"] == 1
+        if case == "v4_off":
+            assert got_moved["decision.skipped_unicast_route"] > 16
+        if case == "other_classes":
+            assert got_moved["decision.skipped_unicast_route"] == 1
+            assert got_moved["decision.incompatible_forwarding_type"] == 1
+            assert got_moved["decision.missing_loopback_addr"] == 1
+        # one read for all the plain prefixes, and one for each prefix
+        # that went one by one and reached the table (the BGP prefix with
+        # a loopback and the IP / SR_MPLS one; the prefix of two areas);
+        # none with LFA on
+        one_by_one = {"other_classes": 2, "two_areas": 1}.get(case, 0)
+        reads = together.counters[TABLE_READS]
+        assert reads == (0 if case == "lfa" else 1 + one_by_one)
+        if case != "lfa":
+            assert singly.counters[TABLE_READS] > reads
+
+    def test_drained_announcers_are_dropped_unless_all_are(self):
+        me, als, ps, _ = _seam_network("drained")
+        got = {}
+        TpuSpfSolver(me).build_unicast_routes(
+            got, me, ps.prefixes.items(), als, ps
+        )
+
+        def toward(prefix):
+            entry = got[IpPrefix(prefix)]
+            return (
+                {nh.metric for nh in entry.nexthops},
+                ps.prefixes[IpPrefix(prefix)],
+                entry.best_prefix_entry,
+            )
+
+        # alone: the drained announcer keeps its route
+        metrics, _, _ = toward("10.220.0.0/16")
+        assert metrics == {2}
+        # beside healthy g3_3 (distance 6): the route goes there, and the
+        # best entry stays the smallest reachable name's, drained g1_1's
+        metrics, adverts, best = toward("10.221.0.0/16")
+        assert metrics == {6} and best is adverts["g1_1"]["0"]
+        # both drained: the nearest of them
+        metrics, _, _ = toward("10.222.0.0/16")
+        assert metrics == {2}
+
+    def test_policy_rewrites_one_entry_and_leaves_its_siblings(self):
+        me, als, ps, _ = _seam_network("fabric")
+        solver = TpuSpfSolver(me)
+        plain = SpfSolver(me).build_route_db(me, als, ps)
+        # a route over every up-link, as every other pod's racks have
+        victim = max(
+            plain.unicast_entries,
+            key=lambda p: len(plain.unicast_entries[p].nexthops),
+        )
+
+        def policy(entry):
+            if entry.prefix == victim:
+                entry.nexthops.clear()
+
+        db, _, _ = DeltaRouteBuilder(solver).build(
+            me, als, ps, None, force_full=True, policy_fn=policy
+        )
+        assert db.unicast_entries[victim].nexthops == set()
+        wide = plain.unicast_entries[victim].nexthops
+        siblings = [
+            prefix
+            for prefix, entry in plain.unicast_entries.items()
+            if entry.nexthops == wide and prefix != victim
+        ]
+        assert siblings
+        for prefix in siblings:
+            assert db.unicast_entries[prefix].nexthops == wide, prefix
+        table = solver._solves[("0", me)][1].next_hop_table()
+        assert frozenset(wide) in set(table.unicast_sets.values())
+
+    def test_a_delta_build_asks_the_table_once_for_its_prefixes(self):
+        edges = [("a", "b", 1), ("b", "c", 1), ("c", "d", 1), ("a", "d", 9)]
+        h = DeltaHarness(
+            edges, "a", {"b": [PFXS[0]], "c": [PFXS[1]], "d": [PFXS[2]]}
+        )
+        set_metric(h.dbs, h.ls, "b", "c", 8)  # c and d move: two columns
+        before = dict(h.solver.counters)
+        assert h.step()
+        moved = _moved(h.solver, before)
+        reads = h.solver.counters[TABLE_READS] - before[TABLE_READS]
+        # unicast and label route of every changed column: the labels'
+        # reads one each, the prefixes' one together
+        assert moved[TABLE_ROUTES] == 4
+        assert reads == 2 + 1
+
+    def test_a_delta_build_keeps_the_entries_that_changed_after_the_policy(self):
+        edges = [("a", "b", 1), ("b", "c", 1), ("c", "d", 1), ("a", "d", 9)]
+        dbs = build_adj_dbs(edges)
+        ls = LinkState("0")
+        for db in dbs.values():
+            ls.update_adjacency_database(db)
+        als = {"0": ls}
+        ps = make_prefix_state(
+            {"b": [PFXS[0]], "c": [PFXS[1]], "d": [PFXS[2], PFXS[3]]}
+        )
+        emptied, kept, moved, gone = (IpPrefix(p) for p in PFXS)
+
+        def policy(entry):
+            if entry.prefix == emptied:
+                entry.nexthops.clear()
+
+        builder = DeltaRouteBuilder(TpuSpfSolver("a"))
+        db, _, _ = builder.build(
+            "a", als, ps, None, force_full=True, policy_fn=policy
+        )
+        assert db.unicast_entries[emptied].nexthops == set()
+        set_metric(dbs, ls, "c", "d", 3)  # d's column moves, c's does not
+        ps.update_prefix_database(
+            PrefixDatabase("d", [PrefixEntry(moved)], area="0")
+        )
+        new_db, update, used = builder.build(
+            "a", als, ps, db, dirty_prefixes={emptied, kept, gone},
+            policy_fn=policy,
+        )
+        assert used
+        # rebuilt: four prefixes; changed: one; withdrawn: one. The
+        # policy's entry was emptied again before it was compared
+        assert [e.prefix for e in update.unicast_routes_to_update] == [moved]
+        assert update.unicast_routes_to_delete == [gone]
+        assert new_db.unicast_entries[emptied] is db.unicast_entries[emptied]
+        assert new_db.unicast_entries[kept] is db.unicast_entries[kept]
+        assert {nh.metric for nh in new_db.unicast_entries[moved].nexthops} == {5}
