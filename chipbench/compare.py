@@ -1,18 +1,29 @@
 """The comparison that decides `correct`.
 
 It reads what the timed path produced, where it ends: the platform agent's
-log of programming calls and its table. It replays the window's events on
-the plain LSDB copy and asks the reference (reference.py) what the vantage
-had to hold after each. Every comparison is exact, so every limit is 0.
+log of programming calls and its tables. It replays the window's events on
+the plain LSDB copy and asks the reference that the configuration names
+(its `"reference"`: a module of chipbench/, `reference` where it names
+none) what the vantage had to hold after each. Every comparison is exact,
+so every limit is 0.
+
+A table is the plain form of what the agent holds. Unicast: `{prefix:
+frozenset((address, interface, metric, push))}`, `push` the PUSH labels of
+the next hop's MPLS action, `()` where it has none. MPLS: `{top label:
+frozenset((address, interface, action, labels))}`, `labels` the PUSH
+labels, the SWAP label, or `()`. `Tables` is the pair.
 
 Numbers compared (each returned beside its limit):
 
-  table_mismatches   prefixes of the agent's final table whose next-hop set
+  table_mismatches   prefixes of the agent's final unicast table whose
+                     next-hop set differs from the reference's, either way
+  mpls_table_mismatches  labels of the agent's final MPLS table whose entry
                      differs from the reference's, either way
   event_mismatches   verified events whose programmed routes differ from
-                     what the event had to change: a route the reference
-                     changed that was not programmed (or not deleted), or a
-                     programmed route that is not the reference's
+                     what the event had to change: a route or label route
+                     the reference changed that was not programmed (or not
+                     deleted), or a programmed one that is not the
+                     reference's
   events_unprogrammed  events after which nothing reached the agent though
                      the reference says a route changed
   events_not_one_update  events of the window for which Decision did not
@@ -22,7 +33,10 @@ Numbers compared (each returned beside its limit):
                      inside the window
 
 `agent_events` is, per event of the window, the slice of the agent's log
-that the event produced: a list of (call name, payload) in order.
+that the event produced: a list of (call name, payload) in order. Label
+calls are the configuration's to allow: with segment routing off (the
+daemon's `enable_segment_routing`, off by default) an mpls call fails the
+event; a full sync inside the window fails it either way.
 """
 
 from __future__ import annotations
@@ -30,10 +44,11 @@ from __future__ import annotations
 import random
 from typing import Callable, Dict, List, Sequence, Tuple
 
-from chipbench.reference import Table
+from chipbench.reference import MplsTable, Table, Tables
 
 LIMITS = {
     "table_mismatches": 0,
+    "mpls_table_mismatches": 0,
     "event_mismatches": 0,
     "events_unprogrammed": 0,
     "events_not_one_update": 0,
@@ -41,67 +56,114 @@ LIMITS = {
 }
 
 
+def _labels(action) -> Tuple[str, tuple]:
+    """An MPLS action -> (its name, the labels it pushes or swaps in)."""
+    name = action.action.name
+    if name == "PUSH":
+        return name, tuple(action.push_labels)
+    return name, (action.swap_label,) if name == "SWAP" else ()
+
+
 def routes_as_table(routes) -> Table:
-    """The program's UnicastRoute objects -> the reference's plain form."""
+    """The program's UnicastRoute objects -> the plain form."""
     return {
         str(route.dest): frozenset(
-            (nh.address, nh.iface, nh.metric) for nh in route.nexthops
+            (nh.address, nh.iface, nh.metric,
+             () if nh.mpls_action is None else _labels(nh.mpls_action)[1])
+            for nh in route.nexthops
         )
         for route in routes
     }
 
 
-def table_mismatches(got: Table, want: Table) -> List[str]:
+def mpls_routes_as_table(routes) -> MplsTable:
+    """The program's MplsRoute objects -> the plain form."""
+    return {
+        route.top_label: frozenset(
+            (nh.address, nh.iface, *_labels(nh.mpls_action))
+            for nh in route.nexthops
+        )
+        for route in routes
+    }
+
+
+def table_mismatches(got: dict, want: dict) -> list:
+    """The keys (prefixes or labels) whose entry differs, either way."""
     return sorted(
         p for p in set(got) | set(want) if got.get(p) != want.get(p)
     )
 
 
-def apply_calls(calls: Sequence[Tuple[str, list]]) -> Tuple[Table, set]:
-    """One event's programming calls -> (routes set, prefixes deleted), the
-    later call winning where two name the same prefix."""
-    programmed: Table = {}
-    deleted: set = set()
+# a programming call -> (0 unicast or 1 MPLS, whether it adds)
+_DELTA_CALLS = {
+    "add_unicast_routes": (0, True),
+    "delete_unicast_routes": (0, False),
+    "add_mpls_routes": (1, True),
+    "delete_mpls_routes": (1, False),
+}
+
+
+def apply_calls(
+    calls: Sequence[Tuple[str, list]], segment_routing: bool = False
+) -> Tuple[Tables, Tuple[set, set]]:
+    """One event's programming calls -> ((routes set, label routes set),
+    (prefixes deleted, labels deleted)), the later call winning where two
+    name the same prefix or label."""
+    programmed: Tables = ({}, {})
+    deleted: Tuple[set, set] = (set(), set())
     for name, payload in calls:
-        if name == "add_unicast_routes":
-            for prefix, nexthops in routes_as_table(payload).items():
-                programmed[prefix] = nexthops
-                deleted.discard(prefix)
-        elif name == "delete_unicast_routes":
-            for prefix in map(str, payload):
-                programmed.pop(prefix, None)
-                deleted.add(prefix)
-        elif name == "sync_fib":
+        if name in ("sync_fib", "sync_mpls_fib"):
             raise ValueError("a full sync inside the window: not a delta")
-        else:
-            # the configurations state "no mpls route is programmed"; one
-            # that enables labels has to bring their comparison with it
+        if name not in _DELTA_CALLS:
             raise ValueError(f"{name}: a call that no configuration allows")
+        side, adds = _DELTA_CALLS[name]
+        if side and not segment_routing:
+            raise ValueError(
+                f"{name}: a call that a configuration with segment routing "
+                "off does not allow"
+            )
+        if adds:
+            as_table = mpls_routes_as_table if side else routes_as_table
+            for key, nexthops in as_table(payload).items():
+                programmed[side][key] = nexthops
+                deleted[side].discard(key)
+        else:
+            for key in payload if side else map(str, payload):
+                programmed[side].pop(key, None)
+                deleted[side].add(key)
     return programmed, deleted
 
 
 def event_is_wrong(
-    calls: Sequence[Tuple[str, list]], changed: Sequence[str], after: Table
+    calls: Sequence[Tuple[str, list]],
+    changed: Tuple[Sequence, Sequence],
+    after: Tables,
+    segment_routing: bool = False,
 ) -> str:
     """'' when the event's programming is exactly what moves the vantage's
-    table to `after`, modulo routes re-programmed unchanged; else a short
-    description of the first difference. `changed` is the prefixes whose
-    route the event changes in the reference."""
+    tables to `after`, modulo routes re-programmed unchanged; else a short
+    description of the first difference. `changed` is the prefixes and the
+    labels whose route the event changes in the reference."""
     try:
-        programmed, deleted = apply_calls(calls)
+        programmed, deleted = apply_calls(calls, segment_routing)
     except ValueError as exc:
         return str(exc)
-    for prefix, nexthops in programmed.items():
-        if after.get(prefix) != nexthops:
-            return f"{prefix} programmed {sorted(nexthops)}, reference {sorted(after.get(prefix, ()))}"
-    for prefix in deleted:
-        if prefix in after:
-            return f"{prefix} deleted, reference holds it"
-    for prefix in changed:
-        if prefix in after and prefix not in programmed:
-            return f"{prefix} changed in the reference, not programmed"
-        if prefix not in after and prefix not in deleted:
-            return f"{prefix} gone in the reference, not deleted"
+    for side in (0, 1):
+        want, done, gone = after[side], programmed[side], deleted[side]
+        for key, nexthops in done.items():
+            if want.get(key) != nexthops:
+                return (
+                    f"{key} programmed {sorted(nexthops, key=repr)}, "
+                    f"reference {sorted(want.get(key, ()), key=repr)}"
+                )
+        for key in gone:
+            if key in want:
+                return f"{key} deleted, reference holds it"
+        for key in changed[side]:
+            if key in want and key not in done:
+                return f"{key} changed in the reference, not programmed"
+            if key not in want and key not in gone:
+                return f"{key} gone in the reference, not deleted"
     return ""
 
 
@@ -117,29 +179,34 @@ def choose_events(n_events: int, verify_events: int, seed: int) -> List[int]:
     return sorted(chosen)
 
 
+def segment_routing(config: dict) -> bool:
+    """Whether the configuration's daemon programs label routes."""
+    return bool(config["daemon"].get("enable_segment_routing", False))
+
+
 def replay_reference(
     config: dict, params: dict, seed: int, n_warm: int, n_events: int,
     verify: List[int],
-) -> Dict[int, Table]:
+) -> Dict[int, Tables]:
     """The reference's side of a run: an LSDB of its own from the
     configuration, the mix's events replayed from the seed, and the
-    vantage's table after `i` events of the window for every `i` that the
+    vantage's tables after `i` events of the window for every `i` that the
     comparison reads (0: at the window's start)."""
     import importlib
 
     from chipbench.lsdb import Lsdb
-    from chipbench.reference import Reference
     from chipbench.topologies import build_edges
 
     kind = importlib.import_module(f"chipbench.traffic_kinds.{params['kind']}")
+    module = importlib.import_module(f"chipbench.{config.get('reference', 'reference')}")
     needed = {i for v in verify for i in (v, v + 1)} | {n_events}
     lsdb = Lsdb(build_edges(config["topology"]))
-    reference = Reference(lsdb, config["vantage"])
+    reference = module.Reference(lsdb, config["vantage"], config)
     events = kind.generate(params, seed)
-    at_index: Dict[int, Table] = {}
+    at_index: Dict[int, Tables] = {}
     for i in range(n_warm + n_events + 1):
         if i - n_warm in needed:
-            at_index[i - n_warm] = reference.table()
+            at_index[i - n_warm] = reference.tables()
         keys = next(events).apply(lsdb)
         reference.refresh(key.split(":", 1)[1] for key in keys)
     return at_index
@@ -147,30 +214,36 @@ def replay_reference(
 
 def compare(
     *,
-    final_table: Table,
+    final: Tables,
     agent_events: List[Sequence[Tuple[str, list]]],
-    tables: Callable[[int], Table],
+    tables: Callable[[int], Tables],
     verify: List[int],
     updates_per_event: Sequence[int],
     counter_moves: Dict[str, int],
+    segment_routing: bool = False,
 ) -> Tuple[bool, Dict[str, dict], List[str]]:
-    """`tables(i)` is the reference's table after `i` events of the window
-    (0: at the window's start); `updates_per_event`, how many route
-    updates Decision published for each. Returns (correct, numbers beside
-    limits, notes for standard error)."""
+    """`final` is the agent's tables at the window's end; `tables(i)` the
+    reference's after `i` events of the window (0: at the window's start);
+    `updates_per_event`, how many route updates Decision published for
+    each. Returns (correct, numbers beside limits, notes for standard
+    error)."""
     notes: List[str] = []
     n_events = len(agent_events)
-    bad_prefixes = table_mismatches(final_table, tables(n_events))
+    want = tables(n_events)
+    bad_prefixes = table_mismatches(final[0], want[0])
     if bad_prefixes:
         notes.append(f"final table differs at {bad_prefixes[:8]}")
+    bad_labels = table_mismatches(final[1], want[1])
+    if bad_labels:
+        notes.append(f"final MPLS table differs at labels {bad_labels[:8]}")
     wrong = unprogrammed = 0
     for i in verify:
-        after = tables(i + 1)
-        changed = table_mismatches(tables(i), after)
-        if not agent_events[i] and changed:
+        before, after = tables(i), tables(i + 1)
+        changed = tuple(table_mismatches(before[s], after[s]) for s in (0, 1))
+        if not agent_events[i] and any(changed):
             unprogrammed += 1
             continue
-        why = event_is_wrong(agent_events[i], changed, after)
+        why = event_is_wrong(agent_events[i], changed, after, segment_routing)
         if why:
             wrong += 1
             if wrong <= 4:
@@ -183,6 +256,7 @@ def compare(
         notes.append(f"counters moved in the window: {moved}")
     got = {
         "table_mismatches": len(bad_prefixes),
+        "mpls_table_mismatches": len(bad_labels),
         "event_mismatches": wrong,
         "events_unprogrammed": unprogrammed,
         "events_not_one_update": len(not_one),

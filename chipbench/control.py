@@ -3,14 +3,18 @@ place with one stated guarantee broken. It has to come out not correct.
 
 The system states no precision, so the control breaks a guarantee of the
 configuration ("every route the agent holds equals the reference's on the
-same LSDB"), in the two ways that would tempt a later change:
+same LSDB"), in the ways that would tempt a later change:
 
-  stale      each event is answered from the LSDB as it was one event
-             earlier (an acknowledgement before the solve has the event):
-             a stale answer where a current one is promised
-  first_hop  every next-hop set is cut to one member (the equal-cost
-             multipath extraction left out): an approximate answer where
-             an exact one is promised
+  stale       each event is answered from the LSDB as it was one event
+              earlier (an acknowledgement before the solve has the event):
+              a stale answer where a current one is promised
+  first_hop   every next-hop set, of a route or a label route, is cut to
+              one member (the equal-cost multipath extraction left out): an
+              approximate answer where an exact one is promised
+  push_stack  every push stack loses its bottom label (the destination's
+              own, or its prepend label): a label stack built one node
+              short. Only where the configuration's prefixes push labels
+              (`prefix_forwarding` SR_MPLS); elsewhere every stack is empty
 
     python3 -m chipbench.control --workload <cell> --seed <n> --events <n>
 
@@ -23,40 +27,75 @@ from __future__ import annotations
 
 import argparse
 import collections
+import enum
 import json
 import sys
 from typing import Dict, List, Tuple
 
 from chipbench import compare
-from chipbench.reference import Table
+from chipbench.reference import Tables
 
-BREAKAGES = ("stale", "first_hop")
+BREAKAGES = ("stale", "first_hop")  # every configuration's
+LABEL_BREAKAGES = ("push_stack",)  # a configuration's whose prefixes push labels
 
 
-# a programmed route in the shape compare.routes_as_table reads
+def breakages(config: dict) -> Tuple[str, ...]:
+    """The breakages that can show on the configuration."""
+    pushes = config.get("prefix_forwarding", {}).get("type") == "SR_MPLS"
+    return BREAKAGES + (LABEL_BREAKAGES if pushes else ())
+
+
+# programmed routes in the shapes compare.py reads
 _Route = collections.namedtuple("_Route", "dest nexthops")
-_NextHop = collections.namedtuple("_NextHop", "address iface metric")
+_MplsRoute = collections.namedtuple("_MplsRoute", "top_label nexthops")
+_NextHop = collections.namedtuple("_NextHop", "address iface metric mpls_action")
+_Action = collections.namedtuple("_Action", "action push_labels swap_label")
+_Code = enum.Enum("_Code", "PUSH SWAP PHP POP_AND_LOOKUP")
 
 
-def _break_table(table: Table, breakage: str) -> Table:
-    if breakage != "first_hop":
-        return table
-    return {p: frozenset(sorted(nhs)[:1]) for p, nhs in table.items()}
+def _unicast_hop(address, iface, metric, push) -> _NextHop:
+    action = _Action(_Code.PUSH, push, None) if push else None
+    return _NextHop(address, iface, metric, action)
 
 
-def _calls(before: Table, after: Table) -> List[Tuple[str, list]]:
-    """The programming calls that move an agent from `before` to `after`."""
-    adds = [
-        _Route(p, [_NextHop(*nh) for nh in nhs])
-        for p, nhs in after.items()
-        if before.get(p) != nhs
-    ]
-    deletes = [p for p in before if p not in after]
+def _label_hop(address, iface, action, labels) -> _NextHop:
+    swap = labels[0] if action == "SWAP" else None
+    push = labels if action == "PUSH" else ()
+    return _NextHop(address, iface, 0, _Action(_Code[action], push, swap))
+
+
+def _break_tables(tables: Tables, breakage: str) -> Tables:
+    if breakage == "first_hop":
+        return tuple(
+            {key: frozenset(sorted(nhs)[:1]) for key, nhs in table.items()}
+            for table in tables
+        )
+    if breakage == "push_stack":
+        unicast, mpls = tables
+        return {
+            p: frozenset((*nh[:3], nh[3][1:]) for nh in nhs)
+            for p, nhs in unicast.items()
+        }, mpls
+    return tables
+
+
+def _calls(before: Tables, after: Tables) -> List[Tuple[str, list]]:
+    """The programming calls that move an agent from `before` to `after`,
+    in Fib's order."""
     calls = []
-    if deletes:
-        calls.append(("delete_unicast_routes", deletes))
-    if adds:
-        calls.append(("add_unicast_routes", adds))
+    for side, (route, hop) in enumerate(((_Route, _unicast_hop), (_MplsRoute, _label_hop))):
+        was, now = before[side], after[side]
+        deletes = [key for key in was if key not in now]
+        adds = [
+            route(key, [hop(*nh) for nh in nhs])
+            for key, nhs in now.items()
+            if was.get(key) != nhs
+        ]
+        kind = "mpls" if side else "unicast"
+        if deletes:
+            calls.append((f"delete_{kind}_routes", deletes))
+        if adds:
+            calls.append((f"add_{kind}_routes", adds))
     return calls
 
 
@@ -71,19 +110,20 @@ def control_run(cell: dict, seed: int, n_events: int, breakage: str):
     )
     lag = 1 if breakage == "stale" else 0
 
-    def answered(i: int) -> Table:  # what the control holds after i events
-        return _break_table(tables[max(i - lag, 0)], breakage)
+    def answered(i: int) -> Tables:  # what the control holds after i events
+        return _break_tables(tables[max(i - lag, 0)], breakage)
 
     agent_events = [
         _calls(answered(i), answered(i + 1)) for i in range(n_events)
     ]
     correct, compared, notes = compare.compare(
-        final_table=answered(n_events),
+        final=answered(n_events),
         agent_events=agent_events,
         tables=tables.__getitem__,
         verify=verify,
         updates_per_event=[1] * n_events,
         counter_moves={},
+        segment_routing=compare.segment_routing(config),
     )
     return correct, compared, notes
 
@@ -98,7 +138,7 @@ def main(argv=None) -> int:
     args = parser.parse_args(argv)
     cell = resolve_cell(args.workload)
     out: Dict[str, dict] = {}
-    for breakage in BREAKAGES:
+    for breakage in breakages(cell["config_data"]):
         correct, compared, _ = control_run(cell, args.seed, args.events, breakage)
         out[breakage] = {
             "correct": correct,

@@ -2,7 +2,8 @@
 
 `Lsdb` is what the reference reads: node names, a metric per directed
 adjacency, which links are up, one prefix per node and whether the node
-announces it. It imports nothing of the program. The
+announces it, one MPLS node label per node. It imports nothing of the
+program. The
 encoding that the daemon is fed (the program's own AdjacencyDatabase /
 PrefixDatabase types, serialized as KvStore values) is `WireEncoder`, kept
 apart so that the reference never sees a program object.
@@ -17,11 +18,13 @@ from __future__ import annotations
 
 import base64
 import zlib
-from typing import Dict, List, Set, Tuple
+from typing import Dict, List, Optional, Set, Tuple
 
 from chipbench.topologies import Edge
 
 AREA = "0"
+# a configuration's `prefix_forwarding` where it states none
+PREFIX_FORWARDING = {"type": "IP", "algorithm": "SP_ECMP"}
 
 
 def _crc(text: str) -> int:
@@ -58,6 +61,10 @@ class Lsdb:
         self.prefix_of: Dict[str, str] = {
             node: f"10.{i // 256}.{i % 256}.0/24"
             for i, node in enumerate(self.nodes)
+        }
+        # every node's MPLS node label, in its adjacency database
+        self.label_of: Dict[str, int] = {
+            node: i + 100 for i, node in enumerate(self.nodes)
         }
         self.down: Set[Tuple[str, str]] = set()  # directed: a down link both ways
         self.withdrawn: Set[str] = set()  # nodes that do not announce their /24
@@ -117,10 +124,12 @@ class WireEncoder:
     entry, which Decision reads as the withdrawal.
     """
 
-    def __init__(self, lsdb: Lsdb) -> None:
+    def __init__(self, lsdb: Lsdb, prefix_forwarding: Optional[dict] = None) -> None:
         self.lsdb = lsdb
+        # what every PrefixEntry states: a configuration's
+        # `prefix_forwarding`, upstream's default IP / SP_ECMP without one
+        self.forwarding = dict(PREFIX_FORWARDING, **(prefix_forwarding or {}))
         self.versions: Dict[str, int] = {}
-        self._labels = {n: i + 100 for i, n in enumerate(lsdb.nodes)}
         self._adj_bytes: Dict[tuple, str] = {}
 
     def _adj_value(self, node: str) -> str:
@@ -147,18 +156,30 @@ class WireEncoder:
                     for peer, metric in peers.items()
                 ],
                 area=AREA,
-                node_label=self._labels[node],
+                node_label=self.lsdb.label_of[node],
             )
             cached = self._adj_bytes[key] = base64.b64encode(serializer.dumps(db)).decode()
         return cached
 
     def _prefix_value(self, node: str) -> str:
-        from openr_tpu.types import IpPrefix, PrefixDatabase, PrefixEntry
+        from openr_tpu.types import (
+            IpPrefix,
+            PrefixDatabase,
+            PrefixEntry,
+            PrefixForwardingAlgorithm,
+            PrefixForwardingType,
+        )
         from openr_tpu.utils import serializer
 
         entries = (
             [] if node in self.lsdb.withdrawn
-            else [PrefixEntry(IpPrefix(self.lsdb.prefix_of[node]))]
+            else [PrefixEntry(
+                IpPrefix(self.lsdb.prefix_of[node]),
+                forwarding_type=PrefixForwardingType[self.forwarding["type"]],
+                forwarding_algorithm=PrefixForwardingAlgorithm[
+                    self.forwarding["algorithm"]
+                ],
+            )]
         )
         db = PrefixDatabase(node, entries, area=AREA)
         return base64.b64encode(serializer.dumps(db)).decode()

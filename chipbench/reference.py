@@ -12,13 +12,17 @@ no edge, either way, and no first hop; a node that does not announce its
 /24 has no route. It imports nothing of the program and reads only
 `chipbench.lsdb.Lsdb`.
 
-A table is `{prefix: frozenset((address, interface, metric), ...)}`: the
-form in which `compare.py` also reads the platform agent's table.
+A table is `{prefix: frozenset((address, interface, metric), ...)}`.
+`tables()` gives what `compare.py` reads, the form of every reference that
+a configuration may name (its `"reference"`, this module by default): the
+unicast table with each next hop's push stack, `()` here, since SP_ECMP
+over IP pushes no label, and the MPLS table, empty, since segment routing
+is off.
 """
 
 from __future__ import annotations
 
-from typing import Dict, FrozenSet, Iterable, Tuple
+from typing import Dict, FrozenSet, Iterable, List, Optional, Tuple
 
 import numpy as np
 from scipy.sparse import csr_matrix
@@ -28,13 +32,20 @@ from chipbench.lsdb import Lsdb, if_name, nexthop_v4
 
 NextHops = FrozenSet[Tuple[str, str, int]]
 Table = Dict[str, NextHops]
+# compare.py's forms: a unicast next hop (address, interface, metric,
+# push stack); a label route's (address, interface, action, labels)
+MplsTable = Dict[int, FrozenSet[tuple]]
+Tables = Tuple[Table, MplsTable]
 
 
 class Reference:
     """The vantage's table on `lsdb` as it stands; `refresh` after the
     LSDB moved."""
 
-    def __init__(self, lsdb: Lsdb, vantage: str) -> None:
+    def __init__(
+        self, lsdb: Lsdb, vantage: str, config: Optional[dict] = None
+    ) -> None:
+        # `config` is every reference's third argument; this one reads none
         self.lsdb, self.vantage = lsdb, vantage
         self.number = {node: i for i, node in enumerate(lsdb.nodes)}
         # CSR by hand, a row per node in the order of `lsdb.nodes`: `slot`
@@ -71,27 +82,42 @@ class Reference:
     def table(self) -> Table:
         """The prefix of every node that is reachable and announces it, on
         the LSDB as it stands -> its ECMP next-hop set."""
-        me = self.number[self.vantage]
+        return self._table(())
+
+    def tables(self) -> Tables:
+        """`table()` in compare.py's form, and no label route."""
+        return self._table(((),)), {}
+
+    def first_hops(self) -> Tuple[List[str], np.ndarray, np.ndarray]:
+        """(the vantage's up neighbours, its distance to every node by
+        number, member): member[i, d] says that neighbour i is a first hop
+        toward node d, on some shortest path."""
         neighbours = self.lsdb.up_peers(self.vantage)
+        sources = [self.number[self.vantage]] + [self.number[peer] for peer in neighbours]
+        dist = dijkstra(self.graph, directed=True, indices=sources)
+        through = np.fromiter(neighbours.values(), float, len(neighbours))[:, None] + dist[1:]
+        return list(neighbours), dist[0], through == dist[0]
+
+    def _table(self, tail: tuple) -> Table:
+        """`table()`, each next hop's tuple followed by `tail`."""
+        me = self.number[self.vantage]
+        neighbours, dist, member = self.first_hops()
         hops = [self.hop_of[peer] for peer in neighbours]
         silent = {self.number[node] for node in self.lsdb.withdrawn}
-        sources = [me] + [self.number[peer] for peer in neighbours]
-        dist = dijkstra(self.graph, directed=True, indices=sources)
-        # member[i, d]: neighbour i is a first hop toward d. A destination's
-        # set is its column, of any height; equal columns share one frozenset,
-        # keyed by the column's packed bytes and the distance
-        through = np.fromiter(neighbours.values(), float, len(hops))[:, None] + dist[1:]
-        member = through == dist[0]
+        # a destination's set is its column of `member`, of any height;
+        # equal columns share one frozenset, keyed by the column's packed
+        # bytes and the distance
         columns = np.ascontiguousarray(np.packbits(member, axis=0).T)
         sets: Dict[Tuple[bytes, int], NextHops] = {}
         table: Table = {}
-        for node in np.flatnonzero(np.isfinite(dist[0])).tolist():
+        for node in np.flatnonzero(np.isfinite(dist)).tolist():
             if node == me or node in silent:
                 continue
-            key = (columns[node].tobytes(), int(dist[0][node]))
+            key = (columns[node].tobytes(), int(dist[node]))
             if key not in sets:
                 sets[key] = frozenset(
-                    (*hops[i], key[1]) for i in np.flatnonzero(member[:, node])
+                    (*hops[i], key[1], *tail)
+                    for i in np.flatnonzero(member[:, node])
                 )
                 if not sets[key]:
                     raise ValueError(

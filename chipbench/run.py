@@ -17,8 +17,9 @@ it prints is a device number).
            the ctrl write, the event's latency is the agent's last stamp
            for it minus t0. Nothing else runs between events.
   after    histograms and counters; the daemon stops; the plain reference
-           replays the events from the seed and compare.py decides
-           `correct`; the last line of standard output is the result.
+           that the configuration names replays the events from the seed
+           and compare.py decides `correct`; the last line of standard
+           output is the result.
 
 What belongs to one configuration, one traffic mix, one cell or one
 per-layer metric is a file found by its name in BENCHMARK.json:
@@ -194,7 +195,7 @@ async def run_cell(cell: dict, seed: int, seconds: float, trace: bool) -> dict:
     say(f"compile cache {cache_dir}; native/ built")
 
     lsdb = Lsdb(build_edges(config["topology"]))  # the daemon's side
-    wire = WireEncoder(lsdb)
+    wire = WireEncoder(lsdb, config.get("prefix_forwarding"))
     say(f"LSDB {len(lsdb.nodes)} nodes, {lsdb.n_links} links, vantage {me}")
     agent = StampingAgent()
     daemon_config = dict(config["daemon"], node_name=me)
@@ -368,8 +369,13 @@ async def run_cell(cell: dict, seed: int, seconds: float, trace: bool) -> dict:
         hists = await client.call("getHistograms")
         counters1 = await client.call("getCounters")
         cache1 = persistent_cache_counts()
-    final_table = compare.routes_as_table(
-        agent.unicast_routes.get(FIB_CLIENT_OPENR, {}).values()
+    final = (
+        compare.routes_as_table(
+            agent.unicast_routes.get(FIB_CLIENT_OPENR, {}).values()
+        ),
+        compare.mpls_routes_as_table(
+            agent.mpls_routes.get(FIB_CLIENT_OPENR, {}).values()
+        ),
     )
     agent_events = [
         [(name, payload) for _, name, payload in agent.log[a:b]]
@@ -404,17 +410,19 @@ async def run_cell(cell: dict, seed: int, seconds: float, trace: bool) -> dict:
         config, params, seed, n_warm, n_events, verify
     )
     correct, compared, notes = compare.compare(
-        final_table=final_table,
+        final=final,
         agent_events=agent_events,
         tables=at_index.__getitem__,
         verify=verify,
         updates_per_event=win.updates,
         counter_moves=counter_moves,
+        segment_routing=compare.segment_routing(config),
     )
     failed = win.timed_out + compared["served_off_device"]["value"]
     correct = correct and win.timed_out == 0 and n_events > 0
     say(
-        f"reference: {len(at_index)} tables of {len(final_table)} routes, "
+        f"reference: {len(at_index)} tables of {len(final[0])} routes and "
+        f"{len(final[1])} label routes, "
         f"{len(verify)} of {n_events} events verified, "
         f"{time.perf_counter() - t0:.1f}s"
     )

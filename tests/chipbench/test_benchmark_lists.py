@@ -1,8 +1,8 @@
 """`BENCHMARK.json` grows by appending: a new cell goes to the end of
 `workloads` and of every list that reports it, a new metric to the end of
 `per_layer`. What eight tests of PRs 30, 33 and 34 pinned letter for letter
-(conftest.py lists them) is kept here in the form that stays true when a
-later PR appends again: every list begins with what the PR that wrote it
+is kept here, as those tests now keep it too, in the form that stays true
+when a later PR appends again: every list begins with what the PR that wrote it
 left, in its order, and each metric's file reads what its entry says. PR 35's
 own four metrics are held the same way."""
 

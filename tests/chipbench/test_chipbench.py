@@ -211,16 +211,22 @@ def test_control_breaks_a_guarantee_and_comes_out_not_correct(breakage, cell_nam
 
 
 def test_comparison_refuses_split_events_and_calls_no_configuration_allows():
-    table = {"10.0.0.0/24": frozenset({("a", "if", 1)})}
+    tables = ({"10.0.0.0/24": frozenset({("a", "if", 1, ())})}, {})
     ok, compared, _ = compare.compare(
-        final_table=table, agent_events=[[], []], tables=lambda i: table,
+        final=tables, agent_events=[[], []], tables=lambda i: tables,
         verify=[0, 1], updates_per_event=[1, 2], counter_moves={},
     )
     assert not ok and compared["events_not_one_update"]["value"] == 1
-    assert "no configuration allows" in compare.event_is_wrong(
-        [("add_mpls_routes", [])], [], table
+    # with segment routing off, as every configuration of a cell has it
+    assert "segment routing off does not allow" in compare.event_is_wrong(
+        [("add_mpls_routes", [])], ([], []), tables
     )
-    assert "full sync" in compare.event_is_wrong([("sync_fib", [])], [], table)
+    assert "no configuration allows" in compare.event_is_wrong(
+        [("add_static_routes", [])], ([], []), tables
+    )
+    for call in ("sync_fib", "sync_mpls_fib"):
+        for on in (False, True):
+            assert "full sync" in compare.event_is_wrong([(call, [])], ([], []), tables, on)
     # a sample keeps the last event and is drawn from the seed
     assert compare.choose_events(5, 10, 1) == [0, 1, 2, 3, 4]
     sample = compare.choose_events(500, 50, 2**31 + 3)

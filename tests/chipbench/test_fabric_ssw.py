@@ -162,12 +162,16 @@ def test_the_cell_reports_the_warm_paths_metrics_and_the_five_new_ones():
     assert reported <= rack
     e2e = {m["name"] for m in cell["end_to_end"]}
     assert {"event_to_fib_ms.p50", "events_per_s", "setup_s"} <= e2e
-    # the cell is the last of its list wherever an older metric gained it:
-    # an entry put first or in the middle reads as a change to what was there
+    # the cell stands behind every older cell wherever an older metric
+    # gained it, and only cells appended after it stand behind it: an entry
+    # put first or in the middle reads as a change to what was there
+    order = [w["name"] for w in bench["workloads"]]
     for m in bench["end_to_end"] + bench["per_layer"]:
         cells = m.get("workloads", [])
         if CELL in cells and m["name"] not in NEW_METRICS:
-            assert cells[-1] == CELL, m["name"]
+            at = cells.index(CELL)
+            assert all(order.index(c) < order.index(CELL) for c in cells[:at]), m["name"]
+            assert all(order.index(c) > order.index(CELL) for c in cells[at + 1:]), m["name"]
 
 
 def test_every_new_metric_file_is_named_by_benchmark_json():
@@ -186,11 +190,12 @@ def _context(counters0=None, counters1=None, gauges=None):
 @pytest.mark.parametrize("name", sorted(NEW_METRICS))
 def test_entry_and_file_read_the_programs_gauge_or_counter(name):
     layer, unit, source, older_cells = NEW_METRICS[name]
-    entry = next(m for m in _bench()["per_layer"] if m["name"] == name)
+    entry = dict(next(m for m in _bench()["per_layer"] if m["name"] == name))
+    # the list the metric came with, and whatever later cells appended
+    assert entry.pop("workloads")[: 1 + len(older_cells)] == [CELL] + older_cells
     assert entry == {
         "name": name, "unit": unit, "better": "lower", "source": "program_counter",
         "layer": layer, "moves": "event_to_fib_ms.p50",
-        "workloads": [CELL] + older_cells,
     }
     spec = bench_run.load_json("metrics", name + ".json")
     assert spec == {"name": name, "layer": layer, "unit": unit,

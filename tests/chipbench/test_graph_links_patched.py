@@ -28,13 +28,15 @@ def _context(counters0, counters1):
 def test_entry_and_file_read_the_programs_counter_per_event():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as fh:
         bench = json.load(fh)
-    assert bench["per_layer"][-1]["name"] == NAME  # appended, nothing moved
-    entry = bench["per_layer"][-1]
+    names = [m["name"] for m in bench["per_layer"]]
+    assert names.index(NAME) == 45  # appended as the 46th, nothing moved
+    entry = bench["per_layer"][45]
     assert (entry["source"], entry["layer"]) == (
         "program_counter", "supervised solve",
     )
     assert (entry["better"], entry["moves"]) == ("higher", "events_per_s")
-    assert entry["workloads"] == [w["name"] for w in bench["workloads"]]
+    # the list the metric came with, and whatever later cells appended
+    assert entry["workloads"][:5] == [w["name"] for w in bench["workloads"]][:5]
     spec = bench_run.load_json("metrics", NAME + ".json")
     assert {k: spec[k] for k in ("name", "layer", "unit", "moves")} == {
         k: entry[k] for k in ("name", "layer", "unit", "moves")

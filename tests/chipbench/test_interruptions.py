@@ -73,9 +73,10 @@ def test_the_twelve_follow_label_sets_made_and_nothing_before_them_moved():
     assert names.index("label_sets_made_per_event") == 50
     assert names[51:63] == NAMES
     assert [w["name"] for w in bench["workloads"]][: len(CELLS)] == CELLS
-    # gc_pause_ms.max stays beside them, as it was, until a benchmark PR retires it
+    # gc_pause_ms.max stays beside them on the one cell whose window always
+    # holds full collections; the three whose window may hold none left it
     pause = next(m for m in bench["per_layer"] if m["name"] == "gc_pause_ms.max")
-    assert pause["workloads"][:4] == CELLS[:4]
+    assert pause["workloads"] == ["fabric9976.own_link_flaps"]
     # none moves the p95, which two cells do not report
     assert {m["moves"] for m in bench["per_layer"][51:63]} == {P50, RATE}
 

@@ -175,7 +175,8 @@ def test_the_cell_joins_the_grids_lists_and_not_gc_pause():
         if m["name"] in NEW_METRICS:
             continue
         cells = m["workloads"]
-        expected = "grid10000.metric_flaps" in cells and m["name"] != "gc_pause_ms.max"
+        # gc_pause_ms.max lists neither: a window may hold no full collection
+        expected = "grid10000.metric_flaps" in cells
         assert (CELL in cells) == expected, m["name"]
         if CELL in cells:
             assert cells[-1] == CELL, m["name"]
@@ -189,7 +190,7 @@ def test_the_cell_joins_the_grids_lists_and_not_gc_pause():
     assert set(NEW_METRICS) | {"relax_roofline", "solve_rows", "solve_warm_ms.avg",
                                "delta_route_build_share", "compiles_in_window"} <= reported
     grid = {m["name"] for m in bench_run.resolve_cell("grid10000.metric_flaps")["per_layer"]}
-    assert reported == grid - {"gc_pause_ms.max"}
+    assert reported == grid and "gc_pause_ms.max" not in reported
     # four configurations and seven cells, all one chip
     assert len(bench["configs"]) == 4 and len(bench["workloads"]) == 7
     assert {w["chips"] for w in bench["workloads"]} == {1}
