@@ -12,10 +12,15 @@ node-label routes the device delta names and emits the
 propagation (PAPERS.md, arxiv 1808.06893) on the host side.
 
 Soundness: a route entry from `my_node_name`'s perspective is a function of
-(a) the distance columns of its announcers / label targets, (b) my own
-out-link attributes (the nexthop triangle's weight column, link up/down,
-addresses), (c) the transit/overload mask, (d) node labels, and (e) the
-prefix advertisements themselves. The device delta covers (a) exactly; the
+(a) my own distance row and the first-hop mask at the columns of its
+announcers / label targets (under LFA, every changed column: the
+alternates read the neighbours' rows), (b) my own out-link attributes (the
+nexthop triangle's weight column, link up/down, addresses), (c) the
+transit/overload mask, (d) node labels, and (e) the prefix advertisements
+themselves. The device delta covers (a) exactly: the mirror patch compares
+my row and the mask, after the overload rule, before and after each solve
+and names the columns where either moved, or every changed column where
+the old values are not in hand (`_AreaSolve._finish_delta`); the
 solver refuses to produce a delta for events touching (b) or (c)
 (`_AreaSolve._finish_delta` qualification), Decision forces the full path
 for (d) and batches that structurally change the LSDB, and Decision feeds
@@ -58,9 +63,10 @@ class _ChangedRoutes:
     """Where the solver writes a partial rebuild's unicast entries. An
     entry is compared with the previous db's where it arrives, after the
     policy hook, and kept only if it differs: DeltaPath rebuilds the
-    prefixes of every column in which any row of the solve moved, a dozen
-    times those whose route from here changes, and an unchanged route's
-    entry dies at once instead of living to the build's end."""
+    prefixes of every column in which my own distance or my first hops
+    moved, and more where it cannot tell (under LFA, or where the mirrors
+    were not in hand), so an unchanged route's entry dies at once instead
+    of living to the build's end."""
 
     def __init__(self, prev_entries, policy_fn, changed: list) -> None:
         self._prev_entries = prev_entries

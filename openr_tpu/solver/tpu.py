@@ -576,13 +576,20 @@ class _AreaSolve:
         # how the two are told apart in tests and dashboards
         self.delta_extracts = 0
         self.delta_columns = 0
+        # of those, the columns a route from here reads: where my own
+        # distance row or my first-hop mask moved (decision.spf.
+        # delta_route_columns)
+        self.delta_route_columns = 0
         self.delta_bytes = 0
         self.delta_extract_ms_last: Optional[float] = None
         # changed destination columns accumulated for the route-delta
-        # consumer (take_route_delta); None = poisoned: some solve since
-        # the last take had no device delta, the consumer must full-rebuild
+        # consumer (take_route_delta), every changed one and the route
+        # columns among them; None = poisoned: some solve since the last
+        # take had no device delta, the consumer must full-rebuild
         self._delta_pending: Optional[set] = set()
+        self._route_pending: Optional[set] = set()
         self._last_solve_delta: Optional[np.ndarray] = None
+        self._last_route_delta: Optional[np.ndarray] = None
         # _sync_spf_counters bookmarks (what is already folded into counters)
         self._inc_synced = 0
         self._full_synced = 0
@@ -592,6 +599,7 @@ class _AreaSolve:
         self._graph_recompiles_synced = 0
         self._graph_links_patched_synced = 0
         self._delta_cols_synced = 0
+        self._delta_route_cols_synced = 0
         self._delta_bytes_synced = 0
         self._delta_extracts_synced = 0
         self._ksp_warm_synced = 0
@@ -830,11 +838,12 @@ class _AreaSolve:
             self._d_host = None
             self._mem_release("mirror")
             self._drop_nh_mask()
-            self._delta_pending = None
+            self._delta_pending = self._route_pending = None
         elif self._delta_pending is not None:
             # qualifying event: mirrors were patched in place during
             # extraction, the changed columns accumulate for the consumer
-            self._delta_pending.update(int(c) for c in self._last_solve_delta)
+            self._delta_pending.update(self._last_solve_delta.tolist())
+            self._route_pending.update(self._last_route_delta.tolist())
         # KSP: (dest, k) -> traced edge-disjoint path set for src == me;
         # reset with the snapshot, so topology changes invalidate it for free
         self._ksp: Dict[Tuple[str, int], List[Path]] = {}
@@ -1466,14 +1475,18 @@ class _AreaSolve:
         changed-column count (4 bytes), size a compacted `_delta_extract`
         dispatch, and patch the persistent host mirrors (distance matrix +
         nexthop mask) in place. Sets self._last_solve_delta to the changed
-        destination columns; leaving it None makes _solve treat the event
-        as full (mirrors reset, accumulated delta poisoned)."""
+        destination columns and self._last_route_delta to those among them
+        whose route from here can have moved; leaving the first None makes
+        _solve treat the event as full (mirrors reset, accumulated delta
+        poisoned)."""
         if not delta_ok:
             return
         self._pclock.enter("delta_extract")
         num = self._to_int(num_changed)
         if num == 0:
-            self._last_solve_delta = np.empty(0, dtype=np.int64)
+            self._last_solve_delta = self._last_route_delta = np.empty(
+                0, dtype=np.int64
+            )
             return
         g = self.graph
         if num > max(_PATCH_SLOTS, int(g.n_pad * _DELTA_MAX_FRAC)):
@@ -1507,7 +1520,14 @@ class _AreaSolve:
         self.delta_extracts += 1
         valid = cols < g.n_pad
         cols_real = cols[valid].astype(np.int64)
+        # a route from here reads my own distance row and the first-hop
+        # mask of its columns: a column where only other rows moved
+        # (a neighbour's distance) routes as before. Where the old values
+        # are not in hand, every changed column is a route column
+        route_cols = cols_real
+        own_old = None
         if self._d_host is not None:
+            own_old = self._d_host[0, cols_real]
             self._d_host[:, cols_real] = dcols[:, valid]
         if self._nh_mask is not None and self._nh_links == names:
             mask_cols = nh[: len(names)][:, valid]
@@ -1516,20 +1536,29 @@ class _AreaSolve:
                     # an overloaded neighbor relays nothing: valid only
                     # when it is itself the destination (nh_mask semantics)
                     mask_cols[i] &= cols_real == g.node_index[nm]
+            if own_old is not None:
+                moved = (own_old != dcols[0, valid]) | (
+                    self._nh_mask[:, cols_real] != mask_cols
+                ).any(axis=0)
+                route_cols = cols_real[moved]
             self._nh_mask[:, cols_real] = mask_cols
         elif self._nh_mask is not None:
             self._drop_nh_mask()  # up-link set moved: rebuild lazily
+        self.delta_route_columns += len(route_cols)
         self._last_solve_delta = cols_real
+        self._last_route_delta = route_cols
 
-    def take_route_delta(self) -> Optional[set]:
+    def take_route_delta(self, every_changed: bool = False) -> Optional[set]:
         """One-shot consumer handshake for the DeltaPath route build: the
-        changed destination columns accumulated since the last take (an
-        empty set means solves ran but no destination moved, or no solve
-        ran), or None when any intervening solve could not produce a
-        device delta — the caller must rebuild the full route db, which
-        re-arms accumulation."""
-        out = self._delta_pending
-        self._delta_pending = set()
+        destination columns accumulated since the last take whose route
+        from here can have moved (an empty set means solves ran but no
+        such destination moved, or no solve ran), or None when any
+        intervening solve could not produce a device delta — the caller
+        must rebuild the full route db, which re-arms accumulation.
+        `every_changed` asks for every column in which any row moved:
+        what RFC 5286 alternates read, the neighbours' rows."""
+        out = self._delta_pending if every_changed else self._route_pending
+        self._delta_pending, self._route_pending = set(), set()
         return out
 
     def nh_mask(self) -> Tuple[List[str], np.ndarray]:
@@ -2076,6 +2105,13 @@ class TpuSpfSolver(SpfSolver):
         if d_cols:
             solve._delta_cols_synced = solve.delta_columns
             self._bump("decision.spf.delta_columns", d_cols)
+        # of those, the columns a route from here reads: bumped also by 0,
+        # so that the counter exists from the first sync
+        self._bump(
+            "decision.spf.delta_route_columns",
+            solve.delta_route_columns - solve._delta_route_cols_synced,
+        )
+        solve._delta_route_cols_synced = solve.delta_route_columns
         d_bytes = solve.delta_bytes - solve._delta_bytes_synced
         if d_bytes:
             solve._delta_bytes_synced = solve.delta_bytes
@@ -2177,11 +2213,13 @@ class TpuSpfSolver(SpfSolver):
         self, area_link_states: Dict[str, LinkState]
     ) -> Optional[Set[str]]:
         """Refresh every area's device solve against the current LSDB and
-        return the union of changed destination NODE NAMES — iff every
-        area event since the last poll rode the device delta-extraction
-        path. None means some event had no device delta (cold solve,
-        overload change, flap incident to me, bulk event): the caller must
-        rebuild the full route db, which re-arms delta accumulation.
+        return the union of the destination NODE NAMES whose route from
+        here can have moved, where my own distance or my first hops
+        toward them moved — iff every area event since the last poll rode
+        the device delta-extraction path. None means some event had no
+        device delta (cold solve, overload change, flap incident to me,
+        bulk event): the caller must rebuild the full route db, which
+        re-arms delta accumulation.
 
         Areas where this node is absent contribute no routes (the pipeline
         sees an empty SPF there) and are skipped.
@@ -2191,7 +2229,9 @@ class TpuSpfSolver(SpfSolver):
         me) reads the ME column from every alt-neighbor row, so a delta
         whose changed set contains me would leave every OTHER prefix's LFA
         threshold stale — that event class is answered with None (full
-        rebuild). Every other LFA input is a changed-announcer column the
+        rebuild) — and the alternates read the neighbours' rows, so the
+        answer holds every changed destination, not only the route
+        columns. Every other LFA input is a changed-announcer column the
         delta already names (docs/Apsp.md "DeltaPath under LFA")."""
         me = self.my_node_name
         changed: Set[str] = set()
@@ -2200,7 +2240,7 @@ class TpuSpfSolver(SpfSolver):
             solve = self._my_solve(link_state)
             if solve is None:
                 continue
-            cols = solve.take_route_delta()
+            cols = solve.take_route_delta(self.compute_lfa_paths)
             if cols is None:
                 ok = False  # keep draining the other areas' pending state
                 # the full build that must follow reads the whole mirror:
