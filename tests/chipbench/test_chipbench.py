@@ -95,6 +95,9 @@ def test_benchmark_json_names_units_and_files():
         assert set(m.get("workloads", [])) <= cells
     configs = {c["name"] for c in bench["configs"]}
     assert configs == {w["config"] for w in bench["workloads"]}  # each is used
+    # at most half of the cells, rounded down, on four chips; one always may
+    four = sum(w["chips"] == 4 for w in bench["workloads"])
+    assert four <= max(1, len(bench["workloads"]) // 2)
     for w in bench["workloads"]:
         assert name_re.match(w["name"]) and w["chips"] in (1, 4) and len(w["why"]) <= 200
         assert w["config"] in configs

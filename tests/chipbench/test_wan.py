@@ -21,6 +21,8 @@ from chipbench.topologies import build_edges, wan
 from chipbench.traffic_kinds import link_metric_swap, listed_link_metric_swap
 from openr_tpu.topology import wan_edges
 
+from test_benchmark_lists import CELLS
+
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 CONFIG, TOY = "wan65536", "rehearsal_wan"
 CELL, TOY_CELL = "wan65536.listed_metric_flaps", "rehearsal_wan.listed_metric_flaps"
@@ -104,8 +106,8 @@ def test_the_configurations_file_states_source_cut_assumptions_and_guarantees():
     assert "100,000" in config["reduced_notes"]["nodes"]
     assert "lsdb.py" in config["reduced_notes"]["nodes"]
     assert "348,288" in config["on_device"] and "[16, 65,536]" in config["on_device"]
-    entry = _bench()["configs"][-1]
-    assert entry["name"] == CONFIG and entry["file"] == f"chipbench/configs/{CONFIG}.json"
+    entry = next(c for c in _bench()["configs"] if c["name"] == CONFIG)
+    assert entry["file"] == f"chipbench/configs/{CONFIG}.json"
     assert entry["reduced"] == ["nodes"]
     assert entry["source"] == config["source"] and len(entry["source"]) <= 200
     assert "BASELINE.json config 3" in entry["source"]
@@ -169,8 +171,11 @@ def test_the_cell_is_metric_flaps_numbers_over_a_list():
 
 
 def test_the_cell_joins_the_grids_lists_and_not_gc_pause():
+    """Held as a beginning: a cell appended after the WAN's joins these lists
+    behind it, and nothing here needs an edit for it."""
     bench = _bench()
-    assert [w["name"] for w in bench["workloads"]][-2:] == [SPINE_CELL, CELL]
+    assert [w["name"] for w in bench["workloads"]][: len(CELLS)] == CELLS
+    older = CELLS[: CELLS.index(CELL)]
     for m in bench["per_layer"]:
         if m["name"] in NEW_METRICS:
             continue
@@ -179,9 +184,11 @@ def test_the_cell_joins_the_grids_lists_and_not_gc_pause():
         expected = "grid10000.metric_flaps" in cells
         assert (CELL in cells) == expected, m["name"]
         if CELL in cells:
-            assert cells[-1] == CELL, m["name"]
+            assert all(cells.index(c) < cells.index(CELL) for c in cells if c in older), m["name"]
     p95 = next(m for m in bench["end_to_end"] if m["name"] == "event_to_fib_ms.p95")
-    assert p95["workloads"][-1] == CELL and SPINE_CELL not in p95["workloads"]
+    cells = p95["workloads"]
+    assert CELL in cells and SPINE_CELL not in cells
+    assert all(cells.index(c) < cells.index(CELL) for c in cells if c in older)
     cell = bench_run.resolve_cell(CELL)
     assert {m["name"] for m in cell["end_to_end"]} == {
         "event_to_fib_ms.p50", "event_to_fib_ms.p95", "events_per_s", "setup_s"
@@ -191,9 +198,12 @@ def test_the_cell_joins_the_grids_lists_and_not_gc_pause():
                                "delta_route_build_share", "compiles_in_window"} <= reported
     grid = {m["name"] for m in bench_run.resolve_cell("grid10000.metric_flaps")["per_layer"]}
     assert reported == grid and "gc_pause_ms.max" not in reported
-    # four configurations and seven cells, all one chip
-    assert len(bench["configs"]) == 4 and len(bench["workloads"]) == 7
-    assert {w["chips"] for w in bench["workloads"]} == {1}
+    # the four configurations and seven cells this file knows, each on one chip
+    assert {"fabric9976", "grid10000", "fabric9976_ssw", CONFIG} <= {
+        c["name"] for c in bench["configs"]
+    }
+    chips = {w["name"]: w["chips"] for w in bench["workloads"]}
+    assert all(chips[name] == 1 for name in CELLS)
 
 
 def _take(params, seed, n):
@@ -265,11 +275,13 @@ def test_every_event_of_300_changes_a_route_in_the_toys_reference():
         before = after
 
 
-def test_every_new_metric_file_is_named_by_benchmark_json():
+@pytest.mark.parametrize("index, name", list(enumerate(NEW_METRICS, start=46)))
+def test_every_new_metric_file_is_named_by_benchmark_json(index, name):
+    """Each by its name, at the index at which it was appended: nothing
+    moved, and what is appended later stands behind it."""
     named = [m["name"] for m in _bench()["per_layer"]]
     files = {f[: -len(".json")] for f in os.listdir(os.path.join(bench_run.HERE, "metrics"))}
-    assert set(NEW_METRICS) <= files & set(named)
-    assert named[-4:] == NEW_METRICS  # appended, nothing moved
+    assert name in files and named.index(name) == index
 
 
 @pytest.mark.parametrize("breakage", control.BREAKAGES)
